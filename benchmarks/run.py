@@ -67,6 +67,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.caches import enable_compile_cache
+
 ROWS = []
 
 
@@ -871,6 +873,7 @@ def write_json(path: pathlib.Path) -> None:
 
 
 def main(argv=None) -> None:
+    enable_compile_cache()
     argv = sys.argv[1:] if argv is None else argv
     json_path = None
     names = []

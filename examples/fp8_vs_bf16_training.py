@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.caches import enable_compile_cache
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, Dataset
 from repro.models import Model
@@ -43,6 +44,7 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("training bf16 baseline ...")
     base = train("none", args.steps)

@@ -82,7 +82,8 @@ def main():
     ap.add_argument("--mesh", default=None, metavar="DATAxMODEL",
                     help="run the engine tensor-parallel over a device "
                          "mesh, e.g. '1x2' (token streams bit-identical "
-                         "to single-device; on CPU set XLA_FLAGS="
+                         "to single-device on CPU host devices, not yet "
+                         "on a TPU; on CPU set XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N first)")
     ap.add_argument("--stream", action="store_true",
                     help="print tokens the step they are sampled")

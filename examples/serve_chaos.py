@@ -26,6 +26,8 @@ import tempfile
 
 import numpy as np
 
+from repro.caches import enable_compile_cache
+
 EPILOG = """\
 fault plan (all per-step probabilities from one seeded stream):
   --exhaustion P     seize pages off the free list for a few steps
@@ -81,6 +83,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None,
                     help="snapshot directory (default: a tempdir)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from repro.configs import get_config
     from repro.launch import serve

@@ -21,8 +21,9 @@ Knobs (environment):
 
   REPRO_AUTOTUNE        "0" never measure; "1"/"force" measure even in
                         interpret mode; unset = measure on TPU/GPU only.
-  REPRO_AUTOTUNE_CACHE  cache file path
-                        (default ``~/.cache/repro/autotune.json``).
+  REPRO_AUTOTUNE_CACHE  cache file path (default
+                        ``<checkout>/.cache/autotune.json``, beside the JAX
+                        compilation cache — see ``repro.caches``).
 
 The cache write is atomic (tmp file + rename) so concurrent processes at
 worst re-measure; measurement happens with explicit blocks, so the tuner
@@ -31,7 +32,11 @@ never recurses into itself.
 Every answered query also publishes an ``autotune_block_us`` gauge
 (labels: kernel, site, config, source=measured|cached|heuristic) into the
 process-global telemetry registry, so the serve CLI's ``--metrics-out``
-exposition records which tilings this process actually ran with.
+exposition records which tilings this process actually ran with.  A
+candidate the compiler refuses is skipped with its reason published (a
+warning and ``autotune_refused_total``); when the compiler refuses every
+candidate the tuner raises — it never answers with a configuration that
+has not run.
 """
 from __future__ import annotations
 
@@ -41,6 +46,7 @@ import pathlib
 import tempfile
 import threading
 import time
+import warnings
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -52,12 +58,19 @@ _CACHE: Optional[Dict[str, list]] = None
 # VMEM ceiling for candidate filtering (bytes); conservative vs 16 MiB/core.
 _VMEM_BUDGET = 8 * 1024 * 1024
 
+# What a compiler refusal raises: Pallas lowering (NotImplementedError for
+# a primitive with no TPU lowering, ValueError for an illegal block shape)
+# and XLA/Mosaic compilation (JaxRuntimeError, VMEM exhaustion included).
+COMPILE_REFUSALS = (NotImplementedError, ValueError, jax.errors.JaxRuntimeError)
+
 
 def cache_path() -> pathlib.Path:
     env = os.environ.get("REPRO_AUTOTUNE_CACHE")
     if env:
         return pathlib.Path(env).expanduser()
-    return pathlib.Path("~/.cache/repro/autotune.json").expanduser()
+    from ..caches import CACHE_ROOT
+
+    return CACHE_ROOT / "autotune.json"
 
 
 def _load() -> Dict[str, list]:
@@ -95,11 +108,8 @@ def clear_memory_cache() -> None:
 
 def _device_kind() -> str:
     """Sanitized device model for cache keys (e.g. ``TPU_v5_lite``)."""
-    try:
-        kind = str(jax.devices()[0].device_kind)
-    except Exception:
-        return "unknown"
-    return kind.strip().replace("|", "/").replace(" ", "_") or "unknown"
+    kind = str(jax.devices()[0].device_kind)
+    return kind.strip().replace("|", "/").replace(" ", "_")
 
 
 def _lookup(key: str, legacy_key: str, interpret: bool):
@@ -128,13 +138,10 @@ def _publish(kernel: str, site: str, config, best_s: Optional[float],
     """Mirror one tuning decision into the process-global telemetry
     registry as ``autotune_block_us{kernel, site, config, source}``.
 
-    Lazy import keeps the kernels package importable without the serving
-    package; ``best_s=None`` (cached / heuristic answers, where nothing
-    was timed in this process) publishes the sentinel -1.0."""
-    try:
-        from ..serving.telemetry import record_autotune
-    except Exception:  # pragma: no cover - serving pkg absent
-        return
+    ``best_s=None`` (cached / heuristic answers, where nothing was timed
+    in this process) publishes the sentinel -1.0."""
+    from ..serving.telemetry import record_autotune
+
     record_autotune(kernel, site, _fmt_config(config),
                     -1.0 if best_s is None else best_s * 1e6, source)
 
@@ -157,28 +164,42 @@ def _time_call(fn, n: int = 5, warmup: int = 2) -> float:
     return (time.perf_counter() - t0) / n
 
 
-def _measure_best(key: str, candidates: Sequence[tuple], make_fn, fallback,
-                  *, kernel: str = "", site: str = ""):
+def _refused(kernel: str, site: str, config, err: BaseException) -> str:
+    """Publish one compiler refusal; returns its one-line reason."""
+    lines = str(err).strip().splitlines()
+    reason = f"{type(err).__name__}: {lines[0][:200] if lines else ''}"
+    warnings.warn(f"autotune: {kernel} candidate {_fmt_config(config)} at "
+                  f"{site} refused by the compiler: {reason}", stacklevel=3)
+    from ..serving.telemetry import record_autotune_refusal
+
+    record_autotune_refusal(kernel, site, _fmt_config(config), reason)
+    return f"{_fmt_config(config)}: {reason}"
+
+
+def _measure_best(key: str, candidates: Sequence, make_fn, *, kernel: str,
+                  site: str):
     """Time each candidate, cache and return the fastest (first on tie).
 
-    Only a config that actually ran is persisted; if every candidate fails
-    on this backend the (unmeasured) ``fallback`` is returned WITHOUT
-    caching, so later runs keep falling through to the heuristics instead
-    of replaying a frozen never-validated tiling."""
-    best, best_t = None, float("inf")
+    The first call of a candidate compiles it.  A candidate the compiler
+    refuses is skipped with its reason published (:func:`_refused`); when
+    every candidate is refused this raises, so nothing unmeasured is ever
+    returned or cached."""
+    best, best_t, refusals = None, float("inf"), []
     for cand in candidates:
+        fn = make_fn(cand)
         try:
-            t = _time_call(make_fn(cand))
-        except Exception:
-            continue  # candidate invalid on this backend; skip
+            jax.block_until_ready(fn())
+        except COMPILE_REFUSALS as err:
+            refusals.append(_refused(kernel, site, cand, err))
+            continue
+        t = _time_call(fn, warmup=1)
         if t < best_t:
             best, best_t = cand, t
     if best is None:
-        if kernel:
-            _publish(kernel, site, fallback, None, "heuristic")
-        return fallback
-    if kernel:
-        _publish(kernel, site, best, best_t, "measured")
+        raise RuntimeError(
+            f"autotune: the compiler refused every {kernel} candidate at "
+            f"{site}: " + "; ".join(refusals))
+    _publish(kernel, site, best, best_t, "measured")
     _store(key, best)
     return best
 
@@ -253,7 +274,6 @@ def matmul_blocks(
                                   blocks=blocks, interpret=interpret)
 
     return _norm(_measure_best(key, _matmul_candidates(M, N, K, impl), make_fn,
-                               _matmul_default(M, N, K, impl, interpret),
                                kernel="matmul", site=tail))
 
 
@@ -288,19 +308,13 @@ def choose_matmul_impl(
     rng = np.random.default_rng(0)
     x = jax.numpy.asarray(rng.integers(0, 256, size=(M, K)).astype(np.uint8))
     w = jax.numpy.asarray(rng.integers(0, 256, size=(K, N)).astype(np.uint8))
-    best, best_t = "fused_dequant", float("inf")
-    for impl in ("fused_dequant", "lns"):
-        try:
-            t = _time_call(lambda impl=impl: lns_matmul(
-                x, w, fmt=fmt, mode=mode, impl=impl, interpret=interpret))
-        except Exception:
-            continue
-        if t < best_t:
-            best, best_t = impl, t
-    _publish("matmul_impl", tail, best,
-             best_t if best_t < float("inf") else None, "measured")
-    _store(key, best)
-    return best
+
+    def make_fn(impl):
+        return lambda: lns_matmul(x, w, fmt=fmt, mode=mode, impl=impl,
+                                  interpret=interpret)
+
+    return _measure_best(key, ("fused_dequant", "lns"), make_fn,
+                         kernel="matmul_impl", site=tail)
 
 
 # --------------------------------------------------------------------------- #
@@ -339,7 +353,7 @@ def elementwise_block_rows(
                                        mode=mode, block_rows=block_rows,
                                        interpret=interpret)
 
-    best = _measure_best(key, _elementwise_candidates(rows), make_fn, 256,
+    best = _measure_best(key, _elementwise_candidates(rows), make_fn,
                          kernel="elemwise", site=tail)
     return int(best) if not isinstance(best, tuple) else int(best[0])
 
@@ -379,7 +393,7 @@ def flash_blocks(
         bq, bk = cand
         return lambda: flash_attention(q, k, v, bq=bq, bk=bk, interpret=interpret)
 
-    return tuple(_measure_best(key, candidates, make_fn, default,
+    return tuple(_measure_best(key, candidates, make_fn,
                                kernel="flash", site=tail))
 
 
@@ -455,5 +469,5 @@ def paged_blocks(
                 interpret=interpret, ppb=ppb, spb=spb)
         return run
 
-    return tuple(_measure_best(key, candidates, make_fn, default,
+    return tuple(_measure_best(key, candidates, make_fn,
                                kernel="paged", site=tail))

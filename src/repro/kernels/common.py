@@ -5,13 +5,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import FORMATS, FP8Format
-
-# jax <= 0.4.x names the TPU compiler-params struct TPUCompilerParams; newer
-# releases renamed it CompilerParams.  All kernels go through this alias.
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def code_to_f32(codes, fmt: FP8Format | str):

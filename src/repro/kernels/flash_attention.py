@@ -25,7 +25,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..models.layers import NEG_INF
-from .common import CompilerParams
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -147,7 +146,7 @@ def _flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

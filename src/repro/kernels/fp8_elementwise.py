@@ -17,10 +17,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import FORMATS
 from ..core.lns import lns_op
-from .common import CompilerParams
 
 LANES = 128
 DEFAULT_BLOCK_ROWS = 256
@@ -108,7 +108,7 @@ def _fp8_elementwise(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows_p, LANES), jnp.uint8),
-        compiler_params=CompilerParams(dimension_semantics=("parallel",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
     )(*args)
     return out.reshape(-1)[:n].reshape(shape)

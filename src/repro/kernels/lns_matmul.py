@@ -6,11 +6,13 @@ Three implementations, all tiled for VMEM with explicit BlockSpecs:
   integer addition ``X + Y + K + c_in`` on the raw codes (eqs. 6/29 +
   Tables 2/3 carry-ins).  All per-operand work — sign/mantissa bit fields,
   the per-operand halves of the factored carry-in expressions, the decode
-  constants — is hoisted out of the inner product (``common.lns_prepare``),
-  then K is processed in sub-chunks of ``ck`` codes as [bm, ck, bn]
+  constants — is done once per element (``common.lns_prepare``), not per
+  product; K is processed in sub-chunks of ``ck`` codes as [ck, bm, bn]
   broadcast integer tiles reduced over ck in one step: bk/ck wide VPU ops
-  instead of bk sequential rank-1 updates.  Product codes are decoded to
-  f32 by exponent/mantissa bit placement (no LUT) and accumulated in f32.
+  instead of bk sequential rank-1 updates.  The x tile is staged K-major
+  in VMEM scratch so each sub-chunk is a sublane slice of a ref.  Product
+  codes are decoded to f32 by exponent/mantissa bit placement (no LUT) and
+  accumulated in f32.
   No floating-point multiplier is ever used — the multiply cost is integer
   adds, exactly the paper's proposition.
 
@@ -28,7 +30,7 @@ Three implementations, all tiled for VMEM with explicit BlockSpecs:
 
 Block sizes come from ``kernels.autotune`` unless given explicitly; the
 ``lns`` tiling is (bm, bn, bk, ck).  VMEM at the default (128, 128, 128, 16)
-blocks: x/w tiles 32 KiB + out 64 KiB + [bm, ck, bn] int32/f32 temporaries
+blocks: x/w tiles 32 KiB + out 64 KiB + [ck, bm, bn] int32/f32 temporaries
 ~ a few MiB << 16 MiB/core.  Matmul dims are multiples of 128 => MXU/VPU
 lane aligned.
 """
@@ -39,69 +41,65 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import FORMATS
-from .common import CompilerParams, LNSOperand, code_to_f32, lns_combine, lns_mul_to_f32, lns_prepare
+from .common import code_to_f32, lns_combine, lns_mul_to_f32, lns_prepare
 
 DEFAULT_BLOCKS = (128, 128, 128)
 DEFAULT_CK = 16
 
 
-def _slice_operand(p: LNSOperand, k0, ck: int, axis: int) -> LNSOperand:
-    """Slice every per-element field of a prepared operand along ``axis``."""
-    return LNSOperand(*(
-        None if f is None else jax.lax.dynamic_slice_in_dim(f, k0, ck, axis=axis)
-        for f in p
-    ))
+def _stage_x_transposed(x_ref, xt_ref):
+    """Widen the x code tile once and store it K-major in VMEM scratch, so
+    every K slice below is a sublane slice of a ref (Mosaic slices refs
+    along sublanes at any offset, along lanes only at multiples of 128)."""
+    xt_ref[...] = x_ref[...].astype(jnp.int32).T
 
 
-def _expand(p: LNSOperand, expander) -> LNSOperand:
-    return LNSOperand(*(None if f is None else expander(f) for f in p))
-
-
-def _lns_kernel(x_ref, w_ref, o_ref, *, fmt, mode, bk, ck):
+def _lns_kernel(x_ref, w_ref, o_ref, xt_ref, *, fmt, mode, bk, ck):
     """Grid (M/bm, N/bn, K/bk), K innermost; o block revisited across k.
 
-    Per-operand bit logic runs once per tile; the inner product is bk/ck
-    vectorized [bm, ck, bn] combine+reduce steps.
+    The inner product is bk/ck vectorized [ck, bm, bn] combine steps, each
+    reduced over its leading (major) axis; every per-operand bit field is
+    prepared once per element.
     """
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    px = lns_prepare(x_ref[...], fmt, mode, side="x")  # fields [bm, bk]
-    pw = lns_prepare(w_ref[...], fmt, mode, side="y")  # fields [bk, bn]
+    _stage_x_transposed(x_ref, xt_ref)
 
     def chunk(c, acc):
-        k0 = c * ck
-        pxs = _expand(_slice_operand(px, k0, ck, axis=1), lambda f: f[:, :, None])
-        pws = _expand(_slice_operand(pw, k0, ck, axis=0), lambda f: f[None, :, :])
-        prod = lns_combine(pxs, pws, fmt)  # [bm, ck, bn] f32
-        return acc + prod.sum(axis=1)
+        k0 = pl.multiple_of(c * ck, ck)
+        # broadcast the codes, not the prepared fields: Mosaic cannot
+        # reshape boolean masks, and the integer prepare commutes with it
+        xs = xt_ref[pl.ds(k0, ck), :][:, :, None]                  # [ck, bm, 1]
+        ws = w_ref[pl.ds(k0, ck), :].astype(jnp.int32)[:, None, :]  # [ck, 1, bn]
+        prod = lns_combine(lns_prepare(xs, fmt, mode, side="x"),
+                           lns_prepare(ws, fmt, mode, side="y"), fmt)
+        return acc + prod.sum(axis=0)  # [ck, bm, bn] -> [bm, bn] f32
 
-    acc = jnp.zeros(o_ref.shape, jnp.float32)
-    if bk == ck:
-        acc = chunk(0, acc)
-    else:
-        acc = jax.lax.fori_loop(0, bk // ck, chunk, acc)
+    acc = jax.lax.fori_loop(0, bk // ck, chunk,
+                            jnp.zeros(o_ref.shape, jnp.float32))
     o_ref[...] += acc
 
 
-def _lns_loop_kernel(x_ref, w_ref, o_ref, *, fmt, mode, bk):
+def _lns_loop_kernel(x_ref, w_ref, o_ref, xt_ref, wi_ref, *, fmt, mode, bk):
     """The seed kernel: sequential rank-1 k-loop.  Perf baseline only."""
 
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    x = x_ref[...]  # [bm, bk] uint8 codes
-    w = w_ref[...]  # [bk, bn] uint8 codes
+    _stage_x_transposed(x_ref, xt_ref)
+    wi_ref[...] = w_ref[...].astype(jnp.int32)  # 1-row loads need 32-bit rows
 
     def body(k, acc):
-        xk = jax.lax.dynamic_slice_in_dim(x, k, 1, axis=1)  # [bm, 1]
-        wk = jax.lax.dynamic_slice_in_dim(w, k, 1, axis=0)  # [1, bn]
-        return acc + lns_mul_to_f32(xk, wk, fmt, mode)  # [bm, bn] f32
+        xk = xt_ref[pl.ds(k, 1), :].T                       # [bm, 1]
+        wk = wi_ref[pl.ds(k, 1), :]                         # [1, bn]
+        return acc + lns_mul_to_f32(xk, wk, fmt, mode)      # [bm, bn] f32
 
     acc = jax.lax.fori_loop(0, bk, body, jnp.zeros(o_ref.shape, jnp.float32))
     o_ref[...] += acc
@@ -215,6 +213,11 @@ def _lns_matmul(
     else:
         raise ValueError(f"unknown impl {impl!r}")
 
+    scratch = {
+        "lns": [pltpu.VMEM((bk, bm), jnp.int32)],
+        "lns_loop": [pltpu.VMEM((bk, bm), jnp.int32),
+                     pltpu.VMEM((bk, bn), jnp.int32)],
+    }.get(impl, [])
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -224,7 +227,8 @@ def _lns_matmul(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        compiler_params=CompilerParams(
+        scratch_shapes=scratch,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

@@ -48,7 +48,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import FORMATS
 from ..core.quant import encode
-from .common import CompilerParams, code_to_f32, lns_combine, lns_prepare
+from .common import code_to_f32, lns_combine, lns_prepare
 
 NEG_INF = -2.0e30
 
@@ -75,39 +75,50 @@ def quantize_q(q, fmt: str, mode: str = "rne"):
 # Phase 1: per-page softmax partials — ONE implementation, called by both
 # the Pallas kernel body and the pure-JAX reference.
 # --------------------------------------------------------------------------- #
-def _page_scores_lns(q_codes, k_codes, qk_scale, fmt, mode):
+def _rowsum(x, pin: bool):
+    """f32 sum over the last axis.  Pinned (traced by XLA CPU): a dot
+    against ones behind a barrier — XLA CPU lowers dots the same in every
+    graph context and reduce-sums not, which the bit-identity contracts
+    need.  Unpinned (the compiled TPU kernel): a VPU reduction, exact in
+    f32 where an MXU dot would round its operands."""
+    if not pin:
+        return x.sum(axis=-1)
+    return jax.lax.optimization_barrier(jax.lax.dot_general(
+        x, jnp.ones((x.shape[-1],), jnp.float32),
+        (((x.ndim - 1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    ))
+
+
+def _page_scores_lns(q_codes, k_codes, qk_scale, fmt, mode, pin=True):
     """LNS integer-domain scores for one (slot, page).
 
     q_codes: [KV, G, hd] uint8; k_codes: [page, KV, hd] uint8;
     qk_scale: f32 scalar (q_scale * k_page_scale * hd**-0.5).
     Returns s [KV, G, page] f32.  Every q·k product is the paper's integer
-    add + carry-in; the sum over hd runs on the wide f32 decode.
+    add + carry-in; the sum over hd runs on the wide f32 decode.  ``pin``
+    places the XLA-CPU bit-identity barriers (see ``_page_partial``).
     """
     px = lns_prepare(q_codes, fmt, mode, side="x")        # fields [KV, G, hd]
-    py = lns_prepare(k_codes, fmt, mode, side="y")        # fields [page, KV, hd]
+    # transpose the codes, not the prepared fields: Mosaic cannot transpose
+    # the boolean masks, and the integer prepare commutes with the transpose
+    py = lns_prepare(jnp.transpose(k_codes, (1, 0, 2)), fmt, mode,
+                     side="y")                            # fields [KV, page, hd]
 
     def ex(f):
         return None if f is None else f[:, :, None, :]    # [KV, G, 1, hd]
 
     def ey(f):
-        return None if f is None else jnp.transpose(f, (1, 0, 2))[:, None, :, :]
+        return None if f is None else f[:, None, :, :]    # [KV, 1, page, hd]
 
     pxe = type(px)(*(ex(f) for f in px))
     pye = type(py)(*(ey(f) for f in py))                  # [KV, 1, page, hd]
     prod = lns_combine(pxe, pye, fmt)                     # [KV, G, page, hd] f32
-    # Sum over hd as a dot against ones, pinned by a barrier: XLA CPU lowers
-    # dots consistently across the Pallas-interpret and plain-jit contexts,
-    # while reduce-sum vectorization is context dependent (would break the
-    # kernel == ref bit-identity contract).
-    ssum = jax.lax.dot_general(
-        prod, jnp.ones((prod.shape[-1],), jnp.float32),
-        (((3,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-    )
-    return jax.lax.optimization_barrier(ssum) * qk_scale
+    return _rowsum(prod, pin) * qk_scale
 
 
 def _page_partial(
     q_op, k_page, v_page, k_s, v_s, t0, length, *, fmt, mode, window, cap,
+    pin=True,
 ):
     """Softmax partials of one (slot, page): (m [KV,G], l [KV,G], o [KV,G,dv]).
 
@@ -116,13 +127,20 @@ def _page_partial(
     t0: global position of the page's first row; length: valid tokens for
     this slot.  A fully masked page yields m = -inf -> zero weight in the
     combine.  ``o`` is the p·V product before the 1/l normalization.
+
+    ``pin`` wraps every order-sensitive f32 reduction in
+    ``optimization_barrier``: XLA CPU traces this body both in plain jit
+    (the ref) and in the Pallas interpreter, and the barriers keep the two
+    bit-identical.  Mosaic has no lowering for the barrier, so the compiled
+    TPU kernel traces with ``pin=False``: plain f32 sums and a p·V product
+    at full f32 precision.
     """
     page = k_page.shape[0]
     if fmt is not None:
         q_codes, q_scale = q_op
         hd = q_codes.shape[-1]
         s = _page_scores_lns(q_codes, k_page, q_scale * k_s * hd**-0.5,
-                             FORMATS[fmt], mode)
+                             FORMATS[fmt], mode, pin)
         vf = code_to_f32(v_page, FORMATS[fmt]) * v_s
     else:
         hd = q_op.shape[-1]
@@ -142,18 +160,13 @@ def _page_partial(
 
     m = s.max(axis=-1)
     p = jnp.exp(s - m[..., None])
-    # Page-row sum as a dot against ones (same reason as the hd sum above):
-    # a reduce-sum here lowers context-dependently and would break the
-    # fused == unfused bit-identity contract.
-    l = jax.lax.optimization_barrier(jax.lax.dot_general(
-        p, jnp.ones((page,), jnp.float32), (((2,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ))
+    l = _rowsum(p, pin)
     # [KV, G, page] x [page, KV, dv] -> [KV, G, dv], batched over KV
-    o = jax.lax.optimization_barrier(jax.lax.dot_general(
-        p, vf, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32
-    ))
-    return m, l, o
+    o = jax.lax.dot_general(
+        p, vf, (((2,), (0,)), ((0,), (1,))), preferred_element_type=jnp.float32,
+        precision=None if pin else jax.lax.Precision.HIGHEST,
+    )
+    return m, l, (jax.lax.optimization_barrier(o) if pin else o)
 
 
 # --------------------------------------------------------------------------- #
@@ -398,50 +411,57 @@ def combine_partials_psum(m, l, o, axis_name: str):
 # Pallas kernel
 # --------------------------------------------------------------------------- #
 def _paged_kernel(
-    *refs, fmt, mode, page, KV, G, window, cap, spb, ppb, fused,
+    *refs, fmt, mode, page, KV, G, window, cap, spb, ppb, fused, pin,
 ):
     """Grid (Bp/spb, Jp/ppb) program: spb slots x ppb pages of partials.
 
-    With ``fused`` the new token's row codes ride in as extra operands and
-    are spliced into the gathered page block in-register before the partial
-    — the kernel never reads the scattered cache arrays.
+    Scalars ride in as scalar-prefetch (SMEM) operands: block tables,
+    lengths, per-slot q scales and the per-(slot, page) K/V scales gathered
+    through the block table, so SMEM holds [Bp, Jp] values however large
+    the page pool is.  With ``fused`` the new token's row codes ride in as
+    extra operands and are spliced into the gathered page block in-register
+    before the partial — the kernel never reads the scattered cache arrays.
     """
     n = spb * ppb
+    npre = 8 if fused else 5
+    bt_ref, len_ref, qs_ref, ks_ref, vs_ref = refs[:5]
     if fused:
-        bt_ref, len_ref, log_ref, row_ref, msk_ref = refs[:5]
-        refs = refs[5:]
-        kins_ref, vins_ref = refs[:2]
-        refs = refs[2:]
+        log_ref, row_ref, msk_ref = refs[5:8]
+        kins_ref, vins_ref = refs[npre:npre + 2]
+        refs = refs[npre + 2:]
     else:
-        bt_ref, len_ref = refs[:2]
-        refs = refs[2:]
-    q_ref, qs_ref = refs[:2]
-    kp_refs = refs[2:2 + n]
-    ks_refs = refs[2 + n:2 + 2 * n]
-    vp_refs = refs[2 + 2 * n:2 + 3 * n]
-    vs_refs = refs[2 + 3 * n:2 + 4 * n]
-    m_ref, l_ref, o_ref = refs[2 + 4 * n:]
+        refs = refs[npre:]
+    q_ref = refs[0]
+    kp_refs = refs[1:1 + n]
+    vp_refs = refs[1 + n:1 + 2 * n]
+    m_ref, l_ref, o_ref = refs[1 + 2 * n:]
     b = pl.program_id(0)
     j = pl.program_id(1)
-    hd = q_ref.shape[-1]
+
+    def widen(codes):
+        # 8-bit codes widen to int32 on load (the LNS prepare does the same
+        # cast): Mosaic lowers the masks and selects on 32-bit layouts only
+        return codes.astype(jnp.int32) if fmt is not None else codes
+
     for i in range(spb):
         bs = b * spb + i
-        q = q_ref[i].reshape(KV, G, hd)
-        q_op = (q, qs_ref[i, 0]) if fmt is not None else q
+        q = widen(q_ref[i])
+        q_op = (q, qs_ref[bs]) if fmt is not None else q
         for jj in range(ppb):
             idx = i * ppb + jj
-            kp_blk = kp_refs[idx][0]
-            vp_blk = vp_refs[idx][0]
+            jp = j * ppb + jj
+            kp_blk = widen(kp_refs[idx][0])
+            vp_blk = widen(vp_refs[idx][0])
             if fused:
-                hit = (log_ref[bs] == j * ppb + jj) & (msk_ref[bs] != 0)
+                hit = (log_ref[bs] == jp) & (msk_ref[bs] != 0)
                 row = jax.lax.broadcasted_iota(
                     jnp.int32, (page, 1, 1), 0) == row_ref[bs]
-                kp_blk = jnp.where(hit & row, kins_ref[i][None], kp_blk)
-                vp_blk = jnp.where(hit & row, vins_ref[i][None], vp_blk)
+                kp_blk = jnp.where(hit & row, widen(kins_ref[i][None]), kp_blk)
+                vp_blk = jnp.where(hit & row, widen(vins_ref[i][None]), vp_blk)
             m, l, o = _page_partial(
-                q_op, kp_blk, vp_blk, ks_refs[idx][0, 0], vs_refs[idx][0, 0],
-                (j * ppb + jj) * page, len_ref[bs],
-                fmt=fmt, mode=mode, window=window, cap=cap,
+                q_op, kp_blk, vp_blk, ks_ref[bs, jp], vs_ref[bs, jp],
+                jp * page, len_ref[bs],
+                fmt=fmt, mode=mode, window=window, cap=cap, pin=pin,
             )
             m_ref[i, jj] = m
             l_ref[i, jj] = l
@@ -465,23 +485,67 @@ def _paged_kernel_call(
     padded lanes carry length 0 / the null page, are fully masked by the
     shared partial (m = -inf), and are sliced off before the combine so the
     result is bit-identical for every (ppb, spb).
+
+    Under a serving mesh that shards KV heads (``parallel.hints``) the
+    launch runs under ``shard_map`` on each shard's KV heads: GSPMD cannot
+    partition a Mosaic kernel, and attention is independent per KV head,
+    so the shards' partials concatenate.
     """
+    from ..parallel.hints import kv_head_mesh
+
     B, H, hd = q_in.shape
-    _, page, _, dv = v_pages.shape
     maxp = block_tables.shape[1]
     Bp = -(-B // spb) * spb
     Jp = -(-maxp // ppb) * ppb
     pad_b, pad_j = Bp - B, Jp - maxp
     bt = jnp.pad(block_tables, ((0, pad_b), (0, pad_j)))
-    ln = _pad_rows(lengths, pad_b)
-    q_in = _pad_rows(q_in, pad_b)
-    q_scale = _pad_rows(q_scale, pad_b)
+    prefetch = [bt, _pad_rows(lengths, pad_b), _pad_rows(q_scale, pad_b),
+                k_scale[bt], v_scale[bt]]
+    args = [_pad_rows(q_in, pad_b).reshape(Bp, KV, G, hd), k_pages, v_pages]
     fused = inserts is not None
+    if fused:
+        k_row, v_row, logical, rows, imask = inserts
+        imask = (jnp.ones((B,), jnp.int32) if imask is None
+                 else imask.astype(jnp.int32))
+        prefetch += [_pad_rows(logical, pad_b), _pad_rows(rows, pad_b),
+                     _pad_rows(imask, pad_b)]
+        args += [_pad_rows(k_row, pad_b), _pad_rows(v_row, pad_b)]
+    launch = functools.partial(
+        _paged_pallas, n_prefetch=len(prefetch), fmt=fmt, mode=mode,
+        page_size=page_size, window=window, cap=cap, interpret=interpret,
+        ppb=ppb, spb=spb,
+    )
+    sharded = kv_head_mesh()
+    if sharded is not None:
+        mesh, ax = sharded
+        P = jax.sharding.PartitionSpec
+        launch = jax.shard_map(
+            launch, mesh=mesh,
+            in_specs=(P(),) * len(prefetch) + (
+                P(None, ax), P(None, None, ax), P(None, None, ax))
+            + (P(None, ax),) * (len(args) - 3),
+            out_specs=(P(None, None, ax),) * 3, check_vma=False,
+        )
+    m, l, o = launch(*prefetch, *args)
+    return _combine_partials(m[:B, :maxp], l[:B, :maxp], o[:B, :maxp])
+
+
+def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
+                  interpret, ppb, spb):
+    """The pallas_call of :func:`_paged_kernel_call` on (possibly
+    shard-local) operands: scalar prefetch, q [Bp, KV, G, hd], the page
+    arrays, then the fused row inserts [Bp, KV, hd] x2 when present."""
+    prefetch = args[:n_prefetch]
+    q, k_pages, v_pages, *rows = args[n_prefetch:]
+    Bp, KV, G, hd = q.shape
+    dv = v_pages.shape[-1]
+    Jp = prefetch[0].shape[1]
+    fused = bool(rows)
     kernel = functools.partial(
         _paged_kernel, fmt=fmt, mode=mode, page=page_size, KV=KV, G=G,
         window=window, cap=cap, spb=spb, ppb=ppb, fused=fused,
+        pin=bool(interpret),
     )
-    n_prefetch = 5 if fused else 2
 
     def page_spec(shape, i, jj):
         def ix(b, j, *pref):
@@ -489,35 +553,16 @@ def _paged_kernel_call(
             return (bt_p[b * spb + i, j * ppb + jj],) + (0,) * (len(shape) - 1)
         return pl.BlockSpec(shape, ix)
 
-    in_specs = [
-        pl.BlockSpec((spb, H, hd), lambda b, j, *pref: (b, 0, 0)),
-        pl.BlockSpec((spb, 1), lambda b, j, *pref: (b, 0)),
-    ]
+    def slot_spec(x):
+        return pl.BlockSpec((spb,) + x.shape[1:],
+                            lambda b, j, *pref: (b,) + (0,) * (x.ndim - 1))
+
+    in_specs = [slot_spec(r) for r in rows] + [slot_spec(q)]
     in_specs += [page_spec((1, page_size, KV, hd), i, jj)
-                 for i in range(spb) for jj in range(ppb)]
-    in_specs += [page_spec((1, 1), i, jj)
                  for i in range(spb) for jj in range(ppb)]
     in_specs += [page_spec((1, page_size, KV, dv), i, jj)
                  for i in range(spb) for jj in range(ppb)]
-    in_specs += [page_spec((1, 1), i, jj)
-                 for i in range(spb) for jj in range(ppb)]
-    operands = [q_in, q_scale[:, None]]
-    operands += [k_pages] * (spb * ppb) + [k_scale[:, None]] * (spb * ppb)
-    operands += [v_pages] * (spb * ppb) + [v_scale[:, None]] * (spb * ppb)
-    prefetch = [bt, ln]
-    if fused:
-        k_row, v_row, logical, rows, imask = inserts
-        imask = (jnp.ones((B,), jnp.int32) if imask is None
-                 else imask.astype(jnp.int32))
-        prefetch += [_pad_rows(logical, pad_b), _pad_rows(rows, pad_b),
-                     _pad_rows(imask, pad_b)]
-        in_specs = [
-            pl.BlockSpec((spb,) + k_row.shape[1:],
-                         lambda b, j, *pref: (b,) + (0,) * (k_row.ndim - 1)),
-            pl.BlockSpec((spb,) + v_row.shape[1:],
-                         lambda b, j, *pref: (b,) + (0,) * (v_row.ndim - 1)),
-        ] + in_specs
-        operands = [_pad_rows(k_row, pad_b), _pad_rows(v_row, pad_b)] + operands
+    operands = rows + [q] + [k_pages] * (spb * ppb) + [v_pages] * (spb * ppb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
         grid=(Bp // spb, Jp // ppb),
@@ -529,7 +574,7 @@ def _paged_kernel_call(
                          lambda b, j, *pref: (b, j, 0, 0, 0)),
         ],
     )
-    m, l, o = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
@@ -537,12 +582,11 @@ def _paged_kernel_call(
             jax.ShapeDtypeStruct((Bp, Jp, KV, G), jnp.float32),
             jax.ShapeDtypeStruct((Bp, Jp, KV, G, dv), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
     )(*prefetch, *operands)
-    return _combine_partials(m[:B, :maxp], l[:B, :maxp], o[:B, :maxp])
 
 
 # --------------------------------------------------------------------------- #

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import numerics
+from ..caches import enable_compile_cache
 from ..configs import get_config
 from ..models import Model
 from ..serving import ContinuousScheduler, PagePool, Request
@@ -105,7 +106,6 @@ class Engine:
         self.max_seq = max_seq
         self.slots = slots
         self.cache_impl = cache_impl
-        self.params = self.model.init(jax.random.PRNGKey(rng_seed))
         # ``mesh``: run the engine tensor-parallel over a
         # jax.sharding.Mesh.  Weights shard concatenation-only (serve_
         # param_pspecs), activations are pinned by the serve hint roles,
@@ -118,6 +118,7 @@ class Engine:
         self.mesh = mesh
         self._hint_specs = None
         self._tp = 1
+        init_key = jax.random.PRNGKey(rng_seed)
         if mesh is not None:
             self._validate_mesh(cfg, mesh, cache_impl)
             from ..parallel import sharding
@@ -128,21 +129,24 @@ class Engine:
             self._replicated = jax.sharding.NamedSharding(
                 mesh, jax.sharding.PartitionSpec())
             pol = numerics.as_policy(cfg.policy)
-            param_sh = sharding.named(
-                mesh, sharding.serve_param_pspecs(cfg, self.params, mesh,
-                                                  policy=pol))
+            param_sh = sharding.named(mesh, sharding.serve_param_pspecs(
+                cfg, jax.eval_shape(self.model.init, init_key), mesh,
+                policy=pol))
+            # each shard initializes only its own slice of every weight
+            self.params = jax.jit(self.model.init,
+                                  out_shardings=param_sh)(init_key)
             if static_weights:
                 from ..models.quantize import quantize_params
 
                 self.params = quantize_params(self.params, pol,
                                               shardings=param_sh)
-            else:
-                self.params = jax.device_put(self.params, param_sh)
-        elif static_weights:
-            from ..models.quantize import quantize_params
+        else:
+            self.params = self.model.init(init_key)
+            if static_weights:
+                from ..models.quantize import quantize_params
 
-            self.params = quantize_params(self.params,
-                                          numerics.as_policy(cfg.policy))
+                self.params = quantize_params(
+                    self.params, numerics.as_policy(cfg.policy))
         shape_s = ("1" if mesh is None else
                    "x".join(str(mesh.shape[a]) for a in mesh.axis_names))
         self.tel.gauge("serve_mesh_info", mesh_shape=shape_s,
@@ -197,13 +201,15 @@ class Engine:
             self.cache = self.place_cache(self.model.make_paged_cache(
                 slots, num_pages, page_size, max_seq
             ))
+            # the steps update the page pool in place (cache donated): a
+            # pool sized to the device's memory cannot be held twice
             self._decode_paged = jax.jit(
                 self.model.decode_step_paged,
-                static_argnames=("page_size", "fused"),
+                static_argnames=("page_size", "fused"), donate_argnums=(1,),
             )
             self._mixed_step = jax.jit(
                 self.model.step_paged,
-                static_argnames=("page_size", "fused"),
+                static_argnames=("page_size", "fused"), donate_argnums=(1,),
             )
             # device mirror of pool.block_tables, re-uploaded only when the
             # pool's version moves (one transfer per mutating step instead
@@ -1326,6 +1332,7 @@ def main(argv=None):
                          "jax.profiler.TraceAnnotation so host phases "
                          "line up with device traces")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.policy is not None:
         if args.quant not in (None, "none"):

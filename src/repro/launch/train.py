@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..caches import enable_compile_cache
 from ..configs import get_config
 from ..data.pipeline import DataConfig, Dataset
 from ..models import Model
@@ -46,6 +47,7 @@ def main(argv=None):
     ap.add_argument("--mesh", default="1x1", help="DATAxMODEL, e.g. 2x4")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke, quant=args.quant)
     model = Model(cfg, max_seq=args.seq)

@@ -118,7 +118,6 @@ def _moe_ffn_shard_map(p, x, cfg, mesh, hint_specs,
         dense path pays) and each device computes its tokens against all
         experts — zero collectives inside the layer.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape.get("model", 1)
@@ -199,12 +198,12 @@ def _moe_ffn_shard_map(p, x, cfg, mesh, hint_specs,
         return out.reshape(B_l, S_l, D).astype(x_loc.dtype), aux
 
     p_in = {k_: p[k_] for k_ in wspec}
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         region,
         mesh=mesh,
         in_specs=(wspec, act_spec),
         out_specs=(act_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(p_in, x)
 
     if "shared" in p:

@@ -296,9 +296,10 @@ def stack_decode(blocks, caches, x, cfg, pattern, *, pos, paged=None):
     )
 
     def block_fn(carry, scanned):
-        x, aux = carry
+        x, aux, caches = carry
         x = hint(x, "act")
-        bp, bc, bkey = scanned
+        bp, i, bkey = scanned
+        bc = jax.tree.map(lambda a: a[i], caches)
         new_cs = []
         for j, spec in enumerate(pattern):
             bpaged = None
@@ -310,9 +311,12 @@ def stack_decode(blocks, caches, x, cfg, pattern, *, pos, paged=None):
                 paged=bpaged, site=f"blocks.{j}",
             )
             new_cs.append(c)
-        return (x, aux), tuple(new_cs)
+        caches = jax.tree.map(lambda a, c: a.at[i].set(c), caches,
+                              tuple(new_cs))
+        return (x, aux, caches), None
 
-    (x, aux), new_caches = jax.lax.scan(
-        block_fn, (x, dict(AUX0)), (blocks, caches, keys)
+    (x, aux, new_caches), _ = jax.lax.scan(
+        block_fn, (x, dict(AUX0), caches),
+        (blocks, jnp.arange(n_blocks), keys)
     )
     return x, new_caches, aux

@@ -52,8 +52,6 @@ def build_compressed_dp_train_step(
     per-device residual, carried stacked on a leading device axis.
     Batch: global [B, ...] arrays, sharded on dim 0 over ``axis``.
     """
-    from jax.experimental.shard_map import shard_map
-
     ndev = mesh.shape[axis]
     cfg = model.cfg
 
@@ -85,12 +83,12 @@ def build_compressed_dp_train_step(
             new_err = jax.tree.map(lambda e: e[None], new_err)  # re-stack
             return new_params, new_opt, new_err, metrics
 
-        out = shard_map(
+        out = jax.shard_map(
             worker,
             mesh=mesh,
             in_specs=(P(), P(), P(axis), P(axis)),
             out_specs=(P(), P(), P(axis), P()),
-            check_rep=False,
+            check_vma=False,
         )(state["params"], state["opt"], state["err"], batch)
         new_params, new_opt, new_err, metrics = out
         return {"params": new_params, "opt": new_opt, "err": new_err}, metrics

@@ -51,6 +51,21 @@ def hint_meta(key: str, default=None):
     return specs.get(key, default)
 
 
+def kv_head_mesh():
+    """``(mesh, axis)`` when the active hint context shards KV heads (the
+    serving engine's ``tp_kv`` role) over a mesh axis of size > 1, else
+    None.  Kernels GSPMD cannot partition run under ``shard_map`` on it."""
+    state = _ctx.get()
+    if state is None:
+        return None
+    mesh, specs = state
+    spec = specs.get("tp_kv")
+    axis = None if spec is None or len(spec) < 3 else spec[2]
+    if axis is None or mesh.shape[axis] == 1:
+        return None
+    return mesh, axis
+
+
 def serve_hint_specs(cfg, mesh: Mesh) -> Dict[str, P]:
     """Serving-time TP hint roles (concatenation-only sharding).
 

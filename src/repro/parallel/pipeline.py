@@ -17,7 +17,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -89,10 +88,10 @@ def pipeline_forward(
         )
         return outs
 
-    return shard_map(
+    return jax.shard_map(
         stage,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, x_micro)

@@ -58,6 +58,7 @@ __all__ = [
     "PHASES",
     "default_registry",
     "record_autotune",
+    "record_autotune_refusal",
 ]
 
 # The canonical engine-step phase decomposition.  Every serving step is
@@ -196,6 +197,10 @@ METRIC_CATALOG: Tuple[MetricSpec, ...] = (
                "Measured (or assumed) best-candidate time per autotuned "
                "kernel site, microseconds.",
                labels=("kernel", "site", "config", "source")),
+    MetricSpec("autotune_refused_total", "counter",
+               "Autotune candidates the compiler refused, with the "
+               "refusal's one-line reason.",
+               labels=("kernel", "site", "config", "reason")),
     # -- telemetry self-accounting ----------------------------------
     MetricSpec("trace_events_dropped_total", "counter",
                "Trace events dropped after the in-memory cap."),
@@ -340,9 +345,10 @@ class Telemetry:
         """
         prof = None
         if self.profile:
-            prof = _profiler_annotation(name)
-            if prof is not None:
-                prof.__enter__()
+            from jax.profiler import TraceAnnotation
+
+            prof = TraceAnnotation(name)
+            prof.__enter__()
         t0 = self.clock()
         self._span_depth += 1
         try:
@@ -538,15 +544,6 @@ def _atomic_write(path: str, text: str) -> None:
     os.replace(tmp, path)
 
 
-def _profiler_annotation(name: str):
-    """Best-effort jax.profiler.TraceAnnotation (None when unavailable)."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler API absent
-        return None
-
-
 # -- process-global registry ----------------------------------------
 #
 # Engine-independent instrumentation (the kernel autotuner fires under
@@ -571,3 +568,11 @@ def record_autotune(kernel: str, site: str, config: str, best_us: float,
     default_registry().gauge(
         "autotune_block_us", kernel=kernel, site=site,
         config=config, source=source).set(best_us)
+
+
+def record_autotune_refusal(kernel: str, site: str, config: str,
+                            reason: str) -> None:
+    """Publish one autotune candidate the compiler refused."""
+    default_registry().counter(
+        "autotune_refused_total", kernel=kernel, site=site, config=config,
+        reason=reason).inc()

@@ -1,11 +1,8 @@
 """Codec, oracle and saturating-op tests, incl. hypothesis property tests."""
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:  # property tests skip without hypothesis
-    from hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import lns
 from repro.core.formats import E4M3, E5M2, FORMATS

@@ -23,12 +23,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:  # property tests skip without hypothesis
-    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
-    from hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1] / "src"))
 

@@ -21,19 +21,16 @@ pytest id:
   checks of the batched entry points (``ensure_capacity_batch``,
   ``writable_mask``) against their per-slot scalar forms.
 
-Property tests proper use ``hypothesis`` where installed and skip (via
-``hypothesis_stub``) where not; the seeded sweeps always run.
+Property tests proper use ``hypothesis``; the seeded sweeps run
+alongside them.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:  # property tests skip without hypothesis
-    from hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.quant import encode
 from repro.kernels.paged_attention import (
@@ -193,8 +190,7 @@ def test_fused_masked_lanes_never_touch_real_pages():
        mask=st.lists(st.booleans(), min_size=2, max_size=2),
        mode_i=st.integers(min_value=0, max_value=2))
 def test_fused_equals_unfused_property(lengths, mask, mode_i):
-    """Hypothesis sweep (skips without hypothesis): fixed tiny geometry,
-    arbitrary lengths/mask/mode."""
+    """Hypothesis sweep: fixed tiny geometry, arbitrary lengths/mask/mode."""
     if not any(mask):
         mask[0] = True
     case = _random_case(3, fmt="e4m3")
@@ -217,7 +213,7 @@ def test_fused_equals_unfused_property(lengths, mask, mode_i):
         case["ks"], case["vs"], case["bt"], case["lengths"],
         fmt="e4m3", n_kv_heads=case["KV"], kv_mode=kv_mode,
         k_key=k_key, v_key=v_key, write_mask=jnp.asarray(case["mask"]),
-        impl="batch",
+        window=case["window"], cap=case["cap"], impl="batch",
     )
     ref = _unfused(case, fmt="e4m3", mode="rne", kv_mode=kv_mode,
                    k_key=k_key, v_key=v_key, impl="batch")

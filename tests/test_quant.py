@@ -3,11 +3,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ModuleNotFoundError:  # property tests skip without hypothesis
-    from hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.formats import E4M3, E5M2
 from repro.core.quant import QTensor, decode, encode, quantize

@@ -232,7 +232,6 @@ def test_lse_psum_combine_matches_full_batch_allclose():
     with the shard count), which is why the engine shards heads."""
     out = run_script("""
 import jax, numpy as np, jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.launch.mesh import make_test_mesh
 from repro.kernels.paged_attention import (combine_partials_psum,
@@ -264,8 +263,8 @@ def shard_fn(tbl):
     )
     return combine_partials_psum(m, l, o, "x")
 
-split = shard_map(shard_fn, mesh=mesh, in_specs=P(None, "x"),
-                  out_specs=P(), check_rep=False)(tables)
+split = jax.shard_map(shard_fn, mesh=mesh, in_specs=P(None, "x"),
+                  out_specs=P(), check_vma=False)(tables)
 np.testing.assert_allclose(np.asarray(split), np.asarray(full),
                            rtol=2e-5, atol=2e-6)
 print("OK")
