@@ -1,0 +1,7 @@
+"""Mean device time of the model step compiled for T=1 (pure decode),
+from the traced slice (ms)."""
+from chipbench import steps
+
+
+def read(run):
+    return steps.mean_program_ms(run, lambda T: T == 1)
