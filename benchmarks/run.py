@@ -588,7 +588,10 @@ def serve_phases():
         _, stats = serve.run(eng, [q.copy() for q in queue], gen=gen,
                              quiet=True, arrivals=arrivals, **rkw)
         phases = stats["phases"]
-        total_s = sum(p["sum_s"] for p in phases.values())
+        # "step" holds the whole step and "wait"/"fetch" split "sync":
+        # counting them again would double the instrumented wall time
+        total_s = sum(p["sum_s"] for ph, p in phases.items()
+                      if ph not in ("step", "wait", "fetch"))
         tag = f"serve_phases/qwen2-0.5b-smoke/{name}"
         for ph in PHASES:
             p = phases[ph]

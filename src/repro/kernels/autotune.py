@@ -150,9 +150,13 @@ def _should_measure(interpret: bool) -> bool:
     env = os.environ.get("REPRO_AUTOTUNE", "").lower()
     if env in ("0", "off", "never"):
         return False
-    if env in ("1", "force", "always"):
-        return True
-    return not interpret and jax.default_backend() in ("tpu", "gpu")
+    measure = env in ("1", "force", "always") or (
+        not interpret and jax.default_backend() in ("tpu", "gpu"))
+    if measure:
+        # importing the telemetry registers its compile listener, so the
+        # measurement's compiles are recorded as compile spans
+        from ..serving import telemetry  # noqa: F401
+    return measure
 
 
 def _time_call(fn, n: int = 5, warmup: int = 2) -> float:

@@ -657,7 +657,7 @@ class Engine:
                 jnp.asarray(pos, jnp.int32),
             )
             self._step += 1
-            return np.asarray(logits[:, : self.cfg.vocab])
+            return self.sync_logits(logits[:, : self.cfg.vocab])
 
     def sync_logits(self, logits) -> np.ndarray:
         """Block on an async-dispatched step's logits (the token-emission
@@ -665,11 +665,16 @@ class Engine:
         mesh this wait also covers the step's collectives (the all-gather
         hints and the sharded-logits device->host gather), so the span is
         named ``collectives`` there — BENCH phase breakdowns attribute
-        the cross-shard cost to one row."""
+        the cross-shard cost to one row.  Inside it, ``wait`` is the host
+        blocked while the device computes and ``fetch`` the copy of the
+        logits to the host."""
         if isinstance(logits, np.ndarray):
             return logits
         with self.tel.span("collectives" if self._tp > 1 else "sync"):
-            return np.asarray(logits)
+            with self.tel.span("wait"):
+                jax.block_until_ready(logits)
+            with self.tel.span("fetch"):
+                return np.asarray(logits)
 
     def decode_paged(self, tokens: np.ndarray, lengths: np.ndarray, *,
                      sync: bool = True):
@@ -964,168 +969,173 @@ def run_bucketed(eng: Engine, queue: List[np.ndarray], *, gen: int,
         return None
 
     while len(statuses) < requests:
-        with tel.span("admit"):
-            # ---- deadline/cancellation sweep over the active slots ---- #
-            for slot, st in list(active.items()):
-                state = expired(st["rid"])
-                if state is not None:
-                    finish(st["rid"], state,
-                           "cancelled by client" if state == CANCELLED
-                           else "deadline exhausted")
-                    del active[slot]
-                    reserved.pop(slot, None)
-                    eng.release(slot)
-            # ---- batched admission into every free slot --------------- #
-            # Admission control reserves each request's worst-case page
-            # count (prompt + full generation budget) so decode can never
-            # exhaust the pool mid-flight; pages themselves are still
-            # allocated lazily.  With the prefix cache on, the reservation
-            # stays the conservative full worst case (shared pages
-            # double-count, never under-count), and EVERY admission — hit
-            # or miss — prefills through the position-addressed chunked
-            # pipeline (Engine.tail_prefill, start = matched length):
-            # registered pages must be content-pure, which the step-keyed
-            # batched splice cannot provide.  Hits map their cached pages
-            # read-only and prefill only the uncached tail.
-            admit_slots, admit_prompts, admit_rids = [], [], []
-            chunked_admissions = []  # (slot, rid, prompt, n_cached)
-            for slot in range(eng.slots):
-                if slot in active:
-                    continue
-                # Drain terminal queue heads before admitting into this
-                # slot: already-cancelled/expired requests, and requests
-                # whose worst case cannot fit an EMPTY pool (or one slot's
-                # block table) — each is terminated *individually*,
-                # holding no slot or pages, instead of crashing the run
-                # with earlier admissions' pages already taken.
-                while next_req < requests:
+        # one ``step`` span per loop pass: admission, its prefills and
+        # (when a slot is active) one decode step (T=1)
+        with tel.span("step", n=steps, T=0) as step:
+            with tel.span("admit"):
+                # ---- deadline/cancellation sweep over the active slots ---- #
+                for slot, st in list(active.items()):
+                    state = expired(st["rid"])
+                    if state is not None:
+                        finish(st["rid"], state,
+                               "cancelled by client" if state == CANCELLED
+                               else "deadline exhausted")
+                        del active[slot]
+                        reserved.pop(slot, None)
+                        eng.release(slot)
+                # ---- batched admission into every free slot --------------- #
+                # Admission control reserves each request's worst-case page
+                # count (prompt + full generation budget) so decode can never
+                # exhaust the pool mid-flight; pages themselves are still
+                # allocated lazily.  With the prefix cache on, the reservation
+                # stays the conservative full worst case (shared pages
+                # double-count, never under-count), and EVERY admission — hit
+                # or miss — prefills through the position-addressed chunked
+                # pipeline (Engine.tail_prefill, start = matched length):
+                # registered pages must be content-pure, which the step-keyed
+                # batched splice cannot provide.  Hits map their cached pages
+                # read-only and prefill only the uncached tail.
+                admit_slots, admit_prompts, admit_rids = [], [], []
+                chunked_admissions = []  # (slot, rid, prompt, n_cached)
+                for slot in range(eng.slots):
+                    if slot in active:
+                        continue
+                    # Drain terminal queue heads before admitting into this
+                    # slot: already-cancelled/expired requests, and requests
+                    # whose worst case cannot fit an EMPTY pool (or one slot's
+                    # block table) — each is terminated *individually*,
+                    # holding no slot or pages, instead of crashing the run
+                    # with earlier admissions' pages already taken.
+                    while next_req < requests:
+                        if arrivals is not None and arrivals[next_req] > steps:
+                            break  # FIFO: the next request has not arrived yet
+                        state = expired(next_req)
+                        if state is not None:
+                            finish(next_req, state,
+                                   "cancelled by client" if state == CANCELLED
+                                   else "deadline exhausted before admission")
+                            next_req += 1
+                            continue
+                        if eng.pool is not None:
+                            worst = eng.pool.pages_needed(
+                                queue[next_req].shape[0] + img_off + gen
+                            )
+                            usable = min(eng.pool.num_pages - 1,
+                                         eng.pool.max_pages_per_slot)
+                            if worst > usable:
+                                finish(next_req, REJECTED,
+                                       f"needs {worst} pages but the pool "
+                                       f"serves at most {usable} per request; "
+                                       f"raise --pages or lower "
+                                       f"--gen/--prompt-len")
+                                next_req += 1
+                                if invariant_checks_enabled():
+                                    eng.pool.assert_invariants()
+                                continue
+                        break
+                    if next_req >= requests:
+                        break
                     if arrivals is not None and arrivals[next_req] > steps:
                         break  # FIFO: the next request has not arrived yet
-                    state = expired(next_req)
-                    if state is not None:
-                        finish(next_req, state,
-                               "cancelled by client" if state == CANCELLED
-                               else "deadline exhausted before admission")
-                        next_req += 1
-                        continue
+                    prompt = queue[next_req]
                     if eng.pool is not None:
                         worst = eng.pool.pages_needed(
-                            queue[next_req].shape[0] + img_off + gen
-                        )
-                        usable = min(eng.pool.num_pages - 1,
-                                     eng.pool.max_pages_per_slot)
-                        if worst > usable:
-                            finish(next_req, REJECTED,
-                                   f"needs {worst} pages but the pool "
-                                   f"serves at most {usable} per request; "
-                                   f"raise --pages or lower "
-                                   f"--gen/--prompt-len")
-                            next_req += 1
-                            if invariant_checks_enabled():
-                                eng.pool.assert_invariants()
-                            continue
-                    break
-                if next_req >= requests:
-                    break
-                if arrivals is not None and arrivals[next_req] > steps:
-                    break  # FIFO: the next request has not arrived yet
-                prompt = queue[next_req]
-                if eng.pool is not None:
-                    worst = eng.pool.pages_needed(
-                        prompt.shape[0] + img_off + gen)
-                    if sum(reserved.values()) + worst > eng.pool.num_pages - 1:
-                        break  # wait for in-flight requests to free pages
-                    reserved[slot] = worst
-                n_cached = eng.admit_prefix(slot, prompt)
-                if eng.prefix_cache:
-                    chunked_admissions.append(
-                        (slot, next_req, prompt, n_cached))
-                else:
-                    admit_slots.append(slot)
-                    admit_prompts.append(prompt)
-                    admit_rids.append(next_req)
-                next_req += 1
-        if admit_prompts:
-            # bucket by prompt length: each bucket is one batched prefill
-            by_len: Dict[int, List[int]] = {}
-            for i, p in enumerate(admit_prompts):
-                by_len.setdefault(p.shape[0], []).append(i)
-            for idxs in by_len.values():
-                first, plen_total = eng.prefill_batch(
-                    [admit_prompts[i] for i in idxs],
-                    [admit_slots[i] for i in idxs],
-                )
-                for j, i in enumerate(idxs):
-                    active[admit_slots[i]] = dict(
-                        rid=admit_rids[i], pos=plen_total,
-                        out=[int(first[j])], last=int(first[j]),
+                            prompt.shape[0] + img_off + gen)
+                        if (sum(reserved.values()) + worst
+                                > eng.pool.num_pages - 1):
+                            break  # wait for in-flight requests to free pages
+                        reserved[slot] = worst
+                    n_cached = eng.admit_prefix(slot, prompt)
+                    if eng.prefix_cache:
+                        chunked_admissions.append(
+                            (slot, next_req, prompt, n_cached))
+                    else:
+                        admit_slots.append(slot)
+                        admit_prompts.append(prompt)
+                        admit_rids.append(next_req)
+                    next_req += 1
+            if admit_prompts:
+                # bucket by prompt length: each bucket is one batched prefill
+                by_len: Dict[int, List[int]] = {}
+                for i, p in enumerate(admit_prompts):
+                    by_len.setdefault(p.shape[0], []).append(i)
+                for idxs in by_len.values():
+                    first, plen_total = eng.prefill_batch(
+                        [admit_prompts[i] for i in idxs],
+                        [admit_slots[i] for i in idxs],
                     )
+                    for j, i in enumerate(idxs):
+                        active[admit_slots[i]] = dict(
+                            rid=admit_rids[i], pos=plen_total,
+                            out=[int(first[j])], last=int(first[j]),
+                        )
+                        if on_token is not None:
+                            on_token(admit_rids[i], int(first[j]), steps)
+            if chunked_admissions:
+                rows = eng.tail_prefill(
+                    [(slot, prompt, n_cached)
+                     for slot, _, prompt, n_cached in chunked_admissions],
+                    chunk=chunk,
+                )
+                for slot, rid, prompt, n_cached in chunked_admissions:
+                    first = int(np.argmax(rows[slot][: eng.cfg.vocab]))
+                    prefix_hit_tokens += n_cached
+                    tel.counter("serve_prefix_hit_tokens_total").inc(n_cached)
+                    active[slot] = dict(rid=rid, pos=prompt.shape[0] + img_off,
+                                        out=[first], last=first)
                     if on_token is not None:
-                        on_token(admit_rids[i], int(first[j]), steps)
-        if chunked_admissions:
-            rows = eng.tail_prefill(
-                [(slot, prompt, n_cached)
-                 for slot, _, prompt, n_cached in chunked_admissions],
-                chunk=chunk,
-            )
-            for slot, rid, prompt, n_cached in chunked_admissions:
-                first = int(np.argmax(rows[slot][: eng.cfg.vocab]))
-                prefix_hit_tokens += n_cached
-                tel.counter("serve_prefix_hit_tokens_total").inc(n_cached)
-                active[slot] = dict(rid=rid, pos=prompt.shape[0] + img_off,
-                                    out=[first], last=first)
-                if on_token is not None:
-                    on_token(rid, first, steps)
+                        on_token(rid, first, steps)
 
-        if not active:
-            # nothing in flight (requests still arriving): let time pass
+            if not active:
+                # nothing in flight (requests still arriving): let time pass
+                steps += 1
+                if eng.pool is not None:
+                    eng.pool.observe_step()
+                continue
+
+            # ---- one decode step for the whole pool ----------------------- #
+            step["T"] = 1
+            toks = np.zeros((eng.slots,), np.int32)
+            pos = np.zeros((eng.slots,), np.int32)
+            for slot, st in active.items():
+                toks[slot] = st["last"]
+                pos[slot] = st["pos"]
+            t_dec = clock()
+            if eng.cache_impl == "paged":
+                # async dispatch: per-step counters and pool telemetry run on
+                # the host while the device decodes; sync_logits blocks at the
+                # sampling (token-emission) boundary below
+                logits = eng.decode_paged(toks, pos, sync=False)
+            else:
+                logits = eng.decode(toks, pos)
             steps += 1
+            tel.counter("serve_steps_total").inc()
+            decoded_tokens += len(active)
+            tel.counter("serve_decoded_tokens_total").inc(len(active))
+            occupied_slot_steps += len(active)
             if eng.pool is not None:
                 eng.pool.observe_step()
-            continue
-
-        # ---- one decode step for the whole pool ----------------------- #
-        toks = np.zeros((eng.slots,), np.int32)
-        pos = np.zeros((eng.slots,), np.int32)
-        for slot, st in active.items():
-            toks[slot] = st["last"]
-            pos[slot] = st["pos"]
-        t_dec = clock()
-        if eng.cache_impl == "paged":
-            # async dispatch: per-step counters and pool telemetry run on
-            # the host while the device decodes; sync_logits blocks at the
-            # sampling (token-emission) boundary below
-            logits = eng.decode_paged(toks, pos, sync=False)
-        else:
-            logits = eng.decode(toks, pos)
-        steps += 1
-        tel.counter("serve_steps_total").inc()
-        decoded_tokens += len(active)
-        tel.counter("serve_decoded_tokens_total").inc(len(active))
-        occupied_slot_steps += len(active)
-        if eng.pool is not None:
-            eng.pool.observe_step()
-            eng.pool.publish_telemetry(tel)
-        logits = eng.sync_logits(logits)
-        decode_wall_s += clock() - t_dec
-        nxt = sample(logits, temperature, rng)
-        done = []
-        for slot, st in list(active.items()):
-            st["last"] = int(nxt[slot])
-            st["out"].append(st["last"])
-            st["pos"] += 1
-            if on_token is not None:
-                on_token(st["rid"], st["last"], steps)
-            if len(st["out"]) >= gen:
-                outputs[st["rid"]] = st["out"]
-                finish(st["rid"], FINISHED)
-                done.append(slot)
-        for slot in done:
-            del active[slot]
-            reserved.pop(slot, None)
-            eng.release(slot)
-        if invariant_checks_enabled() and eng.pool is not None:
-            eng.pool.assert_invariants()
+                eng.pool.publish_telemetry(tel)
+            logits = eng.sync_logits(logits)
+            decode_wall_s += clock() - t_dec
+            nxt = sample(logits, temperature, rng)
+            done = []
+            for slot, st in list(active.items()):
+                st["last"] = int(nxt[slot])
+                st["out"].append(st["last"])
+                st["pos"] += 1
+                if on_token is not None:
+                    on_token(st["rid"], st["last"], steps)
+                if len(st["out"]) >= gen:
+                    outputs[st["rid"]] = st["out"]
+                    finish(st["rid"], FINISHED)
+                    done.append(slot)
+            for slot in done:
+                del active[slot]
+                reserved.pop(slot, None)
+                eng.release(slot)
+            if invariant_checks_enabled() and eng.pool is not None:
+                eng.pool.assert_invariants()
 
     dt = clock() - t0
     stats = dict(

@@ -649,59 +649,63 @@ class ContinuousScheduler:
         """One scheduler step: expire/cancel, admit, fit (maybe preempt),
         dispatch the mixed model step asynchronously, overlap host
         bookkeeping with the device compute, sample/stream at the
-        emission boundary, evict finished slots."""
-        with self.tel.span("admit"):
-            self._expire()
-            self._admit()
-        with self.tel.span("host"):
-            plan = self._plan()
-            self._fit(plan)
-        if plan:
-            # T is 1 on pure-decode steps and ``chunk`` whenever a prefill
-            # is in flight — exactly two model traces for the whole run.
-            pure_decode = all(n == 1 for _, n in plan.values())
-            T = 1 if pure_decode else self.chunk
-            B = self.eng.slots
-            toks = np.zeros((B, T), np.int32)
-            lengths = np.zeros((B,), np.int32)
-            n_new = np.zeros((B,), np.int32)
-            for slot, (tk, n) in plan.items():
-                toks[slot, :n] = tk
-                lengths[slot] = self.active[slot].length
-                n_new[slot] = n
-            t0 = self.tel.clock()
-            # async dispatch: the jitted step returns a device future; the
-            # commit below runs its host-side bookkeeping (prefill
-            # accounting, prefix-page registration) while the device
-            # computes, and blocks only when the first sampled row is
-            # actually read.  A step that samples no token (every lane
-            # mid-prefill) never blocks at all — the next step's
-            # plan/fit/dispatch overlaps this one's compute.
-            logits = _StepLogits(
-                self.eng, self.eng.step_chunk(toks, lengths, n_new,
-                                              sync=False),
-                self.tel.clock,
-            )
+        emission boundary, evict finished slots.  The whole step is one
+        ``step`` span (``n`` the step number, ``T`` the tokens per lane
+        of its model step, 0 when it ran none)."""
+        with self.tel.span("step", n=self.steps, T=0) as step:
+            with self.tel.span("admit"):
+                self._expire()
+                self._admit()
             with self.tel.span("host"):
-                self._commit(plan, logits)
-            # critical-path wall time: dispatch -> emission sync (or
-            # dispatch only, for steps that never emitted)
-            dt = (logits.t_sync if logits.t_sync is not None
-                  else self.tel.clock()) - t0
-            if pure_decode:
-                self.decode_wall_s += dt
-                self.decode_step_tokens += len(plan)
-            else:
-                self.prefill_wall_s += dt
-            self.occupied_slot_steps += len(plan)
-        with self.tel.span("host"):
-            self.pool.observe_step()
-            self.pool.publish_telemetry(self.tel)
-            self.steps += 1
-            self.tel.counter("serve_steps_total").inc()
-            self._break_stall()
-        if invariant_checks_enabled():
-            self.pool.assert_invariants()
+                plan = self._plan()
+                self._fit(plan)
+            if plan:
+                # T is 1 on pure-decode steps and ``chunk`` whenever a prefill
+                # is in flight — exactly two model traces for the whole run.
+                pure_decode = all(n == 1 for _, n in plan.values())
+                T = 1 if pure_decode else self.chunk
+                step["T"] = T
+                B = self.eng.slots
+                toks = np.zeros((B, T), np.int32)
+                lengths = np.zeros((B,), np.int32)
+                n_new = np.zeros((B,), np.int32)
+                for slot, (tk, n) in plan.items():
+                    toks[slot, :n] = tk
+                    lengths[slot] = self.active[slot].length
+                    n_new[slot] = n
+                t0 = self.tel.clock()
+                # async dispatch: the jitted step returns a device future; the
+                # commit below runs its host-side bookkeeping (prefill
+                # accounting, prefix-page registration) while the device
+                # computes, and blocks only when the first sampled row is
+                # actually read.  A step that samples no token (every lane
+                # mid-prefill) never blocks at all — the next step's
+                # plan/fit/dispatch overlaps this one's compute.
+                logits = _StepLogits(
+                    self.eng, self.eng.step_chunk(toks, lengths, n_new,
+                                                  sync=False),
+                    self.tel.clock,
+                )
+                with self.tel.span("host"):
+                    self._commit(plan, logits)
+                # critical-path wall time: dispatch -> emission sync (or
+                # dispatch only, for steps that never emitted)
+                dt = (logits.t_sync if logits.t_sync is not None
+                      else self.tel.clock()) - t0
+                if pure_decode:
+                    self.decode_wall_s += dt
+                    self.decode_step_tokens += len(plan)
+                else:
+                    self.prefill_wall_s += dt
+                self.occupied_slot_steps += len(plan)
+            with self.tel.span("host"):
+                self.pool.observe_step()
+                self.pool.publish_telemetry(self.tel)
+                self.steps += 1
+                self.tel.counter("serve_steps_total").inc()
+                self._break_stall()
+            if invariant_checks_enabled():
+                self.pool.assert_invariants()
 
     def mean_latency_steps(self) -> float:
         """Mean arrival-to-completion latency of FINISHED requests, in

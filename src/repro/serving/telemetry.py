@@ -18,8 +18,14 @@ registry per engine:
   (label ``phase=...``), and append a Chrome-trace ``"X"`` event so the
   whole run can be opened in Perfetto / ``chrome://tracing``.  With
   ``profile=True`` each span additionally opens a
-  ``jax.profiler.TraceAnnotation`` so host phases line up with device
-  traces captured by ``jax.profiler``.
+  ``jax.profiler.TraceAnnotation`` (the ``step`` span a
+  ``StepTraceAnnotation``) so host phases line up with device traces
+  captured by ``jax.profiler``.
+- **Compile spans**: one process-wide listener turns each of JAX's
+  backend compiles and persistent-cache reads into a ``compile`` span of
+  the process registry (``default_registry()``), back-dated from JAX's
+  wall-clock times, and counts them (``jit_compiles_total``,
+  ``compile_cache_hits_total``, ``compile_cache_misses_total``).
 
 The registry is always on: recording is a handful of dict/float ops per
 event, and keeping it unconditional is what makes the bit-neutrality
@@ -36,7 +42,10 @@ Two exporters:
 
 Determinism: the clock is injected (``clock=time.monotonic`` by
 default), so tests drive a fake clock and pin exact durations, bucket
-placement, and exporter bytes.
+placement, and exporter bytes.  Beside the clock's origin each registry
+keeps the wall-clock time in nanoseconds (``t0_wall_ns``), the base of
+the profiler's host events: it puts the registry's spans on the same
+time line as a device trace, and another registry's spans beside them.
 """
 from __future__ import annotations
 
@@ -45,6 +54,7 @@ import contextlib
 import dataclasses
 import json
 import os
+import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
@@ -59,6 +69,7 @@ __all__ = [
     "default_registry",
     "record_autotune",
     "record_autotune_refusal",
+    "watch_compiles",
 ]
 
 # The canonical engine-step phase decomposition.  Every serving step is
@@ -137,7 +148,10 @@ METRIC_CATALOG: Tuple[MetricSpec, ...] = (
                "Engine step time decomposed by phase "
                "(admit/prefill/decode/kv_write/host/sync + auxiliary "
                "spans; mesh engines time the per-step cross-shard "
-               "wait as 'collectives' instead of 'sync').",
+               "wait as 'collectives' instead of 'sync'; 'step' "
+               "holds a whole scheduler step, 'wait' and 'fetch' "
+               "split 'sync', and 'compile' times a compile or cache "
+               "read).",
                labels=("phase",), buckets=_PHASE_BUCKETS),
     MetricSpec("serve_mesh_info", "gauge",
                "Info gauge (constant 1) carrying the serving engine's "
@@ -201,6 +215,16 @@ METRIC_CATALOG: Tuple[MetricSpec, ...] = (
                "Autotune candidates the compiler refused, with the "
                "refusal's one-line reason.",
                labels=("kernel", "site", "config", "reason")),
+    # -- compiles (process registry) ---------------------------------
+    MetricSpec("jit_compiles_total", "counter",
+               "Programs XLA compiled in this process (not read from the "
+               "persistent compilation cache), by jitted function.",
+               labels=("fun",)),
+    MetricSpec("compile_cache_hits_total", "counter",
+               "Programs read from JAX's persistent compilation cache."),
+    MetricSpec("compile_cache_misses_total", "counter",
+               "Compiled programs written to JAX's persistent compilation "
+               "cache (not found there first)."),
     # -- telemetry self-accounting ----------------------------------
     MetricSpec("trace_events_dropped_total", "counter",
                "Trace events dropped after the in-memory cap."),
@@ -281,8 +305,8 @@ class Telemetry:
 
     ``clock`` must be monotonic (it is used exclusively for durations);
     tests inject a fake.  ``profile=True`` additionally wraps every span
-    in ``jax.profiler.TraceAnnotation`` so host phases show up in device
-    traces.
+    in ``jax.profiler.TraceAnnotation`` (``StepTraceAnnotation`` for the
+    ``step`` span) so host phases show up in device traces.
     """
 
     def __init__(self, clock: Callable[[], float] = time.monotonic,
@@ -294,7 +318,8 @@ class Telemetry:
         self._histograms: Dict[Tuple[str, Tuple], Histogram] = {}
         self._events: List[dict] = []
         self._t0 = self.clock()
-        self._span_depth = 0
+        # the same instant on the wall clock, the profiler's time base
+        self.t0_wall_ns = time.time_ns()
 
     # -- registry ----------------------------------------------------
 
@@ -336,37 +361,51 @@ class Telemetry:
         self._events.append(ev)
 
     @contextlib.contextmanager
-    def span(self, name: str, **args) -> Iterator[None]:
+    def span(self, name: str, **args) -> Iterator[dict]:
         """Time a phase: histogram observation + Chrome-trace "X" event.
 
         Spans nest (context-manager discipline gives proper containment,
         which is all the Chrome trace format needs for same-thread
-        nesting).  ``**args`` become trace-event args (stringified).
+        nesting).  ``**args`` become trace-event args (stringified); the
+        span yields them, so an argument known only inside the span can
+        still be added.  The ``step`` span takes its step number as
+        ``n``.
         """
         prof = None
         if self.profile:
-            from jax.profiler import TraceAnnotation
+            from jax import profiler
 
-            prof = TraceAnnotation(name)
+            # XProf's step view groups device time by the "step" span
+            prof = (profiler.StepTraceAnnotation(name, step_num=args["n"])
+                    if name == "step" else profiler.TraceAnnotation(name))
             prof.__enter__()
         t0 = self.clock()
-        self._span_depth += 1
         try:
-            yield
+            yield args
         finally:
-            self._span_depth -= 1
             dur = self.clock() - t0
             if prof is not None:
                 prof.__exit__(None, None, None)
-            self.histogram("serve_phase_seconds", phase=name).observe(dur)
-            ev = {
-                "name": name, "ph": "X", "pid": 1, "tid": 1,
-                "ts": round((t0 - self._t0) * 1e6, 3),
-                "dur": round(dur * 1e6, 3),
-            }
-            if args:
-                ev["args"] = {k: str(v) for k, v in args.items()}
-            self._emit(ev)
+            self._record(name, t0, dur, args)
+
+    def span_at(self, name: str, start_s: float, end_s: float,
+                **args) -> None:
+        """Record a span that has already ended, from its start and end on
+        the wall clock (seconds since the epoch, as ``time.time()``):
+        back-dated onto the registry's clock through ``t0_wall_ns``."""
+        t0 = self._t0 + (start_s - self.t0_wall_ns * 1e-9)
+        self._record(name, t0, max(0.0, end_s - start_s), args)
+
+    def _record(self, name: str, t0: float, dur: float, args: dict) -> None:
+        self.histogram("serve_phase_seconds", phase=name).observe(dur)
+        ev = {
+            "name": name, "ph": "X", "pid": 1, "tid": 1,
+            "ts": round((t0 - self._t0) * 1e6, 3),
+            "dur": round(dur * 1e6, 3),
+        }
+        if args:
+            ev["args"] = {k: str(v) for k, v in args.items()}
+        self._emit(ev)
 
     def event(self, name: str, **args) -> None:
         """Instant (zero-duration) trace event, e.g. a fault injection."""
@@ -450,7 +489,10 @@ class Telemetry:
         return {
             "traceEvents": list(self._events),
             "displayTimeUnit": "ms",
-            "otherData": {"clock": "monotonic", "ts_unit": "us"},
+            # ts + t0_wall_ns / 1e3 is a span's start on the wall clock,
+            # the time base of a jax.profiler (XProf) trace's host events
+            "otherData": {"clock": "monotonic", "ts_unit": "us",
+                          "t0_wall_ns": self.t0_wall_ns},
         }
 
     def write_prometheus(self, path: str) -> None:
@@ -576,3 +618,69 @@ def record_autotune_refusal(kernel: str, site: str, config: str,
     default_registry().counter(
         "autotune_refused_total", kernel=kernel, site=site, config=config,
         reason=reason).inc()
+
+
+# -- compiles --------------------------------------------------------
+#
+# JAX reports each backend compile as a time span on the wall clock
+# (``time.time()``), with the jitted function's name.  The span covers
+# the persistent-cache lookup too: a hit inside it makes the span a
+# cache read.  Both fire on the compiling thread, so the hit is carried
+# per thread from the cache event to the span's end.
+
+_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_READ = "/jax/compilation_cache/cache_retrieval_time_sec"
+_in_compile = threading.local()
+_watching = False
+
+
+def _on_event(event: str, **_) -> None:
+    if event == _CACHE_HIT:
+        _in_compile.hit = True
+        default_registry().counter("compile_cache_hits_total").inc()
+    elif event == _CACHE_MISS:
+        default_registry().counter("compile_cache_misses_total").inc()
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    if event == _CACHE_READ:
+        _in_compile.read_s = duration
+
+
+def _on_time_span(event: str, start: float, end: float, **kw) -> None:
+    if event != _COMPILE:
+        return
+    hit = getattr(_in_compile, "hit", False)
+    read_s = getattr(_in_compile, "read_s", None)
+    _in_compile.hit, _in_compile.read_s = False, None
+    fun = str(kw.get("fun_name", ""))
+    tel = default_registry()
+    args = {"fun": fun, "source": "cache" if hit else "backend"}
+    if read_s is not None:
+        args["cache_read_s"] = round(read_s, 6)
+    tel.span_at("compile", start, end, **args)
+    if not hit:
+        tel.counter("jit_compiles_total", fun=fun).inc()
+
+
+def watch_compiles() -> None:
+    """Register the process's one listener for JAX's compiles and
+    persistent-cache reads (idempotent).  Runs when this module is
+    imported; nothing of it runs per step."""
+    global _watching
+    if _watching:
+        return
+    try:
+        from jax import monitoring
+    except ImportError:  # the registry itself needs no JAX
+        return
+    default_registry()  # its clock starts before the first compile
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_time_span_listener(_on_time_span)
+    _watching = True
+
+
+watch_compiles()

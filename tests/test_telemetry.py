@@ -277,6 +277,161 @@ def test_metric_catalog_names_unique_and_well_formed():
 
 
 # --------------------------------------------------------------------------- #
+# Step, wait/fetch and compile spans
+# --------------------------------------------------------------------------- #
+class _FakeLogits:
+    """Device logits stand-in: blocking takes 0.1 s of the fake clock,
+    the copy to the host 0.02 s."""
+
+    def __init__(self, clock):
+        self.clock = clock
+
+    def block_until_ready(self):
+        self.clock.advance(0.1)
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self.clock.advance(0.02)
+        return np.zeros((2, 8), np.float32)
+
+
+def test_step_sync_wait_fetch_nesting():
+    import types
+
+    clock = FakeClock()
+    tel = Telemetry(clock=clock)
+    eng = types.SimpleNamespace(tel=tel, _tp=1)
+    with tel.span("step", n=7) as step:
+        with tel.span("admit"):
+            clock.advance(0.001)
+        step["T"] = 1
+        out = serve.Engine.sync_logits(eng, _FakeLogits(clock))
+    assert out.shape == (2, 8)
+    ev = {e["name"]: e for e in tel.events}
+    # children close first; the step span closes last
+    assert [e["name"] for e in tel.events] == ["admit", "wait", "fetch",
+                                               "sync", "step"]
+    assert ev["wait"]["dur"] == pytest.approx(100_000.0)
+    assert ev["fetch"]["dur"] == pytest.approx(20_000.0)
+    assert ev["sync"]["dur"] == pytest.approx(120_000.0)
+    assert ev["step"]["dur"] == pytest.approx(121_000.0)
+    assert ev["step"]["args"] == {"n": "7", "T": "1"}
+    # containment: wait and fetch inside sync, everything inside step
+    for inner, outer in (("wait", "sync"), ("fetch", "sync"),
+                         ("sync", "step"), ("admit", "step")):
+        i, o = ev[inner], ev[outer]
+        assert o["ts"] <= i["ts"] and i["ts"] + i["dur"] <= o["ts"] + o["dur"]
+    assert ev["fetch"]["ts"] == pytest.approx(
+        ev["wait"]["ts"] + ev["wait"]["dur"])
+    # on a mesh the outer span is "collectives"
+    tel2 = Telemetry(clock=clock)
+    serve.Engine.sync_logits(types.SimpleNamespace(tel=tel2, _tp=2),
+                             _FakeLogits(clock))
+    assert [e["name"] for e in tel2.events] == ["wait", "fetch",
+                                                "collectives"]
+
+
+def test_step_span_opens_a_step_trace_annotation(monkeypatch):
+    import jax.profiler
+
+    opened = []
+
+    class Annotation:
+        def __init__(self, kind, name, **kw):
+            self.rec = (kind, name, kw)
+
+        def __enter__(self):
+            opened.append(("enter",) + self.rec)
+
+        def __exit__(self, *exc):
+            opened.append(("exit",) + self.rec)
+
+    monkeypatch.setattr(jax.profiler, "StepTraceAnnotation",
+                        lambda name, **kw: Annotation("step", name, **kw))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda name, **kw: Annotation("plain", name, **kw))
+    tel = Telemetry(clock=FakeClock(), profile=True)
+    with tel.span("step", n=3):
+        with tel.span("wait"):
+            pass
+    assert opened == [("enter", "step", "step", {"step_num": 3}),
+                      ("enter", "plain", "wait", {}),
+                      ("exit", "plain", "wait", {}),
+                      ("exit", "step", "step", {"step_num": 3})]
+    # without profile=True no annotation is opened
+    opened.clear()
+    with Telemetry(clock=FakeClock()).span("step", n=4):
+        pass
+    assert opened == []
+
+
+def test_fresh_jit_yields_one_compile_span_and_a_second_call_none():
+    import jax
+    import jax.numpy as jnp
+
+    def fresh_for_the_compile_span_test(x):
+        return x * 3.0 + 1.0
+
+    fun = "jit(fresh_for_the_compile_span_test)"
+    reg = default_registry()
+    f = jax.jit(fresh_for_the_compile_span_test)
+    x = jnp.arange(5.0)
+
+    def compiles():
+        return [e for e in reg.events if e["name"] == "compile"
+                and e["args"]["fun"] == fun]
+
+    assert compiles() == []
+    f(x).block_until_ready()
+    spans = compiles()
+    assert len(spans) == 1
+    assert spans[0]["args"]["source"] == "backend"
+    assert spans[0]["dur"] > 0
+    assert reg.counter_value("jit_compiles_total", fun=fun) == 1
+    f(x).block_until_ready()
+    assert len(compiles()) == 1
+    assert reg.counter_value("jit_compiles_total", fun=fun) == 1
+
+
+def test_compile_listener_back_dates_spans_and_marks_cache_reads(
+        monkeypatch):
+    clock = FakeClock(50.0)
+    reg = Telemetry(clock=clock)
+    reg.t0_wall_ns = 1_000_000_000_000  # the wall clock at t=50.0
+    monkeypatch.setattr(telemetry_mod, "_DEFAULT", reg)
+    # a backend compile from wall 1002.0 s to 1005.5 s
+    telemetry_mod._on_time_span(telemetry_mod._COMPILE, 1002.0, 1005.5,
+                                fun_name="jit(a)")
+    # a cache read: the hit and its read time come inside the span
+    telemetry_mod._on_event(telemetry_mod._CACHE_HIT)
+    telemetry_mod._on_duration(telemetry_mod._CACHE_READ, 0.25)
+    telemetry_mod._on_time_span(telemetry_mod._COMPILE, 1010.0, 1010.5,
+                                fun_name="jit(b)")
+    telemetry_mod._on_event(telemetry_mod._CACHE_MISS)
+    telemetry_mod._on_time_span("/jax/other", 1.0, 2.0)  # not a compile
+    a, b = reg.events
+    assert (a["name"], a["ts"], a["dur"]) == ("compile", 2_000_000.0,
+                                              3_500_000.0)
+    assert a["args"] == {"fun": "jit(a)", "source": "backend"}
+    assert (b["ts"], b["dur"]) == (10_000_000.0, 500_000.0)
+    assert b["args"] == {"fun": "jit(b)", "source": "cache",
+                         "cache_read_s": "0.25"}
+    assert reg.counter_value("jit_compiles_total", fun="jit(a)") == 1
+    assert reg.counter_value("jit_compiles_total", fun="jit(b)") == 0
+    assert reg.counter_value("compile_cache_hits_total") == 1
+    assert reg.counter_value("compile_cache_misses_total") == 1
+    assert reg.histogram("serve_phase_seconds", phase="compile").count == 2
+
+
+def test_chrome_trace_carries_the_wall_clock_anchor():
+    before = __import__("time").time_ns()
+    tel = Telemetry(clock=FakeClock())
+    other = tel.to_chrome_trace()["otherData"]
+    assert other["t0_wall_ns"] == tel.t0_wall_ns
+    assert before <= tel.t0_wall_ns <= __import__("time").time_ns()
+
+
+# --------------------------------------------------------------------------- #
 # Scheduler lifecycle metrics (real engine, smoke scale)
 # --------------------------------------------------------------------------- #
 def test_scheduler_lifecycle_metrics(cfg):
