@@ -12,14 +12,16 @@ traffic is ~half of a bf16 cache, and no float multiplier touches the QK^T
 products.
 
 Structure: flash-decoding style two-phase split.  Phase 1 (the Pallas
-kernel, grid (B, max_pages), both axes parallel) emits per-page softmax
-partials (m, l, unnormalized o) — pages are independent, so there is no
-sequential carry and the grid parallelizes freely.  Phase 2 (plain jnp,
-shared verbatim by the kernel wrapper and the pure-JAX reference) merges
-the partials with the standard log-sum-exp combine.  Block tables and
-per-slot lengths ride in as scalar-prefetch operands so the k/v BlockSpec
-index maps can gather pages (``bt[b, j]``); pages a slot does not own are
-masked out entirely and contribute weight exp(-inf) = 0 in the combine.
+kernel, one program per block of slots) emits per-page softmax partials
+(m, l, unnormalized o) — pages are independent, so there is no sequential
+carry.  Each program walks only the pages of its slots that hold a row:
+block tables and per-slot lengths ride in as scalar-prefetch operands, the
+K/V pools stay in HBM, and each live page is gathered by its own DMA
+(``bt[b, j]``), double-buffered behind the previous block's compute.  A
+page with no valid row (past a slot's length, or outside its window) has
+the partial (NEG_INF, 0, 0) in every impl: weight 0 in the combine.
+Phase 2 (plain jnp, shared verbatim by the kernel wrapper and the pure-JAX
+reference) merges the partials with the standard log-sum-exp combine.
 
 Numerics contract: ``impl="kernel"`` (interpret on CPU) and ``impl="batch"``
 (natively vectorized phase 1, the CPU serving path) are bit-identical to
@@ -51,6 +53,10 @@ from ..core.quant import encode
 from .common import code_to_f32, lns_combine, lns_prepare
 
 NEG_INF = -2.0e30
+
+# VMEM for one kernel program's output blocks (the double-buffered per-page
+# partials); a longer max_seq takes more programs, not more VMEM.
+PARTIALS_VMEM = 4 * 1024 * 1024
 
 
 # --------------------------------------------------------------------------- #
@@ -125,8 +131,9 @@ def _page_partial(
     q_op: (codes [KV, G, hd], scale) for LNS pages, or float q [KV, G, hd]
     for float pages.  k_page/v_page: [page, KV, hd|dv] codes or float.
     t0: global position of the page's first row; length: valid tokens for
-    this slot.  A fully masked page yields m = -inf -> zero weight in the
-    combine.  ``o`` is the p·V product before the 1/l normalization.
+    this slot.  A fully masked page yields (m, l, o) = (NEG_INF, 0, 0):
+    zero weight in the combine, and the partial the kernel leaves on a
+    page it skips.  ``o`` is the p·V product before the 1/l normalization.
 
     ``pin`` wraps every order-sensitive f32 reduction in
     ``optimization_barrier``: XLA CPU traces this body both in plain jit
@@ -159,7 +166,8 @@ def _page_partial(
     s = jnp.where(ok, s, NEG_INF)
 
     m = s.max(axis=-1)
-    p = jnp.exp(s - m[..., None])
+    # a masked row weighs exactly 0, also on a page with no valid row
+    p = jnp.where(ok, jnp.exp(s - m[..., None]), 0.0)
     l = _rowsum(p, pin)
     # [KV, G, page] x [page, KV, dv] -> [KV, G, dv], batched over KV
     o = jax.lax.dot_general(
@@ -329,7 +337,7 @@ def _batch_partials(
     s = pin(jnp.where(ok, s, NEG_INF))
 
     m = pin(s.max(axis=-1))                              # [B, maxp, KV, G]
-    p = pin(jnp.exp(pin(s - m[..., None])))
+    p = pin(jnp.where(ok, pin(jnp.exp(pin(s - m[..., None]))), 0.0))
     # Page-row sum as a dot against ones: XLA CPU lowers dots consistently
     # across graph contexts, while reduce-sum vectorization is context
     # dependent (1-ulp drift when e.g. cache scatters share the graph).
@@ -410,19 +418,37 @@ def combine_partials_psum(m, l, o, axis_name: str):
 # --------------------------------------------------------------------------- #
 # Pallas kernel
 # --------------------------------------------------------------------------- #
+def walked_pages(lengths, page, maxp, xp=jnp):
+    """Pages the kernel walks for a slot of attend length ``lengths``: the
+    pages that hold a row, ``ceil(n/page)``, at least page 0 (a slot with
+    no row walks it fully masked) and at most ``maxp``.  ``xp=np``
+    reckons it on the host, with no device op."""
+    return xp.clip((lengths + page - 1) // page, 1, maxp)
+
+
 def _paged_kernel(
-    *refs, fmt, mode, page, KV, G, window, cap, spb, ppb, fused, pin,
+    *refs, fmt, mode, page, KV, G, window, cap, spb, ppb, cpp, maxp, fused,
+    pin,
 ):
-    """Grid (Bp/spb, Jp/ppb) program: spb slots x ppb pages of partials.
+    """Grid (Bp/spb, ceil(maxp/cpp)) program: walks the live pages of
+    ``spb`` slots among the ``cpp`` pages of its chunk of ``max_seq``.
+
+    A slot holds rows in the pages :func:`walked_pages` counts; the
+    program visits those of its chunk in blocks of ``ppb`` pages and no
+    others.  The K/V pools stay in HBM: each live page is gathered
+    through the scalar-prefetched block table by its own DMA into a
+    double-buffered VMEM block, and the next block's copies (the next
+    slot's first block after a slot's last) are started before this
+    block's compute.  Every page the walk skips keeps the partial a page
+    without a valid row has, ``(NEG_INF, 0, 0)``.
 
     Scalars ride in as scalar-prefetch (SMEM) operands: block tables,
     lengths, per-slot q scales and the per-(slot, page) K/V scales gathered
-    through the block table, so SMEM holds [Bp, Jp] values however large
+    through the block table, so SMEM holds [Bp, maxp] values however large
     the page pool is.  With ``fused`` the new token's row codes ride in as
     extra operands and are spliced into the gathered page block in-register
     before the partial — the kernel never reads the scattered cache arrays.
     """
-    n = spb * ppb
     npre = 8 if fused else 5
     bt_ref, len_ref, qs_ref, ks_ref, vs_ref = refs[:5]
     if fused:
@@ -431,27 +457,60 @@ def _paged_kernel(
         refs = refs[npre + 2:]
     else:
         refs = refs[npre:]
-    q_ref = refs[0]
-    kp_refs = refs[1:1 + n]
-    vp_refs = refs[1 + n:1 + 2 * n]
-    m_ref, l_ref, o_ref = refs[1 + 2 * n:]
+    q_ref, k_hbm, v_hbm, m_ref, l_ref, o_ref, k_buf, v_buf, sem = refs
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    lo = pl.program_id(1) * cpp     # the chunk's first page
+
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
     def widen(codes):
         # 8-bit codes widen to int32 on load (the LNS prepare does the same
         # cast): Mosaic lowers the masks and selects on 32-bit layouts only
         return codes.astype(jnp.int32) if fmt is not None else codes
 
-    for i in range(spb):
+    def live_pages(i):
+        """Pages of slot ``i`` walked in this chunk."""
+        n = walked_pages(len_ref[b * spb + i], page, maxp)
+        return jnp.clip(n - lo, 0, cpp)
+
+    def n_blocks(i):
+        return (live_pages(i) + ppb - 1) // ppb
+
+    def each_live_page(i, blk, fn):
+        """``fn(jj, jp)`` for the pages of block ``blk`` that hold a row
+        (a loop, so the body is compiled once for every block shape)."""
+        n = jnp.minimum(ppb, live_pages(i) - blk * ppb)
+
+        def body(jj, carry):
+            fn(jj, lo + blk * ppb + jj)
+            return carry
+        jax.lax.fori_loop(0, n, body, 0)
+
+    def copies(i, jp, buf, jj):
+        pid = bt_ref[b * spb + i, jp]
+        return (pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, jj],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, jj],
+                                      sem.at[1, buf]))
+
+    def fetch(i, blk, buf):
+        def start(jj, jp):
+            for c in copies(i, jp, buf, jj):
+                c.start()
+        each_live_page(i, blk, start)
+
+    def compute(i, blk, buf):
         bs = b * spb + i
         q = widen(q_ref[i])
         q_op = (q, qs_ref[bs]) if fmt is not None else q
-        for jj in range(ppb):
-            idx = i * ppb + jj
-            jp = j * ppb + jj
-            kp_blk = widen(kp_refs[idx][0])
-            vp_blk = widen(vp_refs[idx][0])
+
+        def one(jj, jp):
+            for c in copies(i, jp, buf, jj):
+                c.wait()
+            kp_blk = _unflatten_page(widen(k_buf[buf, jj]), page, KV)
+            vp_blk = _unflatten_page(widen(v_buf[buf, jj]), page, KV)
             if fused:
                 hit = (log_ref[bs] == jp) & (msk_ref[bs] != 0)
                 row = jax.lax.broadcasted_iota(
@@ -463,28 +522,88 @@ def _paged_kernel(
                 jp * page, len_ref[bs],
                 fmt=fmt, mode=mode, window=window, cap=cap, pin=pin,
             )
-            m_ref[i, jj] = m
-            l_ref[i, jj] = l
-            o_ref[i, jj] = o
+            m_ref[i, jp - lo] = m
+            l_ref[i, jp - lo] = l
+            o_ref[i, jp - lo] = o
+        each_live_page(i, blk, one)
+
+    def slot(i, carry):
+        done, ready = carry     # ready: slot i's first block is in flight
+        nblk = n_blocks(i)
+        pl.when((nblk > 0) & (ready == 0))(lambda: fetch(i, 0, done % 2))
+        nxt = jnp.minimum(i + 1, spb - 1)
+        ahead = (i + 1 < spb) & (n_blocks(nxt) > 0)
+
+        def block(blk, done):
+            buf = done % 2      # blocks walked so far pick the buffer
+            pl.when(blk + 1 < nblk)(lambda: fetch(i, blk + 1, 1 - buf))
+            pl.when((blk + 1 == nblk) & ahead)(lambda: fetch(nxt, 0, 1 - buf))
+            compute(i, blk, buf)
+            return done + 1
+        done = jax.lax.fori_loop(0, nblk, block, done)
+        return done, (ahead & (nblk > 0)).astype(jnp.int32)
+
+    jax.lax.fori_loop(0, spb, slot, (jnp.int32(0), jnp.int32(0)))
+
+
+def _page_rows(page, KV, hd):
+    """(rows, lanes) of the 2-D view a page is gathered in.
+
+    A DMA moves whole (sublane, 128-lane) tiles, so a page is gathered as
+    rows of at least 128 lanes: ``[page, KV*hd]`` when ``KV*hd`` is a
+    multiple of 128; ``[page*KV*hd/128, 128]`` when a whole number of page
+    rows fills each 128-lane row; else ``[page, KV*hd]``, which the
+    compiler may refuse.
+    """
+    width = KV * hd
+    if width % 128 and 128 % width == 0 and page * width % 128 == 0:
+        return page * width // 128, 128
+    return page, width
+
+
+def _unflatten_page(x, page, KV):
+    """[rows, lanes] gathered page (row-major bytes of the page) ->
+    [page, KV, hd], by lane slices and stacks only: Mosaic has no general
+    reshape that splits the lane axis."""
+    rows, lanes = x.shape
+    if rows != page:      # several page rows share one 128-lane row
+        n = page // rows
+        w = lanes // n
+        x = jnp.stack([x[:, i * w:(i + 1) * w] for i in range(n)],
+                      axis=1).reshape(page, w)
+    hd = x.shape[1] // KV
+    if KV == 1:
+        return x[:, None, :]
+    return jnp.stack([x[:, h * hd:(h + 1) * hd] for h in range(KV)], axis=1)
 
 
 def _pad_rows(x, n):
     return x if n == 0 else jnp.pad(x, ((0, n),) + ((0, 0),) * (x.ndim - 1))
 
 
-def _paged_kernel_call(
+def _paged_kernel_call(q_in, q_scale, k_pages, v_pages, k_scale, v_scale,
+                       block_tables, lengths, **kw):
+    """The paged kernel's attention: its partials, then the combine."""
+    return _combine_partials(*_paged_kernel_partials(
+        q_in, q_scale, k_pages, v_pages, k_scale, v_scale, block_tables,
+        lengths, **kw))
+
+
+def _paged_kernel_partials(
     q_in, q_scale, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
     *, fmt, mode, page_size, KV, G, window, cap, interpret,
-    ppb: int = 1, spb: int = 1, inserts=None,
+    ppb: int = 1, spb: int = 1, cpp: Optional[int] = None, inserts=None,
 ):
-    """Launch the paged kernel on a (slots/spb, pages/ppb) grid.
+    """Launch the paged kernel: per-page partials (m, l, o) shaped
+    [B, maxp, KV, G(, dv)], as :func:`_batch_partials`.
 
-    ``ppb`` pages x ``spb`` slots per program (the autotuned block shape):
-    each gathered page is its own operand so the BlockSpec index maps stay
-    single-page gathers.  Slot/page axes are padded up to the block shape —
-    padded lanes carry length 0 / the null page, are fully masked by the
-    shared partial (m = -inf), and are sliced off before the combine so the
-    result is bit-identical for every (ppb, spb).
+    ``spb`` slots per program, walked ``ppb`` pages per block, among the
+    ``cpp`` pages of the program's chunk of ``max_seq`` (by default
+    :func:`pages_per_program`).  The slot axis is padded up to ``spb`` —
+    padded lanes carry length 0 and page 0 of their block table, walk
+    that one fully masked page, and are sliced off, as are the pages the
+    last chunk holds past ``maxp``, so the result is bit-identical for
+    every (ppb, spb, cpp).
 
     Under a serving mesh that shards KV heads (``parallel.hints``) the
     launch runs under ``shard_map`` on each shard's KV heads: GSPMD cannot
@@ -496,9 +615,8 @@ def _paged_kernel_call(
     B, H, hd = q_in.shape
     maxp = block_tables.shape[1]
     Bp = -(-B // spb) * spb
-    Jp = -(-maxp // ppb) * ppb
-    pad_b, pad_j = Bp - B, Jp - maxp
-    bt = jnp.pad(block_tables, ((0, pad_b), (0, pad_j)))
+    pad_b = Bp - B
+    bt = _pad_rows(block_tables, pad_b)
     prefetch = [bt, _pad_rows(lengths, pad_b), _pad_rows(q_scale, pad_b),
                 k_scale[bt], v_scale[bt]]
     args = [_pad_rows(q_in, pad_b).reshape(Bp, KV, G, hd), k_pages, v_pages]
@@ -513,7 +631,7 @@ def _paged_kernel_call(
     launch = functools.partial(
         _paged_pallas, n_prefetch=len(prefetch), fmt=fmt, mode=mode,
         page_size=page_size, window=window, cap=cap, interpret=interpret,
-        ppb=ppb, spb=spb,
+        ppb=ppb, spb=spb, cpp=cpp,
     )
     sharded = kv_head_mesh()
     if sharded is not None:
@@ -527,51 +645,66 @@ def _paged_kernel_call(
             out_specs=(P(None, None, ax),) * 3, check_vma=False,
         )
     m, l, o = launch(*prefetch, *args)
-    return _combine_partials(m[:B, :maxp], l[:B, :maxp], o[:B, :maxp])
+    return m[:B, :maxp], l[:B, :maxp], o[:B, :maxp]
+
+
+def _f32_tile_bytes(rows, cols):
+    """VMEM of a [rows, cols] f32 array: 8 x 128 tiles."""
+    return -(-rows // 8) * 8 * -(-cols // 128) * 128 * 4
+
+
+def pages_per_program(spb, maxp, KV, G, dv):
+    """Pages of ``max_seq`` one kernel program covers: ``maxp`` cut into
+    the fewest equal chunks whose output blocks — the double-buffered
+    partials of ``spb`` slots, m and l [KV, G] and o [KV, G, dv] f32 per
+    page — fit :data:`PARTIALS_VMEM`, so the kernel's VMEM does not grow
+    with ``max_seq``."""
+    page_bytes = 2 * _f32_tile_bytes(KV, G) + KV * _f32_tile_bytes(G, dv)
+    fit = max(1, PARTIALS_VMEM // (2 * spb * page_bytes))
+    return -(-maxp // -(-maxp // fit))
 
 
 def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
-                  interpret, ppb, spb):
-    """The pallas_call of :func:`_paged_kernel_call` on (possibly
+                  interpret, ppb, spb, cpp):
+    """The pallas_call of :func:`_paged_kernel_partials` on (possibly
     shard-local) operands: scalar prefetch, q [Bp, KV, G, hd], the page
-    arrays, then the fused row inserts [Bp, KV, hd] x2 when present."""
+    arrays (left in HBM), then the fused row inserts [Bp, KV, hd] x2 when
+    present."""
     prefetch = args[:n_prefetch]
     q, k_pages, v_pages, *rows = args[n_prefetch:]
     Bp, KV, G, hd = q.shape
     dv = v_pages.shape[-1]
-    Jp = prefetch[0].shape[1]
+    maxp = prefetch[0].shape[1]
+    cpp = cpp or pages_per_program(spb, maxp, KV, G, dv)
+    Jp = -(-maxp // cpp) * cpp
     fused = bool(rows)
     kernel = functools.partial(
         _paged_kernel, fmt=fmt, mode=mode, page=page_size, KV=KV, G=G,
-        window=window, cap=cap, spb=spb, ppb=ppb, fused=fused,
-        pin=bool(interpret),
+        window=window, cap=cap, spb=spb, ppb=ppb, cpp=cpp, maxp=maxp,
+        fused=fused, pin=bool(interpret),
     )
-
-    def page_spec(shape, i, jj):
-        def ix(b, j, *pref):
-            bt_p = pref[0]
-            return (bt_p[b * spb + i, j * ppb + jj],) + (0,) * (len(shape) - 1)
-        return pl.BlockSpec(shape, ix)
 
     def slot_spec(x):
         return pl.BlockSpec((spb,) + x.shape[1:],
-                            lambda b, j, *pref: (b,) + (0,) * (x.ndim - 1))
+                            lambda b, c, *pref: (b,) + (0,) * (x.ndim - 1))
 
-    in_specs = [slot_spec(r) for r in rows] + [slot_spec(q)]
-    in_specs += [page_spec((1, page_size, KV, hd), i, jj)
-                 for i in range(spb) for jj in range(ppb)]
-    in_specs += [page_spec((1, page_size, KV, dv), i, jj)
-                 for i in range(spb) for jj in range(ppb)]
-    operands = rows + [q] + [k_pages] * (spb * ppb) + [v_pages] * (spb * ppb)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    k_rows = _page_rows(page_size, KV, hd)
+    v_rows = _page_rows(page_size, KV, dv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
-        grid=(Bp // spb, Jp // ppb),
-        in_specs=in_specs,
+        grid=(Bp // spb, Jp // cpp),
+        in_specs=[slot_spec(r) for r in rows] + [slot_spec(q), hbm, hbm],
         out_specs=[
-            pl.BlockSpec((spb, ppb, KV, G), lambda b, j, *pref: (b, j, 0, 0)),
-            pl.BlockSpec((spb, ppb, KV, G), lambda b, j, *pref: (b, j, 0, 0)),
-            pl.BlockSpec((spb, ppb, KV, G, dv),
-                         lambda b, j, *pref: (b, j, 0, 0, 0)),
+            pl.BlockSpec((spb, cpp, KV, G), lambda b, c, *pref: (b, c, 0, 0)),
+            pl.BlockSpec((spb, cpp, KV, G), lambda b, c, *pref: (b, c, 0, 0)),
+            pl.BlockSpec((spb, cpp, KV, G, dv),
+                         lambda b, c, *pref: (b, c, 0, 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb) + k_rows, k_pages.dtype),
+            pltpu.VMEM((2, ppb) + v_rows, v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     return pl.pallas_call(
@@ -586,7 +719,8 @@ def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,
-    )(*prefetch, *operands)
+    )(*prefetch, *rows, q, k_pages.reshape((-1,) + k_rows),
+      v_pages.reshape((-1,) + v_rows))
 
 
 # --------------------------------------------------------------------------- #
@@ -646,13 +780,13 @@ def paged_decode_attention(
 @functools.partial(
     jax.jit,
     static_argnames=("fmt", "n_kv_heads", "mode", "window", "cap", "impl",
-                     "interpret", "ppb", "spb"),
+                     "interpret", "ppb", "spb", "cpp"),
 )
 def _paged_decode_attention(
     q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
     fmt: Optional[str], n_kv_heads: int, mode: str,
     window: int, cap: float, impl: str, interpret: bool,
-    ppb: int = 1, spb: int = 1,
+    ppb: int = 1, spb: int = 1, cpp: Optional[int] = None,
 ):
     B, one, H, hd = q.shape
     assert one == 1, "paged decode attention is single-position"
@@ -688,7 +822,7 @@ def _paged_decode_attention(
             q_arr, q_scale, k_pages, v_pages, k_scale, v_scale,
             block_tables, lengths, fmt=fmt, mode=mode,
             page_size=k_pages.shape[1], KV=KV, G=G, window=window, cap=cap,
-            interpret=interpret, ppb=ppb, spb=spb,
+            interpret=interpret, ppb=ppb, spb=spb, cpp=cpp,
         )
     else:
         raise ValueError(f"unknown impl {impl!r}")
@@ -743,15 +877,24 @@ def fused_decode_write_attend(
     return out, new_kp, new_ks, new_vp, new_vs
 
 
+def _set_rows(pages, pids, rows, row):
+    """Write one [KV, hd] row per lane through the [P, page, KV*hd] view
+    the kernel gathers from, so the compiler keeps one layout of the pool
+    for the write and the attend."""
+    P, page, KV, hd = pages.shape
+    flat = pages.reshape(P, page, KV * hd)
+    return flat.at[pids, rows].set(row.reshape(-1, KV * hd)).reshape(pages.shape)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("fmt", "n_kv_heads", "mode", "kv_mode", "window", "cap",
-                     "impl", "interpret", "ppb", "spb"),
+                     "impl", "interpret", "ppb", "spb", "cpp"),
 )
 def _fused_decode_write_attend(
     q, k_new, v_new, k_pages, v_pages, k_scale, v_scale, block_tables,
     lengths, k_key, v_key, write_mask, *, fmt, n_kv_heads, mode, kv_mode,
-    window, cap, impl, interpret, ppb, spb,
+    window, cap, impl, interpret, ppb, spb, cpp=None,
 ):
     from ..serving.page_pool import token_row_codes
 
@@ -775,8 +918,8 @@ def _fused_decode_write_attend(
         write_mask=write_mask,
     )
     # cache carry for the next step — off the attention's critical path
-    new_kp = k_pages.at[pids_k, rows].set(k_row)
-    new_vp = v_pages.at[pids_v, rows].set(v_row)
+    new_kp = _set_rows(k_pages, pids_k, rows, k_row)
+    new_vp = _set_rows(v_pages, pids_v, rows, v_row)
     if fmt is not None:
         new_ks = k_scale.at[pids_k].set(ks_new)
         new_vs = v_scale.at[pids_v].set(vs_new)
@@ -822,7 +965,7 @@ def _fused_decode_write_attend(
             q_arr, q_scale, k_pages, v_pages, new_ks, new_vs,
             block_tables, attend_len, fmt=fmt, mode=mode,
             page_size=page_size, KV=KV, G=G, window=window, cap=cap,
-            interpret=interpret, ppb=ppb, spb=spb,
+            interpret=interpret, ppb=ppb, spb=spb, cpp=cpp,
             inserts=(k_row, v_row, logical, rows, mask),
         )
     else:

@@ -60,6 +60,7 @@ import numpy as np
 from .. import numerics
 from ..caches import enable_compile_cache
 from ..configs import get_config
+from ..kernels.paged_attention import walked_pages
 from ..models import Model
 from ..serving import ContinuousScheduler, PagePool, Request
 from ..serving.page_pool import invariant_checks_enabled
@@ -211,6 +212,12 @@ class Engine:
                 self.model.step_paged,
                 static_argnames=("page_size", "fused"), donate_argnums=(1,),
             )
+            # the GQA layers whose attention walks the page pool
+            self._paged_layers = sum(
+                e["self"]["kp"].shape[0] if stacked else 1
+                for stacked, entries in ((False, self.cache["prefix"]),
+                                         (True, self.cache["blocks"]))
+                for e in entries if "kp" in e.get("self", {}))
             # device mirror of pool.block_tables, re-uploaded only when the
             # pool's version moves (one transfer per mutating step instead
             # of one per slot per step; pinned by tests)
@@ -520,6 +527,18 @@ class Engine:
             self.tel.counter("host_transfers_total").inc()
         return self._bt_device
 
+    def _count_pages(self, attend: np.ndarray) -> None:
+        """``paged_attn_pages_total`` of one model call, from the attend
+        lengths ``attend`` [sub-steps, slots] the host holds: the pages
+        the paged kernel walks and the pages a walk of every slot's
+        ``max_seq`` visits."""
+        walked = walked_pages(attend, self.page_size,
+                              self.max_pages_per_slot, xp=np)
+        self.tel.counter("paged_attn_pages_total", kind="walked").inc(
+            self._paged_layers * int(walked.sum()))
+        self.tel.counter("paged_attn_pages_total", kind="grid").inc(
+            self._paged_layers * attend.size * self.max_pages_per_slot)
+
     # ------------------------------------------------------------------ #
     def _prefill_batch_inputs(self, prompts: List[np.ndarray]):
         cfg = self.cfg
@@ -694,6 +713,7 @@ class Engine:
             self.pool.ensure_capacity_batch(np.where(active, lengths + 1, 0))
             self._assert_writable(lengths, active.astype(np.int32))
             tables = self._device_block_tables()
+            self._count_pages(lengths[None] + 1)
         with self.tel.span("decode"), self._hints():
             logits, self.cache = self._decode_paged(
                 self.params, self.cache, jnp.asarray(tokens, jnp.int32),
@@ -723,8 +743,13 @@ class Engine:
         (resolve with :meth:`sync_logits` at the emission boundary).
         """
         with self.tel.span("host"):
-            self._assert_writable(np.asarray(lengths), np.asarray(n_new))
+            lengths, n_new = np.asarray(lengths), np.asarray(n_new)
+            self._assert_writable(lengths, n_new)
             tables = self._device_block_tables()
+            # sub-step t attends at lengths + min(t, n_new - 1) + 1
+            t = np.arange(np.shape(tokens)[1])[:, None]
+            self._count_pages(lengths + np.minimum(t, np.maximum(n_new - 1, 0))
+                              + 1)
         # a step carrying any prefill chunk is charged to "prefill" (the
         # chunk dominates its T=chunk trace); pure decode steps to "decode"
         phase = "decode" if all(int(n) <= 1 for n in n_new) else "prefill"

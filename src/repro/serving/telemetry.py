@@ -215,6 +215,14 @@ METRIC_CATALOG: Tuple[MetricSpec, ...] = (
                "Autotune candidates the compiler refused, with the "
                "refusal's one-line reason.",
                labels=("kernel", "site", "config", "reason")),
+    MetricSpec("paged_attn_pages_total", "counter",
+               "Pages of the paged attention, summed over layers, sub-"
+               "steps and slots of each model call, reckoned on the host "
+               "from the attend lengths: kind=walked counts the pages the "
+               "kernel visits (those holding a row, at least one per "
+               "slot), kind=grid the pages a walk of every slot's max_seq "
+               "would visit.",
+               labels=("kind",)),
     # -- compiles (process registry) ---------------------------------
     MetricSpec("jit_compiles_total", "counter",
                "Programs XLA compiled in this process (not read from the "

@@ -117,3 +117,13 @@ def test_autotuned_matmul_matches_pinned_blocks():
     auto = lns_matmul(x, w, fmt="e4m3", interpret=True)
     pinned = lns_matmul(x, w, fmt="e4m3", interpret=True, blocks=(64, 48, 32, 8))
     np.testing.assert_allclose(np.asarray(auto), np.asarray(pinned), rtol=1e-6)
+
+
+def test_paged_blocks_is_the_fixed_walk_block(tuner_cache, monkeypatch):
+    """The paged kernel's block shape is a constant: not measured even when
+    measuring is forced, and not read from or written to the cache."""
+    shape = dict(B=2, maxp=3, page=4, KV=1, G=2, hd=8)
+    monkeypatch.setenv("REPRO_AUTOTUNE", "force")
+    assert (autotune.paged_blocks(**shape, fmt="e4m3", interpret=True)
+            == autotune.PAGED_BLOCKS)
+    assert not tuner_cache.exists() or "paged" not in tuner_cache.read_text()

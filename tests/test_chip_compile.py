@@ -20,12 +20,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import paged_attention as pa
+from repro.kernels.autotune import PAGED_BLOCKS
 from repro.kernels.fp8_elementwise import _fp8_elementwise
 from repro.kernels.lns_matmul import _lns_matmul
 
 KV, G, HD, PAGE = 2, 7, 64, 16  # qwen2-0.5b attention geometry, 16-token pages
 SLOTS, MAXP = 8, 32             # 8 slots of up to 512 tokens
 POOL = 65536                    # pages: a deployment-sized pool
+# the decode-long cell: 64 slots of up to 1536 tokens, a pool sized to them
+LONG_SLOTS, LONG_MAXP = 64, 96
+LONG_POOL = LONG_SLOTS * LONG_MAXP + 1
 
 
 @pytest.fixture(scope="module")
@@ -53,37 +57,84 @@ def _compile_text(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _page_args():
-    return [((SLOTS, 1, KV * G, HD), jnp.bfloat16),
-            ((POOL, PAGE, KV, HD), jnp.uint8), ((POOL, PAGE, KV, HD), jnp.uint8),
-            ((POOL,), jnp.float32), ((POOL,), jnp.float32),
-            ((SLOTS, MAXP), jnp.int32), ((SLOTS,), jnp.int32)]
+def _page_args(slots=SLOTS, maxp=MAXP, pool=POOL, kv=KV, g=G, hd=HD):
+    return [((slots, 1, kv * g, hd), jnp.bfloat16),
+            ((pool, PAGE, kv, hd), jnp.uint8), ((pool, PAGE, kv, hd), jnp.uint8),
+            ((pool,), jnp.float32), ((pool,), jnp.float32),
+            ((slots, maxp), jnp.int32), ((slots,), jnp.int32)]
+
+
+def _compile_unfused(one_chip, ppb, spb, cpp=None, kv=KV, **geom):
+    """(pages per walked block, slots per program, pages per program) of
+    the live-page walk."""
+    def unfused(q, kp, vp, ks, vs, bt, ln):
+        return pa._paged_decode_attention(
+            q, kp, vp, ks, vs, bt, ln, fmt="e5m2", n_kv_heads=kv, mode="rne",
+            window=0, cap=0.0, impl="kernel", interpret=False, ppb=ppb,
+            spb=spb, cpp=cpp)
+
+    return _compile_text(unfused, one_chip, *_page_args(kv=kv, **geom))
+
+
+def _compile_fused(one_chip, ppb, spb, cpp=None, slots=SLOTS, kv=KV, hd=HD,
+                   **geom):
+    def fused(q, kp, vp, ks, vs, bt, ln, k_new, v_new, keys, mask):
+        return pa._fused_decode_write_attend(
+            q, k_new, v_new, kp, vp, ks, vs, bt, ln, keys, keys, mask,
+            fmt="e5m2", n_kv_heads=kv, mode="rne", kv_mode="stochastic",
+            window=0, cap=0.0, impl="kernel", interpret=False, ppb=ppb,
+            spb=spb, cpp=cpp)
+
+    return _compile_text(
+        fused, one_chip, *_page_args(slots=slots, kv=kv, hd=hd, **geom),
+        ((slots, kv, hd), jnp.float32), ((slots, kv, hd), jnp.float32),
+        ((slots, 2), jnp.uint32), ((slots,), jnp.bool_))
+
+
+# pages per program where a case cuts the 32 pages into chunks
+CHUNKED = {(4, 2): 8, (1, 4): 12, (2, 2): 12}
 
 
 @pytest.mark.parametrize("ppb,spb", [(1, 1), (2, 1), (4, 2), (1, 4)])
 def test_paged_kernel_compiles(one_chip, ppb, spb):
-    def unfused(q, kp, vp, ks, vs, bt, ln):
-        return pa._paged_decode_attention(
-            q, kp, vp, ks, vs, bt, ln, fmt="e5m2", n_kv_heads=KV, mode="rne",
-            window=0, cap=0.0, impl="kernel", interpret=False, ppb=ppb,
-            spb=spb)
-
-    assert "tpu_custom_call" in _compile_text(unfused, one_chip, *_page_args())
+    text = _compile_unfused(one_chip, ppb, spb, CHUNKED.get((ppb, spb)))
+    assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("ppb,spb", [(1, 1), (2, 2)])
 def test_fused_write_attend_kernel_compiles(one_chip, ppb, spb):
-    def fused(q, kp, vp, ks, vs, bt, ln, k_new, v_new, keys, mask):
-        return pa._fused_decode_write_attend(
-            q, k_new, v_new, kp, vp, ks, vs, bt, ln, keys, keys, mask,
-            fmt="e5m2", n_kv_heads=KV, mode="rne", kv_mode="stochastic",
-            window=0, cap=0.0, impl="kernel", interpret=False, ppb=ppb,
-            spb=spb)
+    text = _compile_fused(one_chip, ppb, spb, CHUNKED.get((ppb, spb)))
+    assert "tpu_custom_call" in text
 
-    text = _compile_text(
-        fused, one_chip, *_page_args(), ((SLOTS, KV, HD), jnp.float32),
-        ((SLOTS, KV, HD), jnp.float32), ((SLOTS, 2), jnp.uint32),
-        ((SLOTS,), jnp.bool_))
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+@pytest.mark.parametrize("ppb,spb", [(1, 1), (8, 4)])
+def test_paged_kernel_compiles_at_decode_long(one_chip, ppb, spb, fused):
+    """The decode-long cell's geometry (64 slots x 96 pages), at the
+    served block shape's neighbours: one chunk of 96 pages, or (4 slots
+    per program) chunks of 32."""
+    compile_ = _compile_fused if fused else _compile_unfused
+    text = compile_(one_chip, ppb, spb, slots=LONG_SLOTS, maxp=LONG_MAXP,
+                    pool=LONG_POOL)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+@pytest.mark.parametrize("geom", [
+    dict(kv=1, g=7, hd=64, slots=64, maxp=96),
+    dict(kv=8, g=5, hd=128, slots=16, maxp=32),
+    dict(kv=8, g=5, hd=128, slots=8, maxp=2048),
+], ids=["qwen2-tp2-shard", "qwen3-14b", "qwen3-14b-32k"])
+def test_paged_kernel_compiles_at_other_geometries(one_chip, geom, fused):
+    """The served block shape at the other geometries the kernel runs: a
+    qwen2 shard of a 2-way tensor-parallel mesh (1 KV head, KV*hd = 64:
+    pages gathered as [8, 128] rows), qwen3-14b's 8 KV heads of 128, and
+    qwen3-14b at 32k-token contexts, where the partials of 2048 pages no
+    longer fit one program."""
+    compile_ = _compile_fused if fused else _compile_unfused
+    ppb, spb = PAGED_BLOCKS
+    geom = dict(geom, pool=geom["slots"] * geom["maxp"] + 1)
+    text = compile_(one_chip, ppb, spb, **geom)
     assert "tpu_custom_call" in text
 
 

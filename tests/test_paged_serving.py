@@ -40,22 +40,103 @@ def _paged_inputs(seed, *, B=3, H=4, KV=2, hd=16, page=8, P=12, maxp=5):
     return q, kf, vf, kp, vp, ks, vs, bt, lengths
 
 
+def _page_edge_inputs(seed, *, B=5, H=4, KV=2, hd=16, page=8, maxp=5):
+    """Every slot owns ``maxp`` pages; the attend lengths sit at page
+    edges (1, page, page+1, maxp*page-1, maxp*page), mixed across slots."""
+    rng = np.random.default_rng(seed)
+    P = B * maxp + 1
+    q = jnp.asarray(rng.standard_normal((B, 1, H, hd)).astype(np.float32))
+    kf = rng.standard_normal((P, page, KV, hd)).astype(np.float32)
+    vf = rng.standard_normal((P, page, KV, hd)).astype(np.float32)
+    ks = jnp.asarray((0.5 + rng.random(P)).astype(np.float32))
+    vs = jnp.asarray((0.5 + rng.random(P)).astype(np.float32))
+    bt = rng.permutation(np.arange(1, P)).reshape(B, maxp).astype(np.int32)
+    edges = [1, page, page + 1, maxp * page - 1, maxp * page]
+    lengths = rng.permutation(np.resize(edges, B)).astype(np.int32)
+    return (q, kf, vf, encode(jnp.asarray(kf), "e5m2"),
+            encode(jnp.asarray(vf), "e5m2"), ks, vs, jnp.asarray(bt),
+            jnp.asarray(lengths))
+
+
 # --------------------------------------------------------------------------- #
-# Kernel == reference, bit for bit (the acceptance contract)
+# Kernel == reference == batch, bit for bit (the acceptance contract)
 # --------------------------------------------------------------------------- #
 @pytest.mark.parametrize("seed", range(4))
 @pytest.mark.parametrize(
-    "kw", [dict(), dict(window=7, cap=25.0), dict(mode="faithful")],
-    ids=["plain", "window-cap", "faithful"],
+    "kw", [dict(), dict(window=7, cap=25.0), dict(mode="faithful"),
+           dict(edges=True), dict(edges=True, window=7, cap=25.0)],
+    ids=["plain", "window-cap", "faithful", "page-edges",
+         "page-edges-window-cap"],
 )
 def test_paged_lns_kernel_bit_identical_to_ref(seed, kw):
-    q, kf, vf, kp, vp, ks, vs, bt, lengths = _paged_inputs(seed)
+    """kernel == ref == batch, unfused and fused (the token written at
+    ``lengths - 1``); at page-edge lengths the kernel's per-page partials
+    also equal the batch's for several block shapes, and every page it
+    skips reads exactly (NEG_INF, 0, 0)."""
+    from repro.kernels import paged_attention as pa
+
+    kw = dict(kw)
+    edges = kw.pop("edges", False)
+    q, kf, vf, kp, vp, ks, vs, bt, lengths = (
+        _page_edge_inputs if edges else _paged_inputs)(seed)
     args = (q, kp, vp, ks, vs, bt, lengths)
-    o_ref = paged_decode_attention(*args, fmt="e5m2", n_kv_heads=2,
-                                   impl="ref", **kw)
-    o_ker = paged_decode_attention(*args, fmt="e5m2", n_kv_heads=2,
-                                   impl="kernel", interpret=True, **kw)
-    np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_ker))
+    outs = [paged_decode_attention(*args, fmt="e5m2", n_kv_heads=2,
+                                   impl=impl, interpret=True, **kw)
+            for impl in ("ref", "batch", "kernel")]
+    for o in outs[1:]:
+        np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(o))
+
+    rng = np.random.default_rng(100 + seed)
+    new = [jnp.asarray(rng.standard_normal((q.shape[0], 2, q.shape[3]))
+                       .astype(np.float32)) for _ in range(2)]
+    fused = [pa.fused_decode_write_attend(
+        q, *new, kp, vp, ks, vs, bt, lengths - 1, fmt="e5m2", n_kv_heads=2,
+        kv_mode="rne", impl=impl, interpret=True, **kw)
+        for impl in ("ref", "batch", "kernel")]
+    for f in fused[1:]:
+        for a, b in zip(fused[0], f):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if not edges:
+        return
+
+    B, page = q.shape[0], kp.shape[1]
+    codes, qs = pa.quantize_q(q.reshape(B, 4, -1), "e5m2")
+    geom = dict(fmt="e5m2", mode=kw.get("mode", "rne"), KV=2, G=2,
+                window=kw.get("window", 0), cap=kw.get("cap", 0.0))
+    want = jax.jit(lambda *a: pa._batch_partials(*a, **geom))(
+        (codes, qs), kp, vp, ks, vs, bt, lengths)
+    live = (np.arange(bt.shape[1])[None, :]
+            < -(-np.asarray(lengths)[:, None] // page))
+    # (pages per block, slots per program, pages per program): one chunk,
+    # and chunks of 2 of the 5 pages with padded slot blocks
+    for ppb, spb, cpp in ((1, 1, None), (4, 1, None), (2, 2, 2), (3, 3, 2)):
+        got = pa._paged_kernel_partials(
+            codes, qs, kp, vp, ks, vs, bt, lengths, page_size=page,
+            interpret=True, ppb=ppb, spb=spb, cpp=cpp, **geom)
+        for w, g, dead in zip(want, got, (pa.NEG_INF, 0.0, 0.0)):
+            np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+            assert np.all(np.asarray(g)[~live] == dead)
+
+
+@pytest.mark.parametrize("KV,G,dv", [(2, 7, 64), (1, 7, 64), (8, 5, 128)],
+                         ids=["qwen2", "qwen2-tp2-shard", "qwen3-14b"])
+def test_kernel_partials_vmem_does_not_grow_with_max_seq(KV, G, dv):
+    """A kernel program's output blocks (double-buffered m, l [KV, G] and
+    o [KV, G, dv] f32 per page, in 8 x 128 tiles) stay within
+    ``PARTIALS_VMEM`` up to 32k-token contexts: a longer ``max_seq`` is cut
+    into more, equal chunks; decode-long's 96 pages take one."""
+    from repro.kernels import paged_attention as pa
+
+    page_bytes = 4 * (2 * 8 * 128 + KV * -(-G // 8) * 8 * -(-dv // 128) * 128)
+    for spb in (1, 4):
+        for maxp in (1, 96, 97, 2048):
+            cpp = pa.pages_per_program(spb, maxp, KV, G, dv)
+            chunks = -(-maxp // cpp)
+            assert 2 * spb * cpp * page_bytes <= pa.PARTIALS_VMEM
+            assert cpp * (chunks - 1) < maxp <= cpp * chunks
+            fit = pa.PARTIALS_VMEM // (2 * spb * page_bytes)
+            assert chunks == -(-maxp // fit)
+    assert pa.pages_per_program(1, 96, 2, 7, 64) == 96
 
 
 @pytest.mark.parametrize("seed", range(2))
@@ -306,3 +387,45 @@ def test_host_transfers_pinned_one_per_allocating_step():
                          scheduler="continuous")
     n = eng2.tel.counter_value("host_transfers_total")
     assert 0 < n <= stats["steps"]
+
+
+@pytest.mark.parametrize("scheduler", ["bucketed", "continuous"])
+def test_paged_attn_pages_counter_matches_lengths(scheduler):
+    """``paged_attn_pages_total{walked, grid}`` equal the sums reckoned
+    from the lengths of every model call of a small engine run: walked =
+    layers x the pages holding a row of each sub-step's attend length
+    (at least one), grid = layers x sub-steps x slots x max pages."""
+    from repro.configs import get_config
+    from repro.launch import serve
+
+    cfg = get_config("qwen2-0.5b", smoke=True, quant="fp8_w8kv8")
+    eng = serve.Engine(cfg, slots=3, max_seq=24, cache_impl="paged",
+                       page_size=4)
+    attends = []
+    decode, chunk = eng.decode_paged, eng.step_chunk
+
+    def decode_paged(tokens, lengths, **kw):
+        attends.append(np.asarray(lengths)[None] + 1)
+        return decode(tokens, lengths, **kw)
+
+    def step_chunk(tokens, lengths, n_new, **kw):
+        # sub-step t of a slot attends to its position lengths + t (held
+        # at its last valid token once t passes n_new), plus one
+        attends.append(np.array(
+            [[int(n0) + min(t, max(int(n) - 1, 0)) + 1
+              for n0, n in zip(lengths, n_new)]
+             for t in range(np.shape(tokens)[1])]))
+        return chunk(tokens, lengths, n_new, **kw)
+
+    eng.decode_paged, eng.step_chunk = decode_paged, step_chunk
+    rng = np.random.default_rng(4)
+    queue = [rng.integers(0, cfg.vocab, size=3 + 2 * i) for i in range(4)]
+    serve.run(eng, queue, gen=7, quiet=True, scheduler=scheduler)
+    assert attends
+    layers, maxp = cfg.n_layers, 24 // 4
+    walked = sum(int(np.maximum(-(-a // 4), 1).sum()) for a in attends)
+    grid = sum(a.size for a in attends) * maxp
+    count = eng.tel.counter_value
+    assert count("paged_attn_pages_total", kind="walked") == layers * walked
+    assert count("paged_attn_pages_total", kind="grid") == layers * grid
+    assert walked < grid
