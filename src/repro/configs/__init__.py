@@ -13,6 +13,7 @@ from .gemma3_12b import CONFIG as _gemma3
 from .whisper_base import CONFIG as _whisper
 from .jamba_v01_52b import CONFIG as _jamba
 from .deepseek_v2_lite_16b import CONFIG as _dsv2
+from .deepseek_v2_lite_ep4 import CONFIG as _dsv2_ep4
 from .granite_moe_3b import CONFIG as _granite
 from .llava_next_mistral_7b import CONFIG as _llava
 from .mamba2_780m import CONFIG as _mamba2
@@ -21,7 +22,7 @@ CONFIGS: Dict[str, ModelConfig] = {
     c.name: c
     for c in [
         _qwen2, _gemma2, _qwen3, _gemma3, _whisper,
-        _jamba, _dsv2, _granite, _llava, _mamba2,
+        _jamba, _dsv2, _granite, _llava, _mamba2, _dsv2_ep4,
     ]
 }
 

@@ -46,6 +46,22 @@ class QuantConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class Yarn:
+    """YaRN rotary scaling, as a published ``rope_scaling`` of type
+    ``yarn`` gives it (DeepSeek-V2): the rotary frequencies blend the
+    interpolated (``/ factor``) and the original ones over a ramp between
+    the correction dimensions of ``beta_fast`` and ``beta_slow``
+    rotations, and the attention's softmax scale gains ``mscale**2``."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
     family: str  # dense | moe | ssm | hybrid | encdec | vlm
@@ -64,6 +80,7 @@ class ModelConfig:
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
     rope_theta: float = 10000.0
+    yarn: Optional[Yarn] = None  # YaRN rope scaling (MLA's rope lanes)
     # sliding-window pattern: period P with the first (P - n_global) layers
     # local; e.g. gemma2 (2, 1): alternate local/global; gemma3 (6, 1): 5 local
     # then 1 global.  window = local attention span.
@@ -84,7 +101,14 @@ class ModelConfig:
     moe_period: int = 1   # MoE ffn every `moe_period` layers ...
     moe_offset: int = 0   # ... at indices where i % period == offset
     first_dense: int = 0  # leading layers forced dense
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25  # training dispatch; serving is dropless
+    # renormalize the top-k gates to sum to 1 (False: the softmax
+    # probabilities of the chosen experts as they are, DeepSeek-V2)
+    norm_topk_prob: bool = True
+    # [first, first + count) of the routed experts this chip holds (expert
+    # parallelism: the router spans all n_experts, the layer computes the
+    # held experts' part of the result); count 0 = every expert
+    held_experts: Tuple[int, int] = (0, 0)
     # sorted_global: one argsort/scatter over all tokens (simple, but the
     # gather/scatter crosses the data/model sharding -> huge collectives).
     # grouped: route per batch-row (x per seq-shard under SP) so dispatch is
@@ -145,6 +169,12 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        first, count = self.held_experts
+        return (first, count) if count else (0, self.n_experts)
 
     @property
     def vocab_padded(self) -> int:
@@ -213,7 +243,12 @@ class ModelConfig:
         if self.attn_impl == "mla":
             changes.update(kv_lora_rank=32, qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16)
         if self.n_experts:
-            changes.update(n_experts=min(self.n_experts, 8), top_k=min(self.top_k, 2), moe_d_ff=64)
+            E = min(self.n_experts, 8)
+            changes.update(n_experts=E, top_k=min(self.top_k, 2), moe_d_ff=64)
+            first, count = self.held_experts
+            if count:  # the same share of the smaller expert set
+                changes.update(held_experts=(first * E // self.n_experts,
+                                             max(1, count * E // self.n_experts)))
         if self.ssm_state:
             changes.update(ssm_state=16, ssm_head_dim=16)
         if self.n_enc_layers:

@@ -1,7 +1,8 @@
 """DeepSeek-V2-Lite-16B [arXiv:2405.04434; hf]: MLA (kv_lora 512, rope 64,
-nope 128), MoE 64 routed top-6 + 2 shared, first layer dense (d_ff 10944).
+nope 128, YaRN x40), MoE 64 routed top-6 (softmax gates, not renormalized)
++ 2 shared, first layer dense (d_ff 10944).
 """
-from .base import ModelConfig
+from .base import ModelConfig, Yarn
 
 CONFIG = ModelConfig(
     name="deepseek-v2-lite-16b",
@@ -18,11 +19,15 @@ CONFIG = ModelConfig(
     qk_nope_dim=128,
     qk_rope_dim=64,
     v_head_dim=128,
+    yarn=Yarn(factor=40.0, original_max_position_embeddings=4096,
+              beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+              mscale_all_dim=0.707),
     n_experts=64,
     n_shared_experts=2,
     top_k=6,
     moe_d_ff=1408,
     moe_period=1,
     first_dense=1,
+    norm_topk_prob=False,
     act_fn="silu",
 )

@@ -122,14 +122,27 @@ def _page_scores_lns(q_codes, k_codes, qk_scale, fmt, mode, pin=True):
     return _rowsum(prod, pin) * qk_scale
 
 
+def _pin_add(a, b, pin: bool):
+    """a + b, each term pinned first (the bit-identity barriers)."""
+    if not pin:
+        return a + b
+    pin = jax.lax.optimization_barrier
+    return pin(a) + pin(b)
+
+
 def _page_partial(
     q_op, k_page, v_page, k_s, v_s, t0, length, *, fmt, mode, window, cap,
-    pin=True,
+    pin=True, scale=None,
 ):
     """Softmax partials of one (slot, page): (m [KV,G], l [KV,G], o [KV,G,dv]).
 
     q_op: (codes [KV, G, hd], scale) for LNS pages, or float q [KV, G, hd]
     for float pages.  k_page/v_page: [page, KV, hd|dv] codes or float.
+    ``scale`` is the softmax scale (None: ``hd**-0.5``).  A query wider
+    than its k page is MLA's ``[q_pe ‖ q_eff]``: a row's score is the
+    product of ``q_pe`` with its rope key (the k page) plus that of
+    ``q_eff`` with its latent (the v page), each at its own page scale,
+    and the values are the latents.
     t0: global position of the page's first row; length: valid tokens for
     this slot.  A fully masked page yields (m, l, o) = (NEG_INF, 0, 0):
     zero weight in the combine, and the partial the kernel leaves on a
@@ -143,18 +156,36 @@ def _page_partial(
     at full f32 precision.
     """
     page = k_page.shape[0]
+    dr = k_page.shape[-1]
+    hd = (q_op[0] if fmt is not None else q_op).shape[-1]
+    mla = hd > dr  # the query [q_pe ‖ q_eff] is wider than its rope key
+    scale = hd**-0.5 if scale is None else scale
     if fmt is not None:
         q_codes, q_scale = q_op
-        hd = q_codes.shape[-1]
-        s = _page_scores_lns(q_codes, k_page, q_scale * k_s * hd**-0.5,
-                             FORMATS[fmt], mode, pin)
+        if mla:
+            s = _pin_add(
+                _page_scores_lns(q_codes[..., :dr], k_page,
+                                 q_scale * k_s * scale, FORMATS[fmt],
+                                 mode, pin),
+                _page_scores_lns(q_codes[..., dr:], v_page,
+                                 q_scale * v_s * scale, FORMATS[fmt],
+                                 mode, pin), pin)
+        else:
+            s = _page_scores_lns(q_codes, k_page, q_scale * k_s * scale,
+                                 FORMATS[fmt], mode, pin)
         vf = code_to_f32(v_page, FORMATS[fmt]) * v_s
     else:
-        hd = q_op.shape[-1]
-        s = jax.lax.dot_general(
-            q_op.astype(jnp.float32), k_page.astype(jnp.float32),
-            (((2,), (2,)), ((0,), (1,))), preferred_element_type=jnp.float32,
-        ) * hd**-0.5
+        def dot(q, k):
+            return jax.lax.dot_general(
+                q.astype(jnp.float32), k.astype(jnp.float32),
+                (((2,), (2,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32,
+            )
+        if mla:
+            s = _pin_add(dot(q_op[..., :dr], k_page) * scale,
+                         dot(q_op[..., dr:], v_page) * scale, pin)
+        else:
+            s = dot(q_op, k_page) * scale
         vf = v_page.astype(jnp.float32)
     if cap:
         s = jnp.tanh(s / cap) * cap
@@ -213,14 +244,23 @@ def _combine_partials(m, l, o):
 # --------------------------------------------------------------------------- #
 # Pure-JAX reference (interpret-mode CI oracle; also the CPU serving path).
 # --------------------------------------------------------------------------- #
+def _heads_view(pages, KV):
+    """[P, page, KV, d] view of a page array; MLA's page arrays are
+    stored [P, page, d] (one latent or rope key per row)."""
+    if pages.ndim == 3:
+        return pages.reshape(pages.shape[:2] + (KV, pages.shape[2] // KV))
+    return pages
+
+
 def paged_attention_ref(
     q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
     fmt: Optional[str], mode: str, page_size: int, KV: int, G: int,
-    window: int = 0, cap: float = 0.0,
+    window: int = 0, cap: float = 0.0, scale: Optional[float] = None,
 ):
     """Per-page partials via lax.map (sequential, unbatched shapes — the
     same shapes one kernel program sees), then the shared combine."""
     maxp = block_tables.shape[1]
+    k_pages, v_pages = _heads_view(k_pages, KV), _heads_view(v_pages, KV)
 
     def slot(args):
         qb, bt, length = args
@@ -236,6 +276,7 @@ def paged_attention_ref(
                 q_slot, k_pages[pid], v_pages[pid], k_scale[pid],
                 v_scale[pid], j * page_size, length,
                 fmt=fmt, mode=mode, window=window, cap=cap,
+                scale=scale,
             )
 
         return jax.lax.map(one_page, jnp.arange(maxp))
@@ -272,11 +313,12 @@ def _insert_rows(gathered, row, logical, rows, mask):
 
 def _batch_partials(
     q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
-    fmt, mode, KV, G, window, cap, inserts=None,
+    fmt, mode, KV, G, window, cap, inserts=None, scale=None,
 ):
     """All (slot, page) softmax partials at once: (m, l, o) shaped
     [B, maxp, KV, G(, dv)] — the same combine input the ref builds."""
     B, maxp = block_tables.shape
+    k_pages, v_pages = _heads_view(k_pages, KV), _heads_view(v_pages, KV)
     kg = k_pages[block_tables]            # [B, maxp, page, KV, hd]
     vg = v_pages[block_tables]            # [B, maxp, page, KV, dv]
     ksg = k_scale[block_tables]           # [B, maxp]
@@ -286,43 +328,60 @@ def _batch_partials(
         k_row, v_row, logical, rows, imask = inserts
         kg = _insert_rows(kg, k_row, logical, rows, imask)
         vg = _insert_rows(vg, v_row, logical, rows, imask)
+    dr = kg.shape[-1]
+    hd = (q_op[0] if fmt is not None else q_op).shape[-1]
+    scale = hd**-0.5 if scale is None else scale
     if fmt is not None:
         fmt_obj = FORMATS[fmt] if isinstance(fmt, str) else fmt
         codes, qs = q_op
-        hd = codes.shape[-1]
         qc = codes.reshape(B, KV, G, hd)
-        px = lns_prepare(qc, fmt_obj, mode, side="x")   # fields [B,KV,G,hd]
-        py = lns_prepare(kg, fmt_obj, mode, side="y")   # [B,maxp,page,KV,hd]
 
-        def ex(f):
-            return None if f is None else f[:, None, :, :, None, :]
+        def scores(qc, kg, ksg, c):
+            px = lns_prepare(qc, fmt_obj, mode, side="x")   # [B,KV,G,d]
+            py = lns_prepare(kg, fmt_obj, mode, side="y")   # [B,maxp,page,KV,d]
 
-        def ey(f):
-            if f is None:
-                return None
-            return jnp.transpose(f, (0, 1, 3, 2, 4))[:, :, :, None, :, :]
+            def ex(f):
+                return None if f is None else f[:, None, :, :, None, :]
 
-        prod = lns_combine(type(px)(*(ex(f) for f in px)),
-                           type(py)(*(ey(f) for f in py)), fmt_obj)
-        # [B, maxp, KV, G, page, hd] -> sum over hd, pinned like the ref
-        ssum = jax.lax.dot_general(
-            prod, jnp.ones((prod.shape[-1],), jnp.float32),
-            (((5,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        )
-        qk = qs[:, None] * ksg * hd**-0.5               # [B, maxp]
-        s = jax.lax.optimization_barrier(ssum) * qk[:, :, None, None, None]
+            def ey(f):
+                if f is None:
+                    return None
+                return jnp.transpose(f, (0, 1, 3, 2, 4))[:, :, :, None, :, :]
+
+            prod = lns_combine(type(px)(*(ex(f) for f in px)),
+                               type(py)(*(ey(f) for f in py)), fmt_obj)
+            # [B, maxp, KV, G, page, d] -> sum over d, pinned like the ref
+            ssum = jax.lax.dot_general(
+                prod, jnp.ones((prod.shape[-1],), jnp.float32),
+                (((5,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            )
+            qk = qs[:, None] * ksg * c                      # [B, maxp]
+            return (jax.lax.optimization_barrier(ssum)
+                    * qk[:, :, None, None, None])
+
+        if hd > dr:  # MLA, as in _page_partial
+            s = _pin_add(scores(qc[..., :dr], kg, ksg, scale),
+                         scores(qc[..., dr:], vg, vsg, scale), True)
+        else:
+            s = scores(qc, kg, ksg, scale)
         vf = code_to_f32(vg, fmt_obj) * vsg[:, :, None, None, None]
     else:
-        hd = q_op.shape[-1]
-        qb = jnp.broadcast_to(
-            q_op.astype(jnp.float32).reshape(B, 1, KV, G, hd),
-            (B, maxp, KV, G, hd),
-        )
-        kt = jnp.transpose(kg.astype(jnp.float32), (0, 1, 3, 2, 4))
-        s = jax.lax.dot_general(
-            qb, kt, (((4,), (4,)), ((0, 1, 2), (0, 1, 2))),
-            preferred_element_type=jnp.float32,
-        ) * hd**-0.5
+        def dot(q, kg):
+            qb = jnp.broadcast_to(
+                q.astype(jnp.float32).reshape(B, 1, KV, G, q.shape[-1]),
+                (B, maxp, KV, G, q.shape[-1]),
+            )
+            kt = jnp.transpose(kg.astype(jnp.float32), (0, 1, 3, 2, 4))
+            return jax.lax.dot_general(
+                qb, kt, (((4,), (4,)), ((0, 1, 2), (0, 1, 2))),
+                preferred_element_type=jnp.float32,
+            )
+
+        if hd > dr:
+            s = _pin_add(dot(q_op[..., :dr], kg) * scale,
+                         dot(q_op[..., dr:], vg) * scale, True)
+        else:
+            s = dot(q_op, kg) * scale
         vf = vg.astype(jnp.float32)
     if cap:
         s = jnp.tanh(s / cap) * cap
@@ -356,12 +415,12 @@ def _batch_partials(
 def paged_attention_batch(
     q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
     fmt: Optional[str], mode: str, page_size: int, KV: int, G: int,
-    window: int = 0, cap: float = 0.0, inserts=None,
+    window: int = 0, cap: float = 0.0, inserts=None, scale: Optional[float] = None,
 ):
     m, l, o = _batch_partials(
         q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
         fmt=fmt, mode=mode, KV=KV, G=G, window=window, cap=cap,
-        inserts=inserts,
+        inserts=inserts, scale=scale,
     )
     return _combine_partials(m, l, o)
 
@@ -428,7 +487,7 @@ def walked_pages(lengths, page, maxp, xp=jnp):
 
 def _paged_kernel(
     *refs, fmt, mode, page, KV, G, window, cap, spb, ppb, cpp, maxp, fused,
-    pin,
+    pin, scale,
 ):
     """Grid (Bp/spb, ceil(maxp/cpp)) program: walks the live pages of
     ``spb`` slots among the ``cpp`` pages of its chunk of ``max_seq``.
@@ -521,6 +580,7 @@ def _paged_kernel(
                 q_op, kp_blk, vp_blk, ks_ref[bs, jp], vs_ref[bs, jp],
                 jp * page, len_ref[bs],
                 fmt=fmt, mode=mode, window=window, cap=cap, pin=pin,
+                scale=scale,
             )
             m_ref[i, jp - lo] = m
             l_ref[i, jp - lo] = l
@@ -593,6 +653,7 @@ def _paged_kernel_partials(
     q_in, q_scale, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
     *, fmt, mode, page_size, KV, G, window, cap, interpret,
     ppb: int = 1, spb: int = 1, cpp: Optional[int] = None, inserts=None,
+    scale: Optional[float] = None,
 ):
     """Launch the paged kernel: per-page partials (m, l, o) shaped
     [B, maxp, KV, G(, dv)], as :func:`_batch_partials`.
@@ -631,7 +692,7 @@ def _paged_kernel_partials(
     launch = functools.partial(
         _paged_pallas, n_prefetch=len(prefetch), fmt=fmt, mode=mode,
         page_size=page_size, window=window, cap=cap, interpret=interpret,
-        ppb=ppb, spb=spb, cpp=cpp,
+        ppb=ppb, spb=spb, cpp=cpp, scale=scale,
     )
     sharded = kv_head_mesh()
     if sharded is not None:
@@ -665,7 +726,7 @@ def pages_per_program(spb, maxp, KV, G, dv):
 
 
 def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
-                  interpret, ppb, spb, cpp):
+                  interpret, ppb, spb, cpp, scale):
     """The pallas_call of :func:`_paged_kernel_partials` on (possibly
     shard-local) operands: scalar prefetch, q [Bp, KV, G, hd], the page
     arrays (left in HBM), then the fused row inserts [Bp, KV, hd] x2 when
@@ -681,7 +742,7 @@ def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
     kernel = functools.partial(
         _paged_kernel, fmt=fmt, mode=mode, page=page_size, KV=KV, G=G,
         window=window, cap=cap, spb=spb, ppb=ppb, cpp=cpp, maxp=maxp,
-        fused=fused, pin=bool(interpret),
+        fused=fused, pin=bool(interpret), scale=scale,
     )
 
     def slot_spec(x):
@@ -689,7 +750,7 @@ def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
                             lambda b, c, *pref: (b,) + (0,) * (x.ndim - 1))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    k_rows = _page_rows(page_size, KV, hd)
+    k_rows = _page_rows(page_size, KV, k_pages.shape[-1])
     v_rows = _page_rows(page_size, KV, dv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
@@ -749,7 +810,7 @@ def _kernel_blocks(impl, block_tables, page_size, KV, G, hd, fmt, interpret,
 def paged_decode_attention(
     q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
     fmt: Optional[str], n_kv_heads: int, mode: str = "rne",
-    window: int = 0, cap: float = 0.0,
+    window: int = 0, cap: float = 0.0, scale: Optional[float] = None,
     impl: str = "auto", interpret: Optional[bool] = None, site: str = "",
 ):
     """Decode attention against a paged KV cache.
@@ -764,6 +825,11 @@ def paged_decode_attention(
     "batch" (vectorized pure JAX — the CPU serving path), "auto" = batch on
     CPU, kernel on accelerators.  All three are bit-identical.  ``site``
     keys the autotune cache entry for the kernel block shape.
+
+    ``scale``: the softmax scale, by default ``hd**-0.5``.  MLA (a query
+    wider than its k pages): absorbed latent attention, one KV head.  q is ``[q_pe ‖ q_eff]`` [B, 1, H, dr + L];
+    k_pages holds the rope keys [P, page, dr] and v_pages the latents
+    [P, page, L], which are the values too.
     Returns [B, 1, H, dv] in q.dtype.
     """
     impl, interpret = _resolve_impl(impl, interpret)
@@ -774,19 +840,21 @@ def paged_decode_attention(
         q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
         fmt=fmt, n_kv_heads=n_kv_heads, mode=mode, window=window, cap=cap,
         impl=impl, interpret=interpret, ppb=ppb, spb=spb,
+        scale=scale,
     )
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("fmt", "n_kv_heads", "mode", "window", "cap", "impl",
-                     "interpret", "ppb", "spb", "cpp"),
+                     "interpret", "ppb", "spb", "cpp", "scale"),
 )
 def _paged_decode_attention(
     q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
     fmt: Optional[str], n_kv_heads: int, mode: str,
     window: int, cap: float, impl: str, interpret: bool,
     ppb: int = 1, spb: int = 1, cpp: Optional[int] = None,
+    scale: Optional[float] = None,
 ):
     B, one, H, hd = q.shape
     assert one == 1, "paged decode attention is single-position"
@@ -805,13 +873,13 @@ def _paged_decode_attention(
         out = paged_attention_ref(
             q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
             fmt=fmt, mode=mode, page_size=k_pages.shape[1], KV=KV, G=G,
-            window=window, cap=cap,
+            window=window, cap=cap, scale=scale,
         )
     elif impl == "batch":
         out = paged_attention_batch(
             q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
             fmt=fmt, mode=mode, page_size=k_pages.shape[1], KV=KV, G=G,
-            window=window, cap=cap,
+            window=window, cap=cap, scale=scale,
         )
     elif impl == "kernel":
         if fmt is not None:
@@ -823,6 +891,7 @@ def _paged_decode_attention(
             block_tables, lengths, fmt=fmt, mode=mode,
             page_size=k_pages.shape[1], KV=KV, G=G, window=window, cap=cap,
             interpret=interpret, ppb=ppb, spb=spb, cpp=cpp,
+            scale=scale,
         )
     else:
         raise ValueError(f"unknown impl {impl!r}")
@@ -836,7 +905,7 @@ def fused_decode_write_attend(
     q, k_new, v_new, k_pages, v_pages, k_scale, v_scale, block_tables,
     lengths, *, fmt: Optional[str], n_kv_heads: int, mode: str = "rne",
     kv_mode: str = "stochastic", k_key=None, v_key=None, write_mask=None,
-    window: int = 0, cap: float = 0.0,
+    window: int = 0, cap: float = 0.0, scale: Optional[float] = None,
     impl: str = "auto", interpret: Optional[bool] = None, site: str = "",
 ):
     """Write one decode token's K/V into its page AND attend, in one launch.
@@ -860,6 +929,9 @@ def fused_decode_write_attend(
     whose contents depend on host scatter order — both compositions mask
     those outputs downstream).
 
+    ``scale`` and MLA as :func:`paged_decode_attention`: k_new is
+    the token's rope key [B, 1, dr] and v_new its latent [B, 1, L].
+
     Returns ``(out [B, 1, H, dv], new_k_pages, new_k_scale, new_v_pages,
     new_v_scale)``.
     """
@@ -872,7 +944,7 @@ def fused_decode_write_attend(
         lengths, k_key, v_key, write_mask,
         fmt=fmt, n_kv_heads=n_kv_heads, mode=mode, kv_mode=kv_mode,
         window=window, cap=cap, impl=impl, interpret=interpret,
-        ppb=ppb, spb=spb,
+        ppb=ppb, spb=spb, scale=scale,
     )
     return out, new_kp, new_ks, new_vp, new_vs
 
@@ -881,20 +953,21 @@ def _set_rows(pages, pids, rows, row):
     """Write one [KV, hd] row per lane through the [P, page, KV*hd] view
     the kernel gathers from, so the compiler keeps one layout of the pool
     for the write and the attend."""
-    P, page, KV, hd = pages.shape
-    flat = pages.reshape(P, page, KV * hd)
-    return flat.at[pids, rows].set(row.reshape(-1, KV * hd)).reshape(pages.shape)
+    P, page = pages.shape[:2]
+    width = row.shape[-2] * row.shape[-1]
+    flat = pages.reshape(P, page, width)
+    return flat.at[pids, rows].set(row.reshape(-1, width)).reshape(pages.shape)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("fmt", "n_kv_heads", "mode", "kv_mode", "window", "cap",
-                     "impl", "interpret", "ppb", "spb", "cpp"),
+                     "impl", "interpret", "ppb", "spb", "cpp", "scale"),
 )
 def _fused_decode_write_attend(
     q, k_new, v_new, k_pages, v_pages, k_scale, v_scale, block_tables,
     lengths, k_key, v_key, write_mask, *, fmt, n_kv_heads, mode, kv_mode,
-    window, cap, impl, interpret, ppb, spb, cpp=None,
+    window, cap, impl, interpret, ppb, spb, cpp=None, scale=None,
 ):
     from ..serving.page_pool import token_row_codes
 
@@ -941,13 +1014,13 @@ def _fused_decode_write_attend(
         out = paged_attention_ref(
             q_op, new_kp, new_vp, new_ks, new_vs, block_tables, attend_len,
             fmt=fmt, mode=mode, page_size=page_size, KV=KV, G=G,
-            window=window, cap=cap,
+            window=window, cap=cap, scale=scale,
         )
     elif impl == "batch":
         m, l, o = _batch_partials(
             q_op, k_pages, v_pages, new_ks, new_vs, block_tables, attend_len,
             fmt=fmt, mode=mode, KV=KV, G=G, window=window, cap=cap,
-            inserts=(k_row, v_row, logical, rows, mask),
+            inserts=(k_row, v_row, logical, rows, mask), scale=scale,
         )
         out = _combine_partials(m, l, o)
         # Materialize the softmax partials as (discarded) graph outputs.
@@ -966,7 +1039,7 @@ def _fused_decode_write_attend(
             block_tables, attend_len, fmt=fmt, mode=mode,
             page_size=page_size, KV=KV, G=G, window=window, cap=cap,
             interpret=interpret, ppb=ppb, spb=spb, cpp=cpp,
-            inserts=(k_row, v_row, logical, rows, mask),
+            inserts=(k_row, v_row, logical, rows, mask), scale=scale,
         )
     else:
         raise ValueError(f"unknown impl {impl!r}")

@@ -21,7 +21,8 @@ Two cache backends (``--cache-impl``):
     and all layers — cache memory scales with the page budget, not with
     slots x max_seq.  Decode attention runs in the paper's LNS integer
     domain straight off the page codes (``kernels.paged_attention``); KV
-    writes use stochastic-rounding carry-ins.  MLA/SSM/cross caches keep
+    writes use stochastic-rounding carry-ins.  MLA latents and rope keys
+    live in the pool too (the kernel's MLA mode); SSM/cross caches keep
     dense per-slot entries.
   * ``dense``: the original per-slot [slots, max_seq] cache, kept so the
     paged path's wins stay measurable (bucketed scheduler only).
@@ -178,8 +179,8 @@ class Engine:
                 raise ValueError(
                     f"prefix caching needs a pure-GQA paged KV cache; "
                     f"{cfg.name!r} (family={cfg.family!r}, "
-                    f"attn_impl={cfg.attn_impl!r}) keeps dense per-slot "
-                    "cache entries that cannot be shared between requests"
+                    f"attn_impl={cfg.attn_impl!r}) keeps cache entries "
+                    "that no prefix index shares between requests"
                 )
             pol = numerics.as_policy(cfg.policy)
             desc = (f"{cfg.name}|{rng_seed}|{page_size}|"
@@ -212,7 +213,7 @@ class Engine:
                 self.model.step_paged,
                 static_argnames=("page_size", "fused"), donate_argnums=(1,),
             )
-            # the GQA layers whose attention walks the page pool
+            # the attention layers (GQA or MLA) that walk the page pool
             self._paged_layers = sum(
                 e["self"]["kp"].shape[0] if stacked else 1
                 for stacked, entries in ((False, self.cache["prefix"]),
@@ -225,6 +226,10 @@ class Engine:
             self._bt_version = -1
         else:
             raise ValueError(f"unknown cache_impl {cache_impl!r}")
+        # routing counts of the mixed steps not yet synced, fetched with
+        # their logits (models with routed experts only)
+        self._has_moe = cfg.n_experts > 0
+        self._moe_pending: List = []
 
     # ------------------------------------------------------------------ #
     # Tensor-parallel mesh: validation, placement, hint context
@@ -260,7 +265,8 @@ class Engine:
             raise ValueError(
                 f"mesh serving needs a pure-GQA paged cache; {cfg.name!r} "
                 f"(family={cfg.family!r}, attn_impl={cfg.attn_impl!r}) "
-                "keeps dense per-slot cache entries without TP rules")
+                "keeps cache entries without TP rules (SSM states, or MLA "
+                "latent pages of one KV head, which heads cannot shard)")
         for dim, what in ((cfg.n_heads, "n_heads"),
                           (cfg.n_kv_heads, "n_kv_heads"),
                           (cfg.d_ff, "d_ff"),
@@ -302,9 +308,10 @@ class Engine:
     @staticmethod
     def prefix_cache_supported(cfg) -> bool:
         """Prefix caching shares *pages*; it needs every block's KV state
-        to live in the page pool — pure-GQA decoder-only stacks.  MLA
-        latents, SSM states and cross/encoder caches are dense per-slot
-        entries that cannot be remapped between requests."""
+        to live in the page pool — pure-GQA decoder-only stacks.  SSM
+        states and cross/encoder caches are dense per-slot entries that
+        cannot be remapped between requests; MLA's latent pages live in
+        the pool, but no prefix index keys or shares them yet."""
         if cfg.family in ("vlm", "encdec") or cfg.attn_impl != "gqa":
             return False
         from ..models.transformer import layer_specs
@@ -584,8 +591,9 @@ class Engine:
             out = {}
             for name, cv in c_e.items():
                 if isinstance(cv, dict) and "kp" in cv:
-                    # paged GQA entry: quantize the prefill rows into
-                    # pages (fmt/mode resolved from the numerics policy)
+                    # paged entry: quantize the prefill rows into pages
+                    # (fmt/mode resolved from the numerics policy); MLA's
+                    # rope keys go to "kp" pages and its latents to "vp"
                     def wr(pages, scales, src, pids, k):
                         return numerics.kv_write_prefill(
                             cfg.policy, pages, scales, src, pids, key=k,
@@ -593,7 +601,9 @@ class Engine:
 
                     kp, ks = cv["kp"], cv["ks"]
                     vp, vs = cv["vp"], cv["vs"]
-                    k_src, v_src = s_e[name]["k"], s_e[name]["v"]
+                    src = s_e[name]
+                    k_src, v_src = ((src["kpe"], src["ckv"]) if "ckv" in src
+                                    else (src["k"], src["v"]))
                     for i in range(n):
                         ki = None if keys is None else jax.random.fold_in(keys, 2 * i)
                         vi = None if keys is None else jax.random.fold_in(keys, 2 * i + 1)
@@ -686,14 +696,27 @@ class Engine:
         named ``collectives`` there — BENCH phase breakdowns attribute
         the cross-shard cost to one row.  Inside it, ``wait`` is the host
         blocked while the device computes and ``fetch`` the copy of the
-        logits to the host."""
+        logits to the host, together with the routing counts of the mixed
+        steps since the last fetch (models with routed experts), which
+        feed ``moe_assignments_total`` and the fetch span's args."""
         if isinstance(logits, np.ndarray):
             return logits
         with self.tel.span("collectives" if self._tp > 1 else "sync"):
             with self.tel.span("wait"):
                 jax.block_until_ready(logits)
-            with self.tel.span("fetch"):
-                return np.asarray(logits)
+            with self.tel.span("fetch") as args:
+                pending = getattr(self, "_moe_pending", None)
+                if not pending:
+                    return np.asarray(logits)
+                host, counts = jax.device_get((logits, pending))
+                self._moe_pending = []
+                held, absent = (int(n) for n in np.sum(counts, axis=0))
+                self.tel.counter("moe_assignments_total",
+                                 expert="held").inc(held)
+                self.tel.counter("moe_assignments_total",
+                                 expert="absent").inc(absent)
+                args.update(moe_held=held, moe_absent=absent)
+                return host
 
     def decode_paged(self, tokens: np.ndarray, lengths: np.ndarray, *,
                      sync: bool = True):
@@ -754,13 +777,15 @@ class Engine:
         # chunk dominates its T=chunk trace); pure decode steps to "decode"
         phase = "decode" if all(int(n) <= 1 for n in n_new) else "prefill"
         with self.tel.span(phase), self._hints():
-            logits, self.cache = self._mixed_step(
+            logits, self.cache, counts = self._mixed_step(
                 self.params, self.cache, jnp.asarray(tokens, jnp.int32),
                 jnp.asarray(lengths, jnp.int32),
                 jnp.asarray(n_new, jnp.int32), tables,
                 page_size=self.page_size, key=self._token_key,
                 fused=self.fused_decode,
             )
+            if self._has_moe:
+                self._moe_pending.append(counts)
             self._step += 1
             out = logits[:, : self.cfg.vocab]
         if not sync:
@@ -780,7 +805,7 @@ class Engine:
     def preempt_slot(self, slot: int) -> dict:
         """Spill ``slot`` to the host: copy its *exclusive* page codes +
         scales out of every paged entry and its per-slot rows out of every
-        dense entry (MLA latents, SSM states), then free those pages.
+        dense entry (SSM states), then free those pages.
         Shared/registered prefix pages are neither copied nor freed — they
         stay resident under a pin (``PagePool.spill_slot``) and are
         re-referenced on restore, so preempting a reader of a shared

@@ -7,6 +7,10 @@ Caches:
   * GQA:  {"k": [B, S, KV, hd], "v": [B, S, KV, dv]}
   * MLA:  {"ckv": [B, S, lora], "kpe": [B, S, rope]}  (compressed — the point
     of MLA; decode uses the absorbed-matrices formulation)
+
+Paged (serving) caches hold page arrays of the global pool, {"kp", "vp",
+"ks", "vs"}: GQA's K and V pages [P, page, KV, hd], or MLA's rope keys
+[P, page, rope] and latents [P, page, lora], each with one scale per page.
 """
 from __future__ import annotations
 
@@ -25,6 +29,7 @@ from .layers import (
     rms_norm,
     rope,
     softcap,
+    yarn_mscale,
 )
 
 
@@ -173,10 +178,7 @@ def gqa_decode_paged(p, x, cfg, *, is_global: bool, cache, paged,
     B = x.shape[0]
     KV = cfg.n_kv_heads
     pol = cfg.policy
-    lengths = jnp.asarray(paged["lengths"], jnp.int32)
-    block_tables = jnp.asarray(paged["block_tables"], jnp.int32)
-    page_size = paged["page_size"]
-    positions = lengths[:, None]
+    positions = jnp.asarray(paged["lengths"], jnp.int32)[:, None]
     q, k_new, v_new = _gqa_qkv(p, x, cfg, positions, use_rope, site=site)
     # Serving TP: heads/KV-groups shard over the model axis (per-group
     # attention concatenates across shards — no cross-shard reduction).
@@ -184,7 +186,29 @@ def gqa_decode_paged(p, x, cfg, *, is_global: bool, cache, paged,
     q = hint(q, "tp_heads")
     k_new = hint(k_new, "tp_kv")
     v_new = hint(v_new, "tp_kv")
+    out, new_cache = _paged_write_attend(
+        q, k_new, v_new, cache, paged, pol, n_kv_heads=KV,
+        window=0 if is_global else cfg.window, cap=cfg.attn_softcap,
+        site=site)
+    # All-gather the head-sharded output BEFORE the wo contraction: the
+    # matmul then sees the whole array on every shard, so TP introduces no
+    # partial sums and the token stream stays bit-identical to TP=1.
+    out = hint(out, "tp_gather")
+    y = qlinear(out.reshape(B, 1, -1), p["wo"], pol, site=f"{site}.wo")
+    return y, new_cache
 
+
+def _paged_write_attend(q, k_new, v_new, cache, paged, pol, *, n_kv_heads,
+                        window=0, cap=0.0, scale=None, site=""):
+    """Write one token's K and V rows [B, 1, KV, d] into their pages and
+    attend (the paged decode of :func:`gqa_decode_paged` and
+    :func:`mla_decode_paged`).  The layer's PRNG key is folded with each
+    slot's write position; ``paged["active"]`` is the write mask.  Fused:
+    one launch, bit-identical to the unfused write-then-attend on active
+    lanes.  Returns (out [B, 1, H, dv], new page arrays)."""
+    lengths = jnp.asarray(paged["lengths"], jnp.int32)
+    block_tables = jnp.asarray(paged["block_tables"], jnp.int32)
+    page_size = paged["page_size"]
     active = paged.get("active")
     key = paged.get("key")
     if key is None:
@@ -193,15 +217,12 @@ def gqa_decode_paged(p, x, cfg, *, is_global: bool, cache, paged,
         kk, vk = tuple(jax.random.split(key))
         fold_pos = jax.vmap(jax.random.fold_in, in_axes=(None, 0))
         kk, vk = fold_pos(kk, lengths), fold_pos(vk, lengths)
-    window = 0 if is_global else cfg.window
     if paged.get("fused", True):
-        # one launch: token KV write + attend (bit-identical to the
-        # unfused composition below on active lanes)
         out, kp, ks, vp, vs = numerics.kv_fused_write_attend(
             q, k_new[:, 0], v_new[:, 0], cache["kp"], cache["vp"],
             cache["ks"], cache["vs"], block_tables, lengths, pol,
-            n_kv_heads=KV, k_key=kk, v_key=vk, write_mask=active,
-            window=window, cap=cfg.attn_softcap, site=site,
+            n_kv_heads=n_kv_heads, k_key=kk, v_key=vk, write_mask=active,
+            window=window, cap=cap, scale=scale, site=site,
         )
     else:
         logical = lengths // page_size
@@ -216,14 +237,10 @@ def gqa_decode_paged(p, x, cfg, *, is_global: bool, cache, paged,
                                          write_mask=active)
         out = numerics.attention(
             q, kp, vp, ks, vs, block_tables, lengths + 1, pol,
-            n_kv_heads=KV, window=window, cap=cfg.attn_softcap, site=site,
+            n_kv_heads=n_kv_heads, window=window, cap=cap,
+            scale=scale, site=site,
         )
-    # All-gather the head-sharded output BEFORE the wo contraction: the
-    # matmul then sees the whole array on every shard, so TP introduces no
-    # partial sums and the token stream stays bit-identical to TP=1.
-    out = hint(out, "tp_gather")
-    y = qlinear(out.reshape(B, 1, -1), p["wo"], pol, site=f"{site}.wo")
-    return y, {"kp": kp, "vp": vp, "ks": ks, "vs": vs}
+    return out, {"kp": kp, "vp": vp, "ks": ks, "vs": vs}
 
 
 # --------------------------------------------------------------------------- #
@@ -244,13 +261,24 @@ def mla_init(rng, cfg):
     }
 
 
+def mla_softmax_scale(cfg) -> float:
+    """The published softmax scale of MLA: ``(nope + rope) ** -0.5``, times
+    ``mscale**2`` of ``mscale_all_dim`` under YaRN."""
+    scale = (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5
+    y = cfg.yarn
+    if y is not None and y.mscale_all_dim:
+        m = yarn_mscale(y.factor, y.mscale_all_dim)
+        scale *= m * m
+    return scale
+
+
 def _mla_q(p, x, cfg, positions, site="blocks.*.attn"):
     B, S, D = x.shape
     H = cfg.n_heads
     dn, dr = cfg.qk_nope_dim, cfg.qk_rope_dim
     q = qlinear(x, p["wq"], cfg.policy, site=f"{site}.wq").reshape(B, S, H, dn + dr)
     q_nope, q_pe = q[..., :dn], q[..., dn:]
-    q_pe = rope(q_pe, positions, cfg.rope_theta)
+    q_pe = rope(q_pe, positions, cfg.rope_theta, cfg.yarn)
     return q_nope, q_pe
 
 
@@ -259,8 +287,20 @@ def _mla_latent(p, x, cfg, positions, site="blocks.*.attn"):
     L, dr = cfg.kv_lora_rank, cfg.qk_rope_dim
     dkv = qlinear(x, p["w_dkv"], cfg.policy, site=f"{site}.w_dkv")
     ckv = rms_norm(dkv[..., :L], p["kv_norm"], cfg.norm_eps)
-    kpe = rope(dkv[..., L:].reshape(B, S, 1, dr), positions, cfg.rope_theta)
+    kpe = rope(dkv[..., L:].reshape(B, S, 1, dr), positions, cfg.rope_theta,
+               cfg.yarn)
     return ckv, kpe.reshape(B, S, dr)
+
+
+def _mla_absorbed(p, name, cfg, dtype, site):
+    """``w_uk`` or ``w_uv`` as [L, H, d] float32, for the absorbed form."""
+    from .quantize import resolve_weight
+
+    H, L = cfg.n_heads, cfg.kv_lora_rank
+    w = resolve_weight(p[name], numerics.weight_format(cfg.policy,
+                                                       f"{site}.{name}"),
+                       dtype)
+    return w.reshape(L, H, -1).astype(jnp.float32)
 
 
 def mla_forward(p, x, cfg, *, positions, q_chunk=512, kv_chunk=1024,
@@ -276,7 +316,8 @@ def mla_forward(p, x, cfg, *, positions, q_chunk=512, kv_chunk=1024,
     v = qlinear(ckv, p["w_uv"], pol, site=f"{site}.w_uv").reshape(B, S, H, dv)
     q = jnp.concatenate([q_nope, q_pe], axis=-1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(kpe[:, :, None, :], (B, S, H, dr))], axis=-1)
-    out = chunked_attention(q, k, v, causal=True, q_chunk=q_chunk, kv_chunk=kv_chunk)
+    out = chunked_attention(q, k, v, causal=True, q_chunk=q_chunk,
+                            kv_chunk=kv_chunk, scale=mla_softmax_scale(cfg))
     y = qlinear(out.reshape(B, S, -1), p["wo"], pol, site=f"{site}.wo")
     # cache representation must match the decode path: FP8 codes when the
     # KV cache is quantized (a raw float here would be garbage-cast to
@@ -307,17 +348,13 @@ def mla_decode(p, x, cfg, *, cache, pos, site="blocks.*.attn", **_):
     ckv, kpe = _kv_load(ckv, cfg), _kv_load(kpe, cfg)
     S = ckv.shape[1]
 
-    from .quantize import resolve_weight
-
-    wfmt = numerics.weight_format(cfg.policy, f"{site}.w_uk")
-    w_uk = resolve_weight(p["w_uk"], wfmt, x.dtype).reshape(L, H, dn)
     # absorb: q_eff[b,h,l] = sum_d q_nope[b,h,d] * w_uk[l,h,d]
     q_eff = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0].astype(jnp.float32),
-                       w_uk.astype(jnp.float32))
+                       _mla_absorbed(p, "w_uk", cfg, x.dtype, site))
     s = jnp.einsum("bhl,bsl->bhs", q_eff, ckv.astype(jnp.float32))
     s = s + jnp.einsum("bhr,bsr->bhs", q_pe[:, 0].astype(jnp.float32),
                        kpe.astype(jnp.float32))
-    s = s * (dn + dr) ** -0.5
+    s = s * mla_softmax_scale(cfg)
     t = jnp.arange(S)
     s = jnp.where((t[None, :] <= pos[:, None])[:, None, :], s, -2.0e30)
     m = s.max(-1, keepdims=True)
@@ -325,10 +362,43 @@ def mla_decode(p, x, cfg, *, cache, pos, site="blocks.*.attn", **_):
     den = pattn.sum(-1, keepdims=True)
     lat = jnp.einsum("bhs,bsl->bhl", pattn / jnp.maximum(den, 1e-37),
                      ckv.astype(jnp.float32))
-    w_uv = resolve_weight(
-        p["w_uv"], numerics.weight_format(cfg.policy, f"{site}.w_uv"), x.dtype
-    ).reshape(L, H, dv)
-    out = jnp.einsum("bhl,lhv->bhv", lat, w_uv.astype(jnp.float32))
+    out = jnp.einsum("bhl,lhv->bhv", lat,
+                     _mla_absorbed(p, "w_uv", cfg, x.dtype, site))
     y = qlinear(out.reshape(B, 1, H * dv).astype(x.dtype), p["wo"], cfg.policy,
                 site=f"{site}.wo")
     return y, cache
+
+
+def mla_decode_paged(p, x, cfg, *, cache, paged, site="blocks.*.attn"):
+    """Absorbed MLA decode against the global page pool (serving path).
+
+    cache: this layer's page arrays {"kp": rope keys [P, page, dr], "vp":
+    latents [P, page, L], "ks", "vs": [P] f32}; ``paged`` as in
+    :func:`gqa_decode_paged`, whose two serving contracts (explicit write
+    mask, position-addressed stochastic streams) hold here too.  The new
+    token's rope key and latent are written into their pages (a fresh page
+    takes a pow2 scale from its first row), and the paged kernel's MLA
+    mode attends: one KV head, the 16 query heads as its group, query
+    ``[q_pe ‖ q_eff]`` with ``q_eff = q_nope · W_uk``, values the latents.
+    The [B, H, L] latent output maps through ``W_uv`` and ``wo``.
+    Returns (y, new_cache).
+    """
+    B = x.shape[0]
+    H, dv = cfg.n_heads, cfg.v_head_dim
+    pol = cfg.policy
+    positions = jnp.asarray(paged["lengths"], jnp.int32)[:, None]
+    q_nope, q_pe = _mla_q(p, x, cfg, positions, site=site)  # [B,1,H,dn|dr]
+    ckv_new, kpe_new = _mla_latent(p, x, cfg, positions, site=site)
+    q_eff = jnp.einsum("bhd,lhd->bhl", q_nope[:, 0].astype(jnp.float32),
+                       _mla_absorbed(p, "w_uk", cfg, x.dtype, site))
+    q = jnp.concatenate([q_pe[:, 0].astype(jnp.float32), q_eff],
+                        axis=-1)[:, None]                  # [B,1,H,dr+L]
+    # the token's rows [B, 1, 1, dr|L]: the kernel's single KV head
+    out, new_cache = _paged_write_attend(
+        q, kpe_new[:, :, None], ckv_new[:, :, None], cache, paged, pol,
+        n_kv_heads=1, scale=mla_softmax_scale(cfg), site=site)
+    o = jnp.einsum("bhl,lhv->bhv", out[:, 0].astype(jnp.float32),
+                   _mla_absorbed(p, "w_uv", cfg, x.dtype, site))
+    y = qlinear(o.reshape(B, 1, H * dv).astype(x.dtype), p["wo"], pol,
+                site=f"{site}.wo")
+    return y, new_cache

@@ -15,10 +15,12 @@ per call site from the policy; no numeric strings are threaded here.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.quant import quantize
 from ..kernels import ops as kops
@@ -43,14 +45,53 @@ def qk_rms_norm(x, scale, eps=1e-6):
 # --------------------------------------------------------------------------- #
 # Rotary embeddings
 # --------------------------------------------------------------------------- #
-def rope(x, positions, theta: float):
-    """x: [..., S, H, hd]; positions: [..., S] int32."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``
+    (1 when the context is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_rope(dim: int, theta: float, yarn):
+    """YaRN's inverse frequencies [dim/2] (float32) and the factor on cos and
+    sin, as DeepSeek-V2 computes them: the extrapolated frequencies
+    ``theta^(-2i/dim)`` and the interpolated ones (divided by ``factor``)
+    blend over a linear ramp between the dimensions that make ``beta_fast``
+    and ``beta_slow`` rotations in ``original_max_position_embeddings``."""
+    half = dim // 2
+
+    def corr_dim(rotations):
+        return (dim * math.log(yarn.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = theta ** (-np.arange(half, dtype=np.float64) / half)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low),
+                   0.0, 1.0)
+    freqs = extra / yarn.factor * ramp + extra * (1.0 - ramp)
+    amp = (yarn_mscale(yarn.factor, yarn.mscale)
+           / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+    return freqs.astype(np.float32), amp
+
+
+def rope(x, positions, theta: float, yarn=None):
+    """x: [..., S, H, hd]; positions: [..., S] int32.  ``yarn``: a
+    :class:`repro.configs.base.Yarn` for YaRN-scaled frequencies."""
     hd = x.shape[-1]
     half = hd // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    amp = 1.0
+    if yarn is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs, amp = yarn_rope(hd, theta, yarn)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., S, half]
     cos = jnp.cos(angles)[..., :, None, :]
     sin = jnp.sin(angles)[..., :, None, :]
+    if amp != 1.0:
+        cos, sin = cos * amp, sin * amp
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
@@ -216,12 +257,14 @@ def chunked_attention(
     q, k, v, *,
     causal=True, window=0, cap=0.0, q_offset=0,
     q_chunk=512, kv_chunk=1024, k_len: Optional[jnp.ndarray] = None,
+    scale: Optional[float] = None,
 ):
     """GQA attention, O(q_chunk*kv_chunk) memory.
 
     q: [B, Sq, H, hd]; k/v: [B, Sk, KV, hd] with H % KV == 0.
     ``q_offset``: position of q[0] in the kv sequence (prefill continuation).
     ``k_len``: optional dynamic valid kv length (decode against a cache).
+    ``scale``: the softmax scale (default ``hd ** -0.5``).
     """
     B, Sq0, H, hd = q.shape
     _, Sk0, KV, _ = k.shape
@@ -252,14 +295,14 @@ def chunked_attention(
     if use_sp:
         out = _attention_sp(
             q, k, v, causal=causal, window=window, cap=cap, q_offset=q_offset,
-            q_chunk=q_chunk, kv_chunk=kv_chunk, k_len=k_len,
+            q_chunk=q_chunk, kv_chunk=kv_chunk, k_len=k_len, scale=scale,
         )
         return out[:, :Sq0]
 
     qc = q.reshape(B, nq, q_chunk, KV, G, hd)
     kc = k.reshape(B, nk, kv_chunk, KV, hd)
     vc = v.reshape(B, nk, kv_chunk, KV, dv)
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
 
     def q_step(qi_and_chunk):
         qi, qb = qi_and_chunk  # qb: [B, q_chunk, KV, G, hd]
@@ -303,6 +346,7 @@ def chunked_attention(
 
 def _attention_sp(
     q, k, v, *, causal, window, cap, q_offset, q_chunk, kv_chunk, k_len,
+    scale=None,
 ):
     """Sequence-parallel attention: q-chunk dim vectorized (sharded over
     ``model``), online-softmax scan over kv chunks.  Per-device score
@@ -315,7 +359,7 @@ def _attention_sp(
     qc = q.reshape(B, nq, q_chunk, KV, G, hd)  # nq sharded over model
     kc = jnp.moveaxis(k.reshape(B, nk, kv_chunk, KV, hd), 1, 0)
     vc = jnp.moveaxis(v.reshape(B, nk, kv_chunk, KV, dv), 1, 0)
-    scale = hd**-0.5
+    scale = hd**-0.5 if scale is None else scale
     q_pos = q_offset + (
         jnp.arange(nq)[:, None] * q_chunk + jnp.arange(q_chunk)[None, :]
     )  # [nq, q_chunk]

@@ -19,6 +19,7 @@ from .layers import rms_norm, softcap
 from .transformer import (
     AUX0,
     SubSpec,
+    serve_aux0,
     layer_specs,
     stack_decode,
     stack_forward,
@@ -194,7 +195,7 @@ class Model:
         x = self._embed(params, tokens[:, None])
         if cfg.family == "encdec":
             x = x + jnp.take(params["dec_pos"], pos, axis=0)[:, None]
-        aux = dict(AUX0)
+        aux = serve_aux0()
         new_prefix = []
         for i, (p, s, c) in enumerate(zip(
             params.get("prefix", ()), self.prefix_specs, cache.get("prefix", ())
@@ -223,14 +224,16 @@ class Model:
         ``active``: optional [B] bool write mask — idle slots' page
         writes land in the reserved null page and their dense cache
         entries are kept, so a slot whose block table still maps shared
-        prefix pages can never corrupt them.  GQA layers read/write the
-        page pool; MLA/SSM/cross entries keep their dense slot caches,
-        indexed by per-slot positions.  Returns (logits, new_cache).
+        prefix pages can never corrupt them.  Attention layers (GQA K/V,
+        MLA latents) read/write the page pool; SSM/cross entries keep their
+        dense slot caches, indexed by per-slot positions.  Returns
+        (logits, new_cache).
         """
-        return self._paged_token_step(
+        logits, cache, _ = self._paged_token_step(
             params, cache, tokens, lengths, block_tables,
             page_size=page_size, key=key, active=active, fused=fused,
         )
+        return logits, cache
 
     def _paged_token_step(self, params, cache, tokens, lengths, block_tables,
                           *, page_size: int, key, active, fused: bool = True):
@@ -239,8 +242,11 @@ class Model:
         ``active`` is None (every slot live — the plain decode path, traced
         without any masking ops) or a [B] bool vector: inactive slots'
         page writes are redirected to the reserved null page and their dense
-        cache entries (MLA latents, SSM states) are kept unchanged, so a
-        masked sub-step is a no-op for them.
+        cache entries (SSM states) are kept unchanged, so a masked sub-step
+        is a no-op for them.
+
+        Returns (logits, new_cache, routing counts of the active lanes:
+        int32 [2], assignments to held and to absent routed experts).
         """
         cfg = self.cfg
         B = tokens.shape[0]
@@ -256,7 +262,7 @@ class Model:
         x = self._embed(params, tokens[:, None])
         if cfg.family == "encdec":
             x = x + jnp.take(params["dec_pos"], lengths, axis=0)[:, None]
-        aux = dict(AUX0)
+        aux = serve_aux0()
         new_prefix = []
         for i, (p, s, c) in enumerate(zip(
             params.get("prefix", ()), self.prefix_specs, cache.get("prefix", ())
@@ -268,13 +274,16 @@ class Model:
             )
             new_prefix.append(nc)
         bkey = None if key is None else jax.random.fold_in(key, 0)
-        x, new_caches, _ = stack_decode(
+        x, new_caches, baux = stack_decode(
             params["blocks"], cache["blocks"], x, cfg, self.pattern,
             pos=lengths, paged=dict(paged, key=bkey),
         )
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = self._unembed(params, x[:, 0])
-        return logits, {"prefix": tuple(new_prefix), "blocks": new_caches}
+        counts = jnp.stack([aux["moe_held"] + baux["moe_held"],
+                            aux["moe_absent"] + baux["moe_absent"]])
+        return (logits, {"prefix": tuple(new_prefix), "blocks": new_caches},
+                counts)
 
     def step_paged(self, params, cache, tokens, lengths, n_new, block_tables,
                    *, page_size: int, key=None, fused: bool = True):
@@ -306,7 +315,9 @@ class Model:
         content (the prefix-cache bit-identity contract).
 
         Returns (logits [B, vocab_padded] of each slot's LAST valid token —
-        zeros for idle slots — and the new cache).
+        zeros for idle slots —, the new cache, and the step's routing
+        counts int32 [2]: its valid tokens' assignments to held and to
+        absent routed experts, summed over the expert layers).
         """
         cfg = self.cfg
         B, T = tokens.shape
@@ -316,21 +327,22 @@ class Model:
         last0 = jnp.zeros((B, cfg.vocab_padded), jnp.float32)
 
         def body(carry, scanned):
-            cache, last = carry
+            cache, last, counts = carry
             t, toks_t = scanned
             act = t < n_new
             pos = lengths + jnp.minimum(t, jnp.maximum(n_new - 1, 0))
-            logits, cache = self._paged_token_step(
+            logits, cache, c = self._paged_token_step(
                 params, cache, toks_t, pos, block_tables,
                 page_size=page_size, key=key, active=act, fused=fused,
             )
             last = jnp.where(act[:, None], logits, last)
-            return (cache, last), None
+            return (cache, last, counts + c), None
 
-        (cache, last), _ = jax.lax.scan(
-            body, (cache, last0), (jnp.arange(T), tokens.T)
+        (cache, last, counts), _ = jax.lax.scan(
+            body, (cache, last0, jnp.zeros((2,), jnp.int32)),
+            (jnp.arange(T), tokens.T)
         )
-        return last, cache
+        return last, cache, counts
 
     # ------------------------------------------------------------------ #
     def _entry_cache(self, spec: SubSpec, B: int, S: int):
@@ -379,29 +391,38 @@ class Model:
 
     def _entry_cache_paged(self, spec: SubSpec, B: int, S: int,
                            num_pages: int, page_size: int):
-        """Per-layer paged entry: GQA KV lives in the global page pool;
-        MLA/SSM/cross entries keep their dense per-slot representation."""
+        """Per-layer paged entry: attention state lives in the global page
+        pool — GQA's K and V pages [P, page, KV, hd], MLA's rope keys
+        ("kp", [P, page, rope]) and latents ("vp", [P, page, lora]), the
+        paged kernel's two operands in its MLA mode — each with one f32
+        scale per page.  SSM and cross entries keep their dense per-slot
+        representation."""
         from .. import numerics
 
         cfg = self.cfg
-        e = self._entry_cache(spec, B, S)
-        if spec.mixer == "attn" and cfg.attn_impl != "mla":
-            dt = jnp.uint8 if numerics.kv_quantized(cfg.policy) else cfg.pdtype
-            pshape = (num_pages, page_size, cfg.n_kv_heads, cfg.hd)
-            e["self"] = {
-                "kp": jnp.zeros(pshape, dt),
-                "vp": jnp.zeros(pshape, dt),
-                "ks": jnp.ones((num_pages,), jnp.float32),
-                "vs": jnp.ones((num_pages,), jnp.float32),
-            }
+        if spec.mixer != "attn":
+            return self._entry_cache(spec, B, S)
+        e = self._entry_cache(spec, B, 0)  # the cross entries, if any
+        dt = jnp.uint8 if numerics.kv_quantized(cfg.policy) else cfg.pdtype
+        if cfg.attn_impl == "mla":
+            kshape = (num_pages, page_size, cfg.qk_rope_dim)
+            vshape = (num_pages, page_size, cfg.kv_lora_rank)
+        else:
+            kshape = vshape = (num_pages, page_size, cfg.n_kv_heads, cfg.hd)
+        e["self"] = {
+            "kp": jnp.zeros(kshape, dt),
+            "vp": jnp.zeros(vshape, dt),
+            "ks": jnp.ones((num_pages,), jnp.float32),
+            "vs": jnp.ones((num_pages,), jnp.float32),
+        }
         return e
 
     def make_paged_cache(self, B: int, num_pages: int, page_size: int,
                          S: int = 0):
         """Decode cache backed by a ``num_pages``-page pool (page 0 is the
-        reserved null page).  Cache memory for GQA layers scales with the
-        pool size, not with slots * max_seq; ``S`` only sizes the dense
-        fallback entries (MLA latent caches, SSM states, cross KV)."""
+        reserved null page).  Cache memory for attention layers (GQA K/V,
+        MLA latents) scales with the pool size, not with slots * max_seq;
+        ``S`` only sizes the dense entries (SSM states)."""
         S = S or self.max_seq
         prefix = tuple(
             self._entry_cache_paged(s, B, S, num_pages, page_size)
@@ -430,13 +451,13 @@ def count_params(cfg, active_only: bool = False, max_seq: int = 1024) -> int:
     )
     if not active_only or cfg.n_experts == 0:
         return total
-    # subtract the inactive fraction of routed expert weights
+    # subtract the inactive fraction of the routed expert weights held
     E, k = cfg.n_experts, cfg.top_k
     per_expert = 3 * cfg.d_model * cfg.moe_d_ff
     n_moe_layers = sum(
         1 for i in range(cfg.n_layers) if cfg.is_moe_layer(i)
     )
-    routed = n_moe_layers * E * per_expert
+    routed = n_moe_layers * cfg.held_range[1] * per_expert
     return total - int(routed * (E - k) / E)
 
 

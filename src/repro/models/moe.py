@@ -5,8 +5,20 @@ scatter (no [T, E, C] one-hot tensor — the buffers are [E, C, D], which
 shards cleanly: E over the ``model`` mesh axis for expert parallelism, or
 the expert FFN dim over ``model`` when E doesn't divide the axis).
 
-Router runs in f32 (correct top-k under bf16 params).  Aux losses: Switch
-load-balance and router z-loss, returned for the trainer to weigh in.
+Router runs in f32 (correct top-k under bf16 params), softmax over every
+routed expert, greedy top-k; the gates are renormalized over the top k
+only when ``cfg.norm_topk_prob``.  Aux losses: Switch load-balance and
+router z-loss, returned for the trainer to weigh in.
+
+Training (:func:`moe_ffn`) drops the tokens past an expert's capacity.
+Serving's decode and mixed steps (:func:`moe_serve`) are dropless: a
+request's output never depends on who shares its batch.  The bucketed
+scheduler's prefill (``Model.prefill``) is the exception: it runs
+:func:`moe_ffn`, whose dispatch buffers stay at the capacity (a dropless
+one would hold [held experts, prompt tokens, D]).  A layer told
+that it holds only some routed experts (``cfg.held_experts``, expert
+parallelism) routes over all of them and adds only its held experts'
+part of the result.
 """
 from __future__ import annotations
 
@@ -20,14 +32,15 @@ from .layers import _act, qlinear
 
 def moe_init(rng, cfg):
     D, E, F = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
+    Eh = cfg.held_range[1]
     dt = cfg.pdtype
     ks = jax.random.split(rng, 5)
     scale = 0.02
     p = {
         "router": (jax.random.normal(ks[0], (D, E), jnp.float32) * scale),
-        "w_gate": (jax.random.normal(ks[1], (E, D, F), jnp.float32) * scale).astype(dt),
-        "w_up": (jax.random.normal(ks[2], (E, D, F), jnp.float32) * scale).astype(dt),
-        "w_down": (jax.random.normal(ks[3], (E, F, D), jnp.float32) * scale).astype(dt),
+        "w_gate": (jax.random.normal(ks[1], (Eh, D, F), jnp.float32) * scale).astype(dt),
+        "w_up": (jax.random.normal(ks[2], (Eh, D, F), jnp.float32) * scale).astype(dt),
+        "w_down": (jax.random.normal(ks[3], (Eh, F, D), jnp.float32) * scale).astype(dt),
     }
     if cfg.n_shared_experts:
         Fs = cfg.n_shared_experts * F
@@ -78,6 +91,83 @@ def _expert_mm(buf, w, pol, site=""):
 def capacity(T: int, k: int, E: int, factor: float) -> int:
     c = int(T * k / E * factor)
     return max(8, -(-c // 8) * 8)  # multiple of 8, at least 8
+
+
+def _route(p, xf, cfg):
+    """Router of tokens xf [T, D]: (logits [T, E] f32, probs, gates [T, k],
+    expert ids [T, k])."""
+    logits = xf.astype(jnp.float32) @ p["router"]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_vals, ids = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True),
+                                            1e-9)
+    return logits, probs, gate_vals, ids
+
+
+def _dispatch(p, xf, gate_vals, ids, cfg, C: int, site: str):
+    """Sorted dispatch of tokens xf [T, D] to the held routed experts, ``C``
+    slots each (an assignment past its expert's ``C`` is dropped; ``C >=
+    T`` drops none, since a token takes an expert at most once).  Returns
+    the gate-weighted sum of the held experts' outputs, [T, D] float32."""
+    T, D = xf.shape
+    E, k = cfg.n_experts, cfg.top_k
+    first, n_held = cfg.held_range
+    flat_ids = ids.reshape(-1)
+    order = jnp.argsort(flat_ids, stable=True)
+    tok = order // k
+    eid = flat_ids[order]
+    starts = jnp.searchsorted(eid, jnp.arange(E))
+    rank = jnp.arange(T * k) - starts[eid]
+    fits = rank < C
+    keep = fits
+    if n_held < E:  # absent experts' assignments add nothing here
+        keep = fits & (eid >= first) & (eid < first + n_held)
+        eid = jnp.clip(eid - first, 0, n_held - 1)
+    rank_c = jnp.where(fits, rank, C - 1)
+
+    buf = jnp.zeros((n_held, C, D), xf.dtype).at[eid, rank_c].add(
+        xf[tok] * keep[:, None].astype(xf.dtype)
+    )
+    h = _act(_expert_mm(buf, p["w_gate"], cfg.policy, f"{site}.w_gate"),
+             cfg.act_fn)
+    h = h * _expert_mm(buf, p["w_up"], cfg.policy, f"{site}.w_up")
+    y = _expert_mm(h, p["w_down"], cfg.policy, f"{site}.w_down")
+
+    g_sorted = gate_vals.reshape(-1)[order] * keep
+    return jnp.zeros((T, D), jnp.float32).at[tok].add(
+        y[eid, rank_c].astype(jnp.float32) * g_sorted[:, None]
+    )
+
+
+def moe_serve(p, x, cfg, *, valid=None, site="blocks.*.ffn"):
+    """The served expert layer (decode and mixed steps): x [B, S, D] ->
+    (out [B, S, D], counts).
+
+    Dropless: every token of the step is one dispatch group and each held
+    expert has a slot for every token, so no assignment is dropped and a
+    request's output does not depend on its batch.  The buffers are
+    [held experts, B*S, D].  The shared experts are computed in full.
+    ``counts`` holds the step's routing assignments of the ``valid`` [B]
+    lanes (all lanes when None) to held and to absent experts, int32
+    scalars ``moe_held`` and ``moe_absent``."""
+    from .layers import gated_mlp
+
+    B, S, D = x.shape
+    xf = x.reshape(B * S, D)
+    _, _, gate_vals, ids = _route(p, xf, cfg)
+    out = _dispatch(p, xf, gate_vals, ids, cfg, B * S, site)
+    out = out.reshape(B, S, D).astype(x.dtype)
+    if "shared" in p:
+        out = out + gated_mlp(x, p["shared"], cfg.policy, cfg.act_fn,
+                              site=f"{site}.shared")
+    first, n_held = cfg.held_range
+    held = (ids >= first) & (ids < first + n_held)
+    lane = jnp.ones((B * S, 1), bool) if valid is None else jnp.repeat(
+        jnp.asarray(valid, bool), S)[:, None]
+    counts = {"moe_held": jnp.sum(held & lane, dtype=jnp.int32),
+              "moe_absent": jnp.sum(~held & lane, dtype=jnp.int32)}
+    return out, counts
 
 
 def moe_ffn(p, x, cfg, site="blocks.*.ffn") -> Tuple[jnp.ndarray, dict]:
@@ -145,18 +235,8 @@ def _moe_ffn_shard_map(p, x, cfg, mesh, hint_specs,
         xf = x_loc.reshape(-1, D)
         Tg = xf.shape[0]
         k = cfg.top_k
-        logits = xf.astype(jnp.float32) @ p_loc["router"]
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_vals, ids = jax.lax.top_k(probs, k)
-        gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-        me = probs.mean(0)
-        counts = jnp.zeros((E,), jnp.float32).at[ids.reshape(-1)].add(1.0)
-        ce = counts / (Tg * k)
-        aux = {
-            "moe_lb": E * jnp.sum(me * ce),
-            "moe_z": jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2),
-        }
+        logits, probs, gate_vals, ids = _route(p_loc, xf, cfg)
+        aux = _aux_losses(logits, probs, ids, cfg)
         for ax in mesh.axis_names:
             aux = {kk: jax.lax.pmean(vv, ax) for kk, vv in aux.items()}
 
@@ -237,88 +317,35 @@ def _moe_ffn_grouped(p, x, cfg, site="blocks.*.ffn") -> Tuple[jnp.ndarray, dict]
     return out, aux
 
 
+def _aux_losses(logits, probs, ids, cfg) -> dict:
+    """Switch load-balance and router z-loss of one routed group."""
+    E, k = cfg.n_experts, cfg.top_k
+    me = probs.mean(0)  # mean router prob per expert
+    counts = jnp.zeros((E,), jnp.float32).at[ids.reshape(-1)].add(1.0)
+    ce = counts / (ids.shape[0] * k)  # fraction of routed slots per expert
+    return {"moe_lb": E * jnp.sum(me * ce),
+            "moe_z": jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2)}
+
+
 def _dispatch_group(p, xf, cfg, site="blocks.*.ffn") -> Tuple[jnp.ndarray, dict]:
     """Sorted-capacity dispatch over one token group [Tg, D] (local)."""
     Tg, D = xf.shape
-    E, k = cfg.n_experts, cfg.top_k
-    logits = xf.astype(jnp.float32) @ p["router"]
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, ids = jax.lax.top_k(probs, k)
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    me = probs.mean(0)
-    counts = jnp.zeros((E,), jnp.float32).at[ids.reshape(-1)].add(1.0)
-    ce = counts / (Tg * k)
-    aux = {"moe_lb": E * jnp.sum(me * ce),
-           "moe_z": jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2)}
-
-    C = capacity(Tg, k, E, cfg.capacity_factor)
-    flat_ids = ids.reshape(-1)
-    order = jnp.argsort(flat_ids, stable=True)
-    tok = order // k
-    eid = flat_ids[order]
-    starts = jnp.searchsorted(eid, jnp.arange(E))
-    rank = jnp.arange(Tg * k) - starts[eid]
-    keep = rank < C
-    rank_c = jnp.where(keep, rank, C - 1)
-
-    buf = jnp.zeros((E, C, D), xf.dtype).at[eid, rank_c].add(
-        xf[tok] * keep[:, None].astype(xf.dtype)
-    )
-    h = _act(_expert_mm(buf, p["w_gate"], cfg.policy, f"{site}.w_gate"),
-             cfg.act_fn)
-    h = h * _expert_mm(buf, p["w_up"], cfg.policy, f"{site}.w_up")
-    y = _expert_mm(h, p["w_down"], cfg.policy, f"{site}.w_down")
-
-    g_sorted = gate_vals.reshape(-1)[order] * keep
-    out = jnp.zeros((Tg, D), jnp.float32).at[tok].add(
-        y[eid, rank_c].astype(jnp.float32) * g_sorted[:, None]
-    )
+    logits, probs, gate_vals, ids = _route(p, xf, cfg)
+    aux = _aux_losses(logits, probs, ids, cfg)
+    C = capacity(Tg, cfg.top_k, cfg.n_experts, cfg.capacity_factor)
+    out = _dispatch(p, xf, gate_vals, ids, cfg, C, site)
     return out.astype(xf.dtype), aux
 
 
 def _moe_ffn_global(p, x, cfg, site="blocks.*.ffn") -> Tuple[jnp.ndarray, dict]:
     B, S, D = x.shape
-    E, k = cfg.n_experts, cfg.top_k
     T = B * S
     xf = x.reshape(T, D)
 
-    logits = xf.astype(jnp.float32) @ p["router"]  # [T, E] f32
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_vals, ids = jax.lax.top_k(probs, k)  # [T, k]
-    gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
-
-    # ---- aux losses (Switch LB + z-loss) ------------------------------- #
-    me = probs.mean(0)  # mean router prob per expert
-    one_hot_counts = jnp.zeros((E,), jnp.float32).at[ids.reshape(-1)].add(1.0)
-    ce = one_hot_counts / (T * k)  # fraction of routed slots per expert
-    aux_lb = E * jnp.sum(me * ce)
-    aux_z = jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2)
-
-    # ---- sorted capacity dispatch -------------------------------------- #
-    C = capacity(T, k, E, cfg.capacity_factor)
-    flat_ids = ids.reshape(-1)  # [T*k]
-    order = jnp.argsort(flat_ids, stable=True)
-    tok = order // k
-    eid = flat_ids[order]
-    starts = jnp.searchsorted(eid, jnp.arange(E))
-    rank = jnp.arange(T * k) - starts[eid]
-    keep = rank < C
-    rank_c = jnp.where(keep, rank, C - 1)
-
-    buf = jnp.zeros((E, C, D), x.dtype)
-    vals = xf[tok] * keep[:, None].astype(x.dtype)
-    buf = buf.at[eid, rank_c].add(vals)
-
-    h = _act(_expert_mm(buf, p["w_gate"], cfg.policy, f"{site}.w_gate"),
-             cfg.act_fn)
-    h = h * _expert_mm(buf, p["w_up"], cfg.policy, f"{site}.w_up")
-    y = _expert_mm(h, p["w_down"], cfg.policy, f"{site}.w_down")  # [E, C, D]
-
-    g_sorted = gate_vals.reshape(-1)[order] * keep
-    out = jnp.zeros((T, D), jnp.float32)
-    out = out.at[tok].add(y[eid, rank_c].astype(jnp.float32) * g_sorted[:, None])
-    out = out.astype(x.dtype)
+    logits, probs, gate_vals, ids = _route(p, xf, cfg)
+    aux = _aux_losses(logits, probs, ids, cfg)
+    C = capacity(T, cfg.top_k, cfg.n_experts, cfg.capacity_factor)
+    out = _dispatch(p, xf, gate_vals, ids, cfg, C, site).astype(x.dtype)
 
     if "shared" in p:
         from .layers import gated_mlp
@@ -326,4 +353,4 @@ def _moe_ffn_global(p, x, cfg, site="blocks.*.ffn") -> Tuple[jnp.ndarray, dict]:
         out = out + gated_mlp(x, p["shared"], cfg.policy, cfg.act_fn,
                               site=f"{site}.shared").reshape(T, D)
 
-    return out.reshape(B, S, D), {"moe_lb": aux_lb, "moe_z": aux_z}
+    return out.reshape(B, S, D), aux

@@ -26,12 +26,13 @@ from .attention import (
     gqa_forward,
     gqa_init,
     mla_decode,
+    mla_decode_paged,
     mla_forward,
     mla_init,
 )
 from .layers import gated_mlp, qlinear, rms_norm
 from .mamba2 import mamba2_init, ssd_decode, ssd_forward
-from .moe import moe_ffn, moe_init
+from .moe import moe_ffn, moe_init, moe_serve
 
 
 @dataclasses.dataclass(frozen=True)
@@ -175,14 +176,21 @@ def sublayer_decode(p, spec: SubSpec, x, cfg, *, cache, pos, aux=None,
     step's shared paged-cache state (block tables, lengths, page size, PRNG
     key) — attention sublayers whose cache entry is paged (has "kp") route
     through the page pool; everything else uses the dense slot cache.
+    ``aux`` carries the step's routing counts (:func:`serve_aux0`); expert
+    layers run the dropless served dispatch.
     """
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
     new_cache: Dict[str, Any] = {}
+    active = None if paged is None else paged.get("active")
+    is_paged = paged is not None and "kp" in cache["self"]
     if spec.mixer == "attn":
-        if cfg.attn_impl == "mla":
+        if cfg.attn_impl == "mla" and is_paged:
+            out, c = mla_decode_paged(p["attn"], h, cfg, cache=cache["self"],
+                                      paged=paged, site=f"{site}.attn")
+        elif cfg.attn_impl == "mla":
             out, c = mla_decode(p["attn"], h, cfg, cache=cache["self"],
                                 pos=pos, site=f"{site}.attn")
-        elif paged is not None and "kp" in cache["self"]:
+        elif is_paged:
             out, c = gqa_decode_paged(
                 p["attn"], h, cfg, is_global=spec.attn_global,
                 cache=cache["self"], paged=paged, use_rope=_use_rope(cfg),
@@ -196,13 +204,11 @@ def sublayer_decode(p, spec: SubSpec, x, cfg, *, cache, pos, aux=None,
             )
     else:
         out, c = ssd_decode(p["mamba"], h, cfg, cache["self"])
-    active = None if paged is None else paged.get("active")
     if active is not None and "kp" not in c:
         # masked sub-step of a mixed prefill+decode batch: dense per-slot
-        # entries (MLA latents, SSM states, dense KV rows) of inactive
-        # slots must not advance — keep the old entry for them.  Paged
-        # entries need no select: their inactive writes already went to
-        # the null page (gqa_decode_paged).
+        # entries (SSM states, dense KV rows) of inactive slots must not
+        # advance — keep the old entry for them.  Paged entries need no
+        # select: their inactive writes already went to the null page.
         c = jax.tree.map(
             lambda nv, ov: jnp.where(
                 active.reshape((-1,) + (1,) * (nv.ndim - 1)), nv, ov
@@ -227,9 +233,10 @@ def sublayer_decode(p, spec: SubSpec, x, cfg, *, cache, pos, aux=None,
     if spec.ffn != "none":
         h = rms_norm(x, p["ln2"], cfg.norm_eps)
         if spec.ffn == "moe":
-            out, moe_aux = moe_ffn(p["ffn"], h, cfg, site=f"{site}.ffn")
+            out, counts = moe_serve(p["ffn"], h, cfg, valid=active,
+                                    site=f"{site}.ffn")
             if aux is not None:
-                aux = {k: aux[k] + moe_aux[k] for k in aux}
+                aux = {k: aux[k] + counts[k] for k in aux}
         else:
             out = gated_mlp(h, p["ffn"], cfg.policy, cfg.act_fn,
                             site=f"{site}.ffn")
@@ -243,6 +250,13 @@ def sublayer_decode(p, spec: SubSpec, x, cfg, *, cache, pos, aux=None,
 # Stack: scan over blocks of P sublayers
 # --------------------------------------------------------------------------- #
 AUX0 = {"moe_lb": 0.0, "moe_z": 0.0}
+
+
+def serve_aux0():
+    """Zero routing counts of a served step: assignments of the step's
+    valid tokens to held and to absent routed experts, over its layers."""
+    return {"moe_held": jnp.zeros((), jnp.int32),
+            "moe_absent": jnp.zeros((), jnp.int32)}
 
 
 def stack_init(rng, cfg, pattern: List[SubSpec], n_blocks: int):
@@ -316,7 +330,7 @@ def stack_decode(blocks, caches, x, cfg, pattern, *, pos, paged=None):
         return (x, aux, caches), None
 
     (x, aux, new_caches), _ = jax.lax.scan(
-        block_fn, (x, dict(AUX0), caches),
+        block_fn, (x, serve_aux0(), caches),
         (blocks, jnp.arange(n_blocks), keys)
     )
     return x, new_caches, aux

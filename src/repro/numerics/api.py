@@ -303,12 +303,14 @@ def kv_write_prefill(pol: PolicyLike, pages, scales, src, page_ids, *,
 # --------------------------------------------------------------------------- #
 def attention(q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
               pol: PolicyLike, *, n_kv_heads: int, window: int = 0,
-              cap: float = 0.0, site: str = ""):
+              cap: float = 0.0, scale: Optional[float] = None, site: str = ""):
     """Paged decode attention under the policy.
 
     QK^T runs in the LNS integer domain off the page codes when the KV
     cache is quantized (``attention_qk`` resolves format/mode/impl);
-    float pages take the float path.  Returns [B, 1, H, dv] in q.dtype.
+    float pages take the float path.  ``scale`` is the softmax scale (None:
+    the head width ** -0.5); a query wider than its k pages is MLA's (see
+    ``kernels.paged_attention``).  Returns [B, 1, H, dv] in q.dtype.
     """
     from ..kernels.paged_attention import paged_decode_attention
 
@@ -317,7 +319,7 @@ def attention(q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
         return paged_decode_attention(
             q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
             fmt=fmt, n_kv_heads=n_kv_heads, mode=pol.mode, window=window,
-            cap=cap,
+            cap=cap, scale=scale,
         )
     qk = pol.resolve("attention_qk", site) if pol is not None else OpPolicy()
     fmt = kv_format(pol)
@@ -326,7 +328,7 @@ def attention(q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
     return paged_decode_attention(
         q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
         fmt=fmt, n_kv_heads=n_kv_heads, mode=mode, window=window, cap=cap,
-        impl=impl, site=site,
+        scale=scale, impl=impl, site=site,
     )
 
 
@@ -334,7 +336,7 @@ def kv_fused_write_attend(q, k_new, v_new, k_pages, v_pages, k_scale,
                           v_scale, block_tables, lengths, pol: PolicyLike, *,
                           n_kv_heads: int, k_key=None, v_key=None,
                           write_mask=None, window: int = 0, cap: float = 0.0,
-                          site: str = ""):
+                          scale: Optional[float] = None, site: str = ""):
     """Fused decode-token KV write + paged attention, policy-resolved.
 
     One launch replacing the ``kv_write_token`` x2 -> ``attention``
@@ -365,5 +367,5 @@ def kv_fused_write_attend(q, k_new, v_new, k_pages, v_pages, k_scale,
         q, k_new, v_new, k_pages, v_pages, k_scale, v_scale, block_tables,
         lengths, fmt=fmt, n_kv_heads=n_kv_heads, mode=mode, kv_mode=kv_mode,
         k_key=k_key, v_key=v_key, write_mask=write_mask, window=window,
-        cap=cap, impl=impl, site=site,
+        cap=cap, scale=scale, impl=impl, site=site,
     )

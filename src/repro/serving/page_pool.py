@@ -757,6 +757,8 @@ def write_token_page(pages, scales, new, page_ids, rows, *,
         write_mask=write_mask, store_dtype=pages.dtype,
     )
     rows = jnp.asarray(rows, jnp.int32)
+    # [P, page, d] pages (MLA) take the [B, 1, d] row without its head axis
+    codes = codes.reshape(codes.shape[:1] + pages.shape[2:])
     pages = pages.at[page_ids, rows].set(codes)
     if fmt is not None:
         scales = scales.at[page_ids].set(s)
@@ -769,7 +771,8 @@ def write_prefill_pages(pages, scales, src, page_ids, *,
     """Splice a prefill cache row into freshly allocated pages.
 
     pages: [P, page, KV, hd]; scales: [P]; src: [S, KV, hd] — scale-1 FP8
-    codes (the dense prefill cache representation) or float; page_ids:
+    codes (the dense prefill cache representation) or float ([P, page, d]
+    pages, MLA's, take src [S, d]); page_ids:
     [n_pages] int32 with n_pages * page_size >= S.  Per-page scales come
     from the page content's absmax; the code -> code rescale is the LNS
     multiply with stochastic carry-ins (exact here because page scales are
@@ -780,16 +783,16 @@ def write_prefill_pages(pages, scales, src, page_ids, *,
     page = pages.shape[1]
     S = src.shape[0]
     pad = n_pages * page - S
-    srcp = jnp.pad(src, ((0, pad), (0, 0), (0, 0))) if pad else src
+    srcp = jnp.pad(src, ((0, pad),) + ((0, 0),) * (src.ndim - 1)) if pad else src
     srcp = srcp.reshape(n_pages, page, *src.shape[1:])
     if fmt is None:
         pages = pages.at[page_ids].set(srcp.astype(pages.dtype))
         return pages, scales
     vals = code_to_f32(srcp, fmt)  # scale-1 decode of the dense cache codes
-    amax = jnp.max(jnp.abs(vals), axis=(1, 2, 3))
+    amax = jnp.max(jnp.abs(vals), axis=tuple(range(1, srcp.ndim)))
     s = pow2_page_scale(amax, fmt)
-    codes = rescale_codes(srcp, (1.0 / s)[:, None, None, None], fmt,
-                          mode=mode, key=key)
+    inv = (1.0 / s).reshape((-1,) + (1,) * (srcp.ndim - 1))
+    codes = rescale_codes(srcp, inv, fmt, mode=mode, key=key)
     pages = pages.at[page_ids].set(codes)
     scales = scales.at[page_ids].set(s)
     return pages, scales

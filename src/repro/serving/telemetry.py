@@ -223,6 +223,14 @@ METRIC_CATALOG: Tuple[MetricSpec, ...] = (
                "slot), kind=grid the pages a walk of every slot's max_seq "
                "would visit.",
                labels=("kind",)),
+    MetricSpec("moe_assignments_total", "counter",
+               "Routing assignments of the valid tokens of each mixed step, "
+               "summed over the expert layers: expert=held counts those to "
+               "the routed experts this engine holds, expert=absent those "
+               "to experts another chip of the deployment holds.  Counted "
+               "on the device and fetched with the step's logits (also the "
+               "moe_held/moe_absent args of its fetch span).",
+               labels=("expert",)),
     # -- compiles (process registry) ---------------------------------
     MetricSpec("jit_compiles_total", "counter",
                "Programs XLA compiled in this process (not read from the "

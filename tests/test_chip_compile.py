@@ -164,3 +164,43 @@ def test_fp8_elementwise_compiles(one_chip, op):
     text = _compile_text(ew, one_chip, ((8, 4864), jnp.uint8),
                          ((8, 4864), jnp.uint8))
     assert "tpu_custom_call" in text
+
+
+# DeepSeek-V2-Lite's latent attention: one KV head, 16 query heads, rope
+# keys of 64 and latents of 512 lanes; the decode-long cell's 64 x 96 pages
+MLA_H, MLA_DR, MLA_L = 16, 64, 512
+
+
+def _mla_args(slots=LONG_SLOTS, maxp=LONG_MAXP):
+    pool = slots * maxp + 1
+    return [((slots, 1, MLA_H, MLA_DR + MLA_L), jnp.float32),
+            ((pool, PAGE, MLA_DR), jnp.uint8), ((pool, PAGE, MLA_L), jnp.uint8),
+            ((pool,), jnp.float32), ((pool,), jnp.float32),
+            ((slots, maxp), jnp.int32), ((slots,), jnp.int32)]
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["unfused", "fused"])
+def test_paged_kernel_compiles_in_mla_mode(one_chip, fused):
+    """The kernel's MLA mode at the served block shape: the 64-lane rope
+    key pages gathered as [8, 128] rows, the latent pages as [16, 512],
+    the query [q_pe ‖ q_eff] of 576 lanes."""
+    ppb, spb = PAGED_BLOCKS
+    kw = dict(fmt="e5m2", n_kv_heads=1, mode="rne", window=0, cap=0.0,
+              impl="kernel", interpret=False, ppb=ppb, spb=spb,
+              scale=0.1)
+    if fused:
+        def fn(q, kp, vp, ks, vs, bt, ln, k_new, v_new, keys, mask):
+            return pa._fused_decode_write_attend(
+                q, k_new, v_new, kp, vp, ks, vs, bt, ln, keys, keys, mask,
+                kv_mode="stochastic", **kw)
+
+        extra = [((LONG_SLOTS, 1, MLA_DR), jnp.float32),
+                 ((LONG_SLOTS, 1, MLA_L), jnp.float32),
+                 ((LONG_SLOTS, 2), jnp.uint32), ((LONG_SLOTS,), jnp.bool_)]
+    else:
+        def fn(q, kp, vp, ks, vs, bt, ln):
+            return pa._paged_decode_attention(q, kp, vp, ks, vs, bt, ln, **kw)
+
+        extra = []
+    text = _compile_text(fn, one_chip, *_mla_args(), *extra)
+    assert "tpu_custom_call" in text

@@ -5,6 +5,8 @@ pure-JAX reference (the subsystem's numerics contract), the page pool
 allocator, page write/splice quantization, the per-slot position vector
 decode path, and end-to-end paged-vs-dense engine agreement.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -58,39 +60,62 @@ def _page_edge_inputs(seed, *, B=5, H=4, KV=2, hd=16, page=8, maxp=5):
             jnp.asarray(lengths))
 
 
+def _mla_inputs(make, seed, *, H=4, dr=8, L=32):
+    """MLA-mode operands over ``make``'s block tables and lengths: query
+    ``[q_pe ‖ q_eff]`` [B, 1, H, dr + L], rope-key pages [P, page, dr] and
+    latent pages [P, page, L] as E5M2 codes, with per-page scales."""
+    _, kf, _, _, _, ks, vs, bt, lengths = make(seed)
+    P, page = kf.shape[:2]
+    rng = np.random.default_rng(1000 + seed)
+    q = jnp.asarray(rng.standard_normal((bt.shape[0], 1, H, dr + L))
+                    .astype(np.float32))
+    kpe = rng.standard_normal((P, page, dr)).astype(np.float32)
+    ckv = rng.standard_normal((P, page, L)).astype(np.float32)
+    return (q, kpe, ckv, encode(jnp.asarray(kpe), "e5m2"),
+            encode(jnp.asarray(ckv), "e5m2"), ks, vs, bt, lengths)
+
+
 # --------------------------------------------------------------------------- #
 # Kernel == reference == batch, bit for bit (the acceptance contract)
 # --------------------------------------------------------------------------- #
 @pytest.mark.parametrize("seed", range(4))
 @pytest.mark.parametrize(
     "kw", [dict(), dict(window=7, cap=25.0), dict(mode="faithful"),
-           dict(edges=True), dict(edges=True, window=7, cap=25.0)],
+           dict(edges=True), dict(edges=True, window=7, cap=25.0),
+           dict(mla=True), dict(mla=True, edges=True)],
     ids=["plain", "window-cap", "faithful", "page-edges",
-         "page-edges-window-cap"],
+         "page-edges-window-cap", "mla", "mla-page-edges"],
 )
 def test_paged_lns_kernel_bit_identical_to_ref(seed, kw):
     """kernel == ref == batch, unfused and fused (the token written at
     ``lengths - 1``); at page-edge lengths the kernel's per-page partials
     also equal the batch's for several block shapes, and every page it
-    skips reads exactly (NEG_INF, 0, 0)."""
+    skips reads exactly (NEG_INF, 0, 0).  The ``mla`` cases run the
+    kernel's MLA mode: one KV head, a query of rope and latent lanes
+    against rope-key and latent pages, the latents as values."""
     from repro.kernels import paged_attention as pa
 
     kw = dict(kw)
     edges = kw.pop("edges", False)
-    q, kf, vf, kp, vp, ks, vs, bt, lengths = (
-        _page_edge_inputs if edges else _paged_inputs)(seed)
+    make = _page_edge_inputs if edges else _paged_inputs
+    KV = 2
+    if kw.pop("mla", False):
+        KV, kw["scale"] = 1, 0.3
+        make = functools.partial(_mla_inputs, make)
+    q, kf, vf, kp, vp, ks, vs, bt, lengths = make(seed)
     args = (q, kp, vp, ks, vs, bt, lengths)
-    outs = [paged_decode_attention(*args, fmt="e5m2", n_kv_heads=2,
+    outs = [paged_decode_attention(*args, fmt="e5m2", n_kv_heads=KV,
                                    impl=impl, interpret=True, **kw)
             for impl in ("ref", "batch", "kernel")]
     for o in outs[1:]:
         np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(o))
 
     rng = np.random.default_rng(100 + seed)
-    new = [jnp.asarray(rng.standard_normal((q.shape[0], 2, q.shape[3]))
-                       .astype(np.float32)) for _ in range(2)]
+    new = [jnp.asarray(rng.standard_normal((q.shape[0], KV, d))
+                       .astype(np.float32))
+           for d in (kp.shape[-1], vp.shape[-1])]
     fused = [pa.fused_decode_write_attend(
-        q, *new, kp, vp, ks, vs, bt, lengths - 1, fmt="e5m2", n_kv_heads=2,
+        q, *new, kp, vp, ks, vs, bt, lengths - 1, fmt="e5m2", n_kv_heads=KV,
         kv_mode="rne", impl=impl, interpret=True, **kw)
         for impl in ("ref", "batch", "kernel")]
     for f in fused[1:]:
@@ -101,8 +126,9 @@ def test_paged_lns_kernel_bit_identical_to_ref(seed, kw):
 
     B, page = q.shape[0], kp.shape[1]
     codes, qs = pa.quantize_q(q.reshape(B, 4, -1), "e5m2")
-    geom = dict(fmt="e5m2", mode=kw.get("mode", "rne"), KV=2, G=2,
-                window=kw.get("window", 0), cap=kw.get("cap", 0.0))
+    geom = dict(fmt="e5m2", mode=kw.get("mode", "rne"), KV=KV, G=4 // KV,
+                window=kw.get("window", 0), cap=kw.get("cap", 0.0),
+                scale=kw.get("scale"))
     want = jax.jit(lambda *a: pa._batch_partials(*a, **geom))(
         (codes, qs), kp, vp, ks, vs, bt, lengths)
     live = (np.arange(bt.shape[1])[None, :]
