@@ -12,24 +12,26 @@ traffic is ~half of a bf16 cache, and no float multiplier touches the QK^T
 products.
 
 Structure: flash-decoding style two-phase split.  Phase 1 (the Pallas
-kernel, one program per block of slots) emits per-page softmax partials
-(m, l, unnormalized o) — pages are independent, so there is no sequential
-carry.  Each program walks only the pages of its slots that hold a row:
-block tables and per-slot lengths ride in as scalar-prefetch operands, the
-K/V pools stay in HBM, and each live page is gathered by its own DMA
-(``bt[b, j]``), double-buffered behind the previous block's compute.  A
-page with no valid row (past a slot's length, or outside its window) has
-the partial (NEG_INF, 0, 0) in every impl: weight 0 in the combine.
-Phase 2 (plain jnp, shared verbatim by the kernel wrapper and the pure-JAX
-reference) merges the partials with the standard log-sum-exp combine.
+kernel, one program per block of slots) folds the softmax partials (m, l,
+unnormalized o) of the pages it walks into one running partial per slot,
+online and in walk order.  Each program walks only the pages of its slots
+that hold a row: block tables and per-slot lengths ride in as
+scalar-prefetch operands, the K/V pools stay in HBM, and each live page is
+gathered by its own DMA (``bt[b, j]``), double-buffered behind the
+previous block's compute.  A page with no valid row (past a slot's
+length, or outside its window) has the partial (NEG_INF, 0, 0), which the
+fold takes as an exact identity: the kernel skips it, the references fold
+it.  Phase 2 (plain jnp, shared verbatim by the kernel wrapper and the
+pure-JAX references) divides each slot's ``o`` by its ``l``.
 
 Numerics contract: ``impl="kernel"`` (interpret on CPU) and ``impl="batch"``
 (natively vectorized phase 1, the CPU serving path) are bit-identical to
-``impl="ref"`` — all three run the same per-element LNS ops and the same
-combine, and every order-sensitive f32 reduction is pinned behind
-``jax.lax.optimization_barrier`` so XLA cannot re-vectorize or FMA-contract
-one side differently (``tests/test_paged_serving.py`` and
-``tests/test_paged_fuzz.py`` pin this).
+``impl="ref"`` — all three run the same per-element LNS ops, the same
+fold order and the same phase 2, and every order-sensitive f32 reduction
+is pinned behind ``jax.lax.optimization_barrier`` so XLA cannot
+re-vectorize or FMA-contract one side differently
+(``tests/test_paged_serving.py`` and ``tests/test_paged_fuzz.py`` pin
+this).
 
 ``fused_decode_write_attend`` is the decode hot path's single entry: it
 computes the new token's page codes once, scatters them into the cache
@@ -53,10 +55,6 @@ from ..core.quant import encode
 from .common import code_to_f32, lns_combine, lns_prepare
 
 NEG_INF = -2.0e30
-
-# VMEM for one kernel program's output blocks (the double-buffered per-page
-# partials); a longer max_seq takes more programs, not more VMEM.
-PARTIALS_VMEM = 4 * 1024 * 1024
 
 
 # --------------------------------------------------------------------------- #
@@ -145,8 +143,8 @@ def _page_partial(
     and the values are the latents.
     t0: global position of the page's first row; length: valid tokens for
     this slot.  A fully masked page yields (m, l, o) = (NEG_INF, 0, 0):
-    zero weight in the combine, and the partial the kernel leaves on a
-    page it skips.  ``o`` is the p·V product before the 1/l normalization.
+    an exact identity of :func:`_fold`, which is why the kernel may skip
+    it.  ``o`` is the p·V product before the 1/l normalization.
 
     ``pin`` wraps every order-sensitive f32 reduction in
     ``optimization_barrier``: XLA CPU traces this body both in plain jit
@@ -208,35 +206,55 @@ def _page_partial(
     return m, l, (jax.lax.optimization_barrier(o) if pin else o)
 
 
-# --------------------------------------------------------------------------- #
-# Phase 2: log-sum-exp combine over pages — shared verbatim by both impls.
-# --------------------------------------------------------------------------- #
-def _combine_partials(m, l, o):
-    """m, l: [B, maxp, KV, G]; o: [B, maxp, KV, G, dv] -> [B, KV*G, dv].
+def _fold(acc, part, pin: bool):
+    """Merge the softmax partial ``part`` into the running ``acc``, both
+    (m [..., KV, G], l [..., KV, G], o [..., KV, G, dv]):
+    ``m' = max(m, m_j)``, ``l' = l·e^(m−m') + l_j·e^(m_j−m')``, o alike.
 
-    The entry barrier isolates the combine from its (impl-specific)
-    producers so XLA fuses/compiles it identically for every impl.  The
-    page-axis sums run as dots against ones for the same reason as
-    ``_page_scores_lns``: XLA CPU lowers dots consistently across graph
-    contexts, while reduce-sum vectorization depends on what else lives in
-    the program (the fused write+attend graph would otherwise combine a
-    ulp apart from the standalone attention).
+    The dead partial (NEG_INF, 0, 0) is an exact identity on either side
+    (``x·1 + 0·0``), so a fold that skips dead pages (the kernel) equals
+    one that folds them (the references), bit for bit.  ``pin`` as in
+    :func:`_page_partial`.
     """
-    pin = jax.lax.optimization_barrier
-    m, l, o = pin((m, l, o))
-    maxp = m.shape[1]
-    ones = jnp.ones((maxp,), jnp.float32)
-    M = pin(m.max(axis=1))                               # [B, KV, G]
-    w = pin(jnp.exp(pin(m - M[:, None])))                # [B, maxp, KV, G]
-    l_tot = pin(jax.lax.dot_general(
-        pin(w * l), ones, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ))
-    o_tot = pin(jax.lax.dot_general(
-        pin(w[..., None] * o), ones, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ))
-    out = o_tot / jnp.maximum(l_tot, 1e-37)[..., None]
+    (m, l, o), (mj, lj, oj) = acc, part
+    bar = jax.lax.optimization_barrier if pin else (lambda x: x)
+    mx = jnp.maximum(m, mj)
+    a = bar(jnp.exp(bar(m - mx)))
+    c = bar(jnp.exp(bar(mj - mx)))
+    return (mx, _pin_add(l * a, lj * c, pin),
+            _pin_add(o * a[..., None], oj * c[..., None], pin))
+
+
+def _dead_partial(shape, dv):
+    """The (NEG_INF, 0, 0) partial: a fold's start, a page with no row."""
+    return (jnp.full(shape, NEG_INF, jnp.float32),
+            jnp.zeros(shape, jnp.float32),
+            jnp.zeros(shape + (dv,), jnp.float32))
+
+
+def _fold_pages(m, l, o):
+    """Per-page partials [B, maxp, KV, G(, dv)] folded, in page order,
+    into one partial per slot: [B, KV, G(, dv)]."""
+    B, _, KV, G = m.shape
+    parts = tuple(jnp.moveaxis(x, 1, 0) for x in (m, l, o))
+    acc, _ = jax.lax.scan(lambda acc, part: (_fold(acc, part, True), None),
+                          _dead_partial((B, KV, G), o.shape[-1]), parts)
+    return acc
+
+
+# --------------------------------------------------------------------------- #
+# Phase 2: normalize each slot's folded partial — shared verbatim by every
+# impl.
+# --------------------------------------------------------------------------- #
+def _normalize(l, o):
+    """l: [B, KV, G]; o: [B, KV, G, dv] -> [B, KV*G, dv]: ``o / l``.
+
+    The entry barrier isolates the division from its (impl-specific)
+    producers so XLA compiles it identically for every impl.  A slot with
+    no row (l = 0) reads 0, not NaN.
+    """
+    l, o = jax.lax.optimization_barrier((l, o))
+    out = o / jnp.maximum(l, 1e-37)[..., None]
     B, KV, G, dv = out.shape
     return out.reshape(B, KV * G, dv)
 
@@ -257,8 +275,9 @@ def paged_attention_ref(
     fmt: Optional[str], mode: str, page_size: int, KV: int, G: int,
     window: int = 0, cap: float = 0.0, scale: Optional[float] = None,
 ):
-    """Per-page partials via lax.map (sequential, unbatched shapes — the
-    same shapes one kernel program sees), then the shared combine."""
+    """Each slot's partial by a sequential fold — a ``lax.scan`` over its
+    pages, of unbatched shapes, the same shapes one kernel program sees —
+    then the shared normalization."""
     maxp = block_tables.shape[1]
     k_pages, v_pages = _heads_view(k_pages, KV), _heads_view(v_pages, KV)
 
@@ -270,19 +289,21 @@ def paged_attention_ref(
         else:
             q_slot = qb.reshape(KV, G, -1)
 
-        def one_page(j):
+        def one_page(acc, j):
             pid = bt[j]
-            return _page_partial(
+            part = _page_partial(
                 q_slot, k_pages[pid], v_pages[pid], k_scale[pid],
                 v_scale[pid], j * page_size, length,
                 fmt=fmt, mode=mode, window=window, cap=cap,
                 scale=scale,
             )
+            return _fold(acc, part, True), None
 
-        return jax.lax.map(one_page, jnp.arange(maxp))
+        dead = _dead_partial((KV, G), v_pages.shape[-1])
+        return jax.lax.scan(one_page, dead, jnp.arange(maxp))[0]
 
-    m, l, o = jax.lax.map(slot, (q_op, block_tables, lengths))
-    return _combine_partials(m, l, o)
+    _, l, o = jax.lax.map(slot, (q_op, block_tables, lengths))
+    return _normalize(l, o)
 
 
 # --------------------------------------------------------------------------- #
@@ -315,8 +336,9 @@ def _batch_partials(
     q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
     fmt, mode, KV, G, window, cap, inserts=None, scale=None,
 ):
-    """All (slot, page) softmax partials at once: (m, l, o) shaped
-    [B, maxp, KV, G(, dv)] — the same combine input the ref builds."""
+    """All (slot, page) softmax partials at once, folded in page order
+    into one per slot: (m, l, o) shaped [B, KV, G(, dv)] — the same
+    partials the ref builds."""
     B, maxp = block_tables.shape
     k_pages, v_pages = _heads_view(k_pages, KV), _heads_view(v_pages, KV)
     kg = k_pages[block_tables]            # [B, maxp, page, KV, hd]
@@ -409,7 +431,7 @@ def _batch_partials(
         p, vt, (((4,), (3,)), ((0, 1, 2), (0, 1, 2))),
         preferred_element_type=jnp.float32,
     ))
-    return m, l, o
+    return _fold_pages(m, l, o)
 
 
 def paged_attention_batch(
@@ -417,12 +439,12 @@ def paged_attention_batch(
     fmt: Optional[str], mode: str, page_size: int, KV: int, G: int,
     window: int = 0, cap: float = 0.0, inserts=None, scale: Optional[float] = None,
 ):
-    m, l, o = _batch_partials(
+    _, l, o = _batch_partials(
         q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
         fmt=fmt, mode=mode, KV=KV, G=G, window=window, cap=cap,
         inserts=inserts, scale=scale,
     )
-    return _combine_partials(m, l, o)
+    return _normalize(l, o)
 
 
 def paged_attention_partials(
@@ -435,19 +457,14 @@ def paged_attention_partials(
     with ``o`` still un-normalized.  Pages this shard does not hold are
     masked by pointing their block-table entries at the null page with
     ``lengths`` clipped, or simply by passing a block table whose rows
-    list only local pages — fully masked pages contribute m = -inf and
-    drop out of the combine.  Feed the result to
+    list only local pages — fully masked pages are the fold's identity
+    and drop out.  Feed the result to
     :func:`combine_partials_psum` inside ``shard_map``.
     """
-    m, l, o = _batch_partials(
+    return _batch_partials(
         q_op, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
         fmt=fmt, mode=mode, KV=KV, G=G, window=window, cap=cap,
     )
-    M = m.max(axis=1)                                    # [B, KV, G]
-    w = jnp.exp(m - M[:, None])
-    l_loc = (w * l).sum(axis=1)
-    o_loc = (w[..., None] * o).sum(axis=1)
-    return M, l_loc, o_loc
 
 
 def combine_partials_psum(m, l, o, axis_name: str):
@@ -486,20 +503,21 @@ def walked_pages(lengths, page, maxp, xp=jnp):
 
 
 def _paged_kernel(
-    *refs, fmt, mode, page, KV, G, window, cap, spb, ppb, cpp, maxp, fused,
+    *refs, fmt, mode, page, KV, G, window, cap, spb, ppb, maxp, fused,
     pin, scale,
 ):
-    """Grid (Bp/spb, ceil(maxp/cpp)) program: walks the live pages of
-    ``spb`` slots among the ``cpp`` pages of its chunk of ``max_seq``.
+    """Grid (Bp/spb,) program: walks the live pages of ``spb`` slots,
+    folding each page's partial into its slot's one running partial.
 
     A slot holds rows in the pages :func:`walked_pages` counts; the
-    program visits those of its chunk in blocks of ``ppb`` pages and no
-    others.  The K/V pools stay in HBM: each live page is gathered
-    through the scalar-prefetched block table by its own DMA into a
-    double-buffered VMEM block, and the next block's copies (the next
-    slot's first block after a slot's last) are started before this
-    block's compute.  Every page the walk skips keeps the partial a page
-    without a valid row has, ``(NEG_INF, 0, 0)``.
+    program visits those in blocks of ``ppb`` pages and no others.  The
+    K/V pools stay in HBM: each live page is gathered through the
+    scalar-prefetched block table by its own DMA into a double-buffered
+    VMEM block, and the next block's copies (the next slot's first block
+    after a slot's last) are started before this block's compute.  The
+    output block holds one ``(m, l, o)`` per slot, started at
+    ``(NEG_INF, 0, 0)`` and folded (:func:`_fold`) in walk order; a page
+    the walk skips would fold in as that identity.
 
     Scalars ride in as scalar-prefetch (SMEM) operands: block tables,
     lengths, per-slot q scales and the per-(slot, page) K/V scales gathered
@@ -518,11 +536,9 @@ def _paged_kernel(
         refs = refs[npre:]
     q_ref, k_hbm, v_hbm, m_ref, l_ref, o_ref, k_buf, v_buf, sem = refs
     b = pl.program_id(0)
-    lo = pl.program_id(1) * cpp     # the chunk's first page
 
-    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-    o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
+    m_ref[...], l_ref[...], o_ref[...] = _dead_partial(m_ref.shape,
+                                                       o_ref.shape[-1])
 
     def widen(codes):
         # 8-bit codes widen to int32 on load (the LNS prepare does the same
@@ -530,9 +546,8 @@ def _paged_kernel(
         return codes.astype(jnp.int32) if fmt is not None else codes
 
     def live_pages(i):
-        """Pages of slot ``i`` walked in this chunk."""
-        n = walked_pages(len_ref[b * spb + i], page, maxp)
-        return jnp.clip(n - lo, 0, cpp)
+        """Pages of slot ``i`` walked."""
+        return walked_pages(len_ref[b * spb + i], page, maxp)
 
     def n_blocks(i):
         return (live_pages(i) + ppb - 1) // ppb
@@ -543,7 +558,7 @@ def _paged_kernel(
         n = jnp.minimum(ppb, live_pages(i) - blk * ppb)
 
         def body(jj, carry):
-            fn(jj, lo + blk * ppb + jj)
+            fn(jj, blk * ppb + jj)
             return carry
         jax.lax.fori_loop(0, n, body, 0)
 
@@ -576,15 +591,14 @@ def _paged_kernel(
                     jnp.int32, (page, 1, 1), 0) == row_ref[bs]
                 kp_blk = jnp.where(hit & row, widen(kins_ref[i][None]), kp_blk)
                 vp_blk = jnp.where(hit & row, widen(vins_ref[i][None]), vp_blk)
-            m, l, o = _page_partial(
+            part = _page_partial(
                 q_op, kp_blk, vp_blk, ks_ref[bs, jp], vs_ref[bs, jp],
                 jp * page, len_ref[bs],
                 fmt=fmt, mode=mode, window=window, cap=cap, pin=pin,
                 scale=scale,
             )
-            m_ref[i, jp - lo] = m
-            l_ref[i, jp - lo] = l
-            o_ref[i, jp - lo] = o
+            acc = (m_ref[i], l_ref[i], o_ref[i])
+            m_ref[i], l_ref[i], o_ref[i] = _fold(acc, part, pin)
         each_live_page(i, blk, one)
 
     def slot(i, carry):
@@ -643,28 +657,25 @@ def _pad_rows(x, n):
 
 def _paged_kernel_call(q_in, q_scale, k_pages, v_pages, k_scale, v_scale,
                        block_tables, lengths, **kw):
-    """The paged kernel's attention: its partials, then the combine."""
-    return _combine_partials(*_paged_kernel_partials(
+    """The paged kernel's attention: its partials, then phase 2."""
+    _, l, o = _paged_kernel_partials(
         q_in, q_scale, k_pages, v_pages, k_scale, v_scale, block_tables,
-        lengths, **kw))
+        lengths, **kw)
+    return _normalize(l, o)
 
 
 def _paged_kernel_partials(
     q_in, q_scale, k_pages, v_pages, k_scale, v_scale, block_tables, lengths,
     *, fmt, mode, page_size, KV, G, window, cap, interpret,
-    ppb: int = 1, spb: int = 1, cpp: Optional[int] = None, inserts=None,
-    scale: Optional[float] = None,
+    ppb: int = 1, spb: int = 1, inserts=None, scale: Optional[float] = None,
 ):
-    """Launch the paged kernel: per-page partials (m, l, o) shaped
-    [B, maxp, KV, G(, dv)], as :func:`_batch_partials`.
+    """Launch the paged kernel: one folded partial (m, l, o) per slot,
+    shaped [B, KV, G(, dv)], as :func:`_batch_partials`.
 
-    ``spb`` slots per program, walked ``ppb`` pages per block, among the
-    ``cpp`` pages of the program's chunk of ``max_seq`` (by default
-    :func:`pages_per_program`).  The slot axis is padded up to ``spb`` —
-    padded lanes carry length 0 and page 0 of their block table, walk
-    that one fully masked page, and are sliced off, as are the pages the
-    last chunk holds past ``maxp``, so the result is bit-identical for
-    every (ppb, spb, cpp).
+    ``spb`` slots per program, walked ``ppb`` pages per block.  The slot
+    axis is padded up to ``spb`` — padded lanes carry length 0 and page 0
+    of their block table, walk that one fully masked page, and are sliced
+    off — so the result is bit-identical for every (ppb, spb).
 
     Under a serving mesh that shards KV heads (``parallel.hints``) the
     launch runs under ``shard_map`` on each shard's KV heads: GSPMD cannot
@@ -674,7 +685,6 @@ def _paged_kernel_partials(
     from ..parallel.hints import kv_head_mesh
 
     B, H, hd = q_in.shape
-    maxp = block_tables.shape[1]
     Bp = -(-B // spb) * spb
     pad_b = Bp - B
     bt = _pad_rows(block_tables, pad_b)
@@ -692,7 +702,7 @@ def _paged_kernel_partials(
     launch = functools.partial(
         _paged_pallas, n_prefetch=len(prefetch), fmt=fmt, mode=mode,
         page_size=page_size, window=window, cap=cap, interpret=interpret,
-        ppb=ppb, spb=spb, cpp=cpp, scale=scale,
+        ppb=ppb, spb=spb, scale=scale,
     )
     sharded = kv_head_mesh()
     if sharded is not None:
@@ -703,30 +713,14 @@ def _paged_kernel_partials(
             in_specs=(P(),) * len(prefetch) + (
                 P(None, ax), P(None, None, ax), P(None, None, ax))
             + (P(None, ax),) * (len(args) - 3),
-            out_specs=(P(None, None, ax),) * 3, check_vma=False,
+            out_specs=(P(None, ax),) * 3, check_vma=False,
         )
     m, l, o = launch(*prefetch, *args)
-    return m[:B, :maxp], l[:B, :maxp], o[:B, :maxp]
-
-
-def _f32_tile_bytes(rows, cols):
-    """VMEM of a [rows, cols] f32 array: 8 x 128 tiles."""
-    return -(-rows // 8) * 8 * -(-cols // 128) * 128 * 4
-
-
-def pages_per_program(spb, maxp, KV, G, dv):
-    """Pages of ``max_seq`` one kernel program covers: ``maxp`` cut into
-    the fewest equal chunks whose output blocks — the double-buffered
-    partials of ``spb`` slots, m and l [KV, G] and o [KV, G, dv] f32 per
-    page — fit :data:`PARTIALS_VMEM`, so the kernel's VMEM does not grow
-    with ``max_seq``."""
-    page_bytes = 2 * _f32_tile_bytes(KV, G) + KV * _f32_tile_bytes(G, dv)
-    fit = max(1, PARTIALS_VMEM // (2 * spb * page_bytes))
-    return -(-maxp // -(-maxp // fit))
+    return m[:B], l[:B], o[:B]
 
 
 def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
-                  interpret, ppb, spb, cpp, scale):
+                  interpret, ppb, spb, scale):
     """The pallas_call of :func:`_paged_kernel_partials` on (possibly
     shard-local) operands: scalar prefetch, q [Bp, KV, G, hd], the page
     arrays (left in HBM), then the fused row inserts [Bp, KV, hd] x2 when
@@ -736,31 +730,28 @@ def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
     Bp, KV, G, hd = q.shape
     dv = v_pages.shape[-1]
     maxp = prefetch[0].shape[1]
-    cpp = cpp or pages_per_program(spb, maxp, KV, G, dv)
-    Jp = -(-maxp // cpp) * cpp
     fused = bool(rows)
     kernel = functools.partial(
         _paged_kernel, fmt=fmt, mode=mode, page=page_size, KV=KV, G=G,
-        window=window, cap=cap, spb=spb, ppb=ppb, cpp=cpp, maxp=maxp,
+        window=window, cap=cap, spb=spb, ppb=ppb, maxp=maxp,
         fused=fused, pin=bool(interpret), scale=scale,
     )
 
     def slot_spec(x):
         return pl.BlockSpec((spb,) + x.shape[1:],
-                            lambda b, c, *pref: (b,) + (0,) * (x.ndim - 1))
+                            lambda b, *pref: (b,) + (0,) * (x.ndim - 1))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     k_rows = _page_rows(page_size, KV, k_pages.shape[-1])
     v_rows = _page_rows(page_size, KV, dv)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_prefetch,
-        grid=(Bp // spb, Jp // cpp),
+        grid=(Bp // spb,),
         in_specs=[slot_spec(r) for r in rows] + [slot_spec(q), hbm, hbm],
         out_specs=[
-            pl.BlockSpec((spb, cpp, KV, G), lambda b, c, *pref: (b, c, 0, 0)),
-            pl.BlockSpec((spb, cpp, KV, G), lambda b, c, *pref: (b, c, 0, 0)),
-            pl.BlockSpec((spb, cpp, KV, G, dv),
-                         lambda b, c, *pref: (b, c, 0, 0, 0)),
+            pl.BlockSpec((spb, KV, G), lambda b, *pref: (b, 0, 0)),
+            pl.BlockSpec((spb, KV, G), lambda b, *pref: (b, 0, 0)),
+            pl.BlockSpec((spb, KV, G, dv), lambda b, *pref: (b, 0, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((2, ppb) + k_rows, k_pages.dtype),
@@ -772,12 +763,12 @@ def _paged_pallas(*args, n_prefetch, fmt, mode, page_size, window, cap,
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((Bp, Jp, KV, G), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, Jp, KV, G), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, Jp, KV, G, dv), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, KV, G), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, KV, G), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, KV, G, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")
+            dimension_semantics=("parallel",)
         ),
         interpret=interpret,
     )(*prefetch, *rows, q, k_pages.reshape((-1,) + k_rows),
@@ -847,14 +838,13 @@ def paged_decode_attention(
 @functools.partial(
     jax.jit,
     static_argnames=("fmt", "n_kv_heads", "mode", "window", "cap", "impl",
-                     "interpret", "ppb", "spb", "cpp", "scale"),
+                     "interpret", "ppb", "spb", "scale"),
 )
 def _paged_decode_attention(
     q, k_pages, v_pages, k_scale, v_scale, block_tables, lengths, *,
     fmt: Optional[str], n_kv_heads: int, mode: str,
     window: int, cap: float, impl: str, interpret: bool,
-    ppb: int = 1, spb: int = 1, cpp: Optional[int] = None,
-    scale: Optional[float] = None,
+    ppb: int = 1, spb: int = 1, scale: Optional[float] = None,
 ):
     B, one, H, hd = q.shape
     assert one == 1, "paged decode attention is single-position"
@@ -890,8 +880,7 @@ def _paged_decode_attention(
             q_arr, q_scale, k_pages, v_pages, k_scale, v_scale,
             block_tables, lengths, fmt=fmt, mode=mode,
             page_size=k_pages.shape[1], KV=KV, G=G, window=window, cap=cap,
-            interpret=interpret, ppb=ppb, spb=spb, cpp=cpp,
-            scale=scale,
+            interpret=interpret, ppb=ppb, spb=spb, scale=scale,
         )
     else:
         raise ValueError(f"unknown impl {impl!r}")
@@ -962,12 +951,12 @@ def _set_rows(pages, pids, rows, row):
 @functools.partial(
     jax.jit,
     static_argnames=("fmt", "n_kv_heads", "mode", "kv_mode", "window", "cap",
-                     "impl", "interpret", "ppb", "spb", "cpp", "scale"),
+                     "impl", "interpret", "ppb", "spb", "scale"),
 )
 def _fused_decode_write_attend(
     q, k_new, v_new, k_pages, v_pages, k_scale, v_scale, block_tables,
     lengths, k_key, v_key, write_mask, *, fmt, n_kv_heads, mode, kv_mode,
-    window, cap, impl, interpret, ppb, spb, cpp=None, scale=None,
+    window, cap, impl, interpret, ppb, spb, scale=None,
 ):
     from ..serving.page_pool import token_row_codes
 
@@ -1022,7 +1011,7 @@ def _fused_decode_write_attend(
             fmt=fmt, mode=mode, KV=KV, G=G, window=window, cap=cap,
             inserts=(k_row, v_row, logical, rows, mask), scale=scale,
         )
-        out = _combine_partials(m, l, o)
+        out = _normalize(l, o)
         # Materialize the softmax partials as (discarded) graph outputs.
         # Barriers alone do not stop XLA CPU from duplicating their
         # producers into downstream fusions with context-dependent
@@ -1038,7 +1027,7 @@ def _fused_decode_write_attend(
             q_arr, q_scale, k_pages, v_pages, new_ks, new_vs,
             block_tables, attend_len, fmt=fmt, mode=mode,
             page_size=page_size, KV=KV, G=G, window=window, cap=cap,
-            interpret=interpret, ppb=ppb, spb=spb, cpp=cpp,
+            interpret=interpret, ppb=ppb, spb=spb,
             inserts=(k_row, v_row, logical, rows, mask), scale=scale,
         )
     else:

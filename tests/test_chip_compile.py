@@ -64,26 +64,26 @@ def _page_args(slots=SLOTS, maxp=MAXP, pool=POOL, kv=KV, g=G, hd=HD):
             ((slots, maxp), jnp.int32), ((slots,), jnp.int32)]
 
 
-def _compile_unfused(one_chip, ppb, spb, cpp=None, kv=KV, **geom):
-    """(pages per walked block, slots per program, pages per program) of
-    the live-page walk."""
+def _compile_unfused(one_chip, ppb, spb, kv=KV, **geom):
+    """(pages per walked block, slots per program) of the live-page
+    walk."""
     def unfused(q, kp, vp, ks, vs, bt, ln):
         return pa._paged_decode_attention(
             q, kp, vp, ks, vs, bt, ln, fmt="e5m2", n_kv_heads=kv, mode="rne",
             window=0, cap=0.0, impl="kernel", interpret=False, ppb=ppb,
-            spb=spb, cpp=cpp)
+            spb=spb)
 
     return _compile_text(unfused, one_chip, *_page_args(kv=kv, **geom))
 
 
-def _compile_fused(one_chip, ppb, spb, cpp=None, slots=SLOTS, kv=KV, hd=HD,
+def _compile_fused(one_chip, ppb, spb, slots=SLOTS, kv=KV, hd=HD,
                    **geom):
     def fused(q, kp, vp, ks, vs, bt, ln, k_new, v_new, keys, mask):
         return pa._fused_decode_write_attend(
             q, k_new, v_new, kp, vp, ks, vs, bt, ln, keys, keys, mask,
             fmt="e5m2", n_kv_heads=kv, mode="rne", kv_mode="stochastic",
             window=0, cap=0.0, impl="kernel", interpret=False, ppb=ppb,
-            spb=spb, cpp=cpp)
+            spb=spb)
 
     return _compile_text(
         fused, one_chip, *_page_args(slots=slots, kv=kv, hd=hd, **geom),
@@ -91,19 +91,15 @@ def _compile_fused(one_chip, ppb, spb, cpp=None, slots=SLOTS, kv=KV, hd=HD,
         ((slots, 2), jnp.uint32), ((slots,), jnp.bool_))
 
 
-# pages per program where a case cuts the 32 pages into chunks
-CHUNKED = {(4, 2): 8, (1, 4): 12, (2, 2): 12}
-
-
 @pytest.mark.parametrize("ppb,spb", [(1, 1), (2, 1), (4, 2), (1, 4)])
 def test_paged_kernel_compiles(one_chip, ppb, spb):
-    text = _compile_unfused(one_chip, ppb, spb, CHUNKED.get((ppb, spb)))
+    text = _compile_unfused(one_chip, ppb, spb)
     assert "tpu_custom_call" in text
 
 
 @pytest.mark.parametrize("ppb,spb", [(1, 1), (2, 2)])
 def test_fused_write_attend_kernel_compiles(one_chip, ppb, spb):
-    text = _compile_fused(one_chip, ppb, spb, CHUNKED.get((ppb, spb)))
+    text = _compile_fused(one_chip, ppb, spb)
     assert "tpu_custom_call" in text
 
 
@@ -111,8 +107,8 @@ def test_fused_write_attend_kernel_compiles(one_chip, ppb, spb):
 @pytest.mark.parametrize("ppb,spb", [(1, 1), (8, 4)])
 def test_paged_kernel_compiles_at_decode_long(one_chip, ppb, spb, fused):
     """The decode-long cell's geometry (64 slots x 96 pages), at the
-    served block shape's neighbours: one chunk of 96 pages, or (4 slots
-    per program) chunks of 32."""
+    served block shape's neighbours, each slot's 96 pages folded into one
+    partial."""
     compile_ = _compile_fused if fused else _compile_unfused
     text = compile_(one_chip, ppb, spb, slots=LONG_SLOTS, maxp=LONG_MAXP,
                     pool=LONG_POOL)
@@ -129,8 +125,8 @@ def test_paged_kernel_compiles_at_other_geometries(one_chip, geom, fused):
     """The served block shape at the other geometries the kernel runs: a
     qwen2 shard of a 2-way tensor-parallel mesh (1 KV head, KV*hd = 64:
     pages gathered as [8, 128] rows), qwen3-14b's 8 KV heads of 128, and
-    qwen3-14b at 32k-token contexts, where the partials of 2048 pages no
-    longer fit one program."""
+    qwen3-14b at 32k-token contexts, whose 2048 pages a slot fold into
+    the same one-partial output block as 32 pages."""
     compile_ = _compile_fused if fused else _compile_unfused
     ppb, spb = PAGED_BLOCKS
     geom = dict(geom, pool=geom["slots"] * geom["maxp"] + 1)

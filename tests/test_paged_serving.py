@@ -14,6 +14,7 @@ import pytest
 
 from repro.core.quant import encode
 from repro.kernels.common import code_to_f32
+from repro.kernels import paged_attention as pa
 from repro.kernels.paged_attention import paged_decode_attention
 from repro.serving import (
     PagePool,
@@ -78,6 +79,18 @@ def _mla_inputs(make, seed, *, H=4, dr=8, L=32):
 # --------------------------------------------------------------------------- #
 # Kernel == reference == batch, bit for bit (the acceptance contract)
 # --------------------------------------------------------------------------- #
+def _partials_jit(fn, *static):
+    """``fn`` jitted once per test process, so the seeds of a case share
+    its compiles."""
+    return jax.jit(fn, static_argnames=static + (
+        "fmt", "mode", "KV", "G", "window", "cap", "scale"))
+
+
+_batch_partials_jit = _partials_jit(pa._batch_partials)
+_kernel_partials_jit = _partials_jit(pa._paged_kernel_partials, "interpret",
+                                     "page_size", "ppb", "spb")
+
+
 @pytest.mark.parametrize("seed", range(4))
 @pytest.mark.parametrize(
     "kw", [dict(), dict(window=7, cap=25.0), dict(mode="faithful"),
@@ -88,13 +101,16 @@ def _mla_inputs(make, seed, *, H=4, dr=8, L=32):
 )
 def test_paged_lns_kernel_bit_identical_to_ref(seed, kw):
     """kernel == ref == batch, unfused and fused (the token written at
-    ``lengths - 1``); at page-edge lengths the kernel's per-page partials
-    also equal the batch's for several block shapes, and every page it
-    skips reads exactly (NEG_INF, 0, 0).  The ``mla`` cases run the
-    kernel's MLA mode: one KV head, a query of rope and latent lanes
-    against rope-key and latent pages, the latents as values."""
-    from repro.kernels import paged_attention as pa
-
+    ``lengths - 1``).  Where the case's lengths are the drawn or page-edge
+    ones (not ``window-cap`` / ``faithful``), also at every (pages per
+    block, slots per program) below: the kernel's folded partials equal
+    the batch's, one per slot, for the case's lengths and for lengths that
+    hold a slot of length 0 (output 0, not NaN) and slots whose live pages
+    end on a page edge; and the outputs of the three impls agree, fused
+    and unfused.  The ``mla``
+    cases run the kernel's MLA mode: one KV head, a query of rope and
+    latent lanes against rope-key and latent pages, the latents as
+    values."""
     kw = dict(kw)
     edges = kw.pop("edges", False)
     make = _page_edge_inputs if edges else _paged_inputs
@@ -110,9 +126,9 @@ def test_paged_lns_kernel_bit_identical_to_ref(seed, kw):
     for o in outs[1:]:
         np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(o))
 
+    B, page = q.shape[0], kp.shape[1]
     rng = np.random.default_rng(100 + seed)
-    new = [jnp.asarray(rng.standard_normal((q.shape[0], KV, d))
-                       .astype(np.float32))
+    new = [jnp.asarray(rng.standard_normal((B, KV, d)).astype(np.float32))
            for d in (kp.shape[-1], vp.shape[-1])]
     fused = [pa.fused_decode_write_attend(
         q, *new, kp, vp, ks, vs, bt, lengths - 1, fmt="e5m2", n_kv_heads=KV,
@@ -121,48 +137,102 @@ def test_paged_lns_kernel_bit_identical_to_ref(seed, kw):
     for f in fused[1:]:
         for a, b in zip(fused[0], f):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    if not edges:
+    if not edges and ("window" in kw or "mode" in kw):
         return
 
-    B, page = q.shape[0], kp.shape[1]
-    codes, qs = pa.quantize_q(q.reshape(B, 4, -1), "e5m2")
-    geom = dict(fmt="e5m2", mode=kw.get("mode", "rne"), KV=KV, G=4 // KV,
+    geom = dict(fmt="e5m2", mode=kw.get("mode", "rne"),
                 window=kw.get("window", 0), cap=kw.get("cap", 0.0),
                 scale=kw.get("scale"))
-    want = jax.jit(lambda *a: pa._batch_partials(*a, **geom))(
-        (codes, qs), kp, vp, ks, vs, bt, lengths)
-    live = (np.arange(bt.shape[1])[None, :]
-            < -(-np.asarray(lengths)[:, None] // page))
-    # (pages per block, slots per program, pages per program): one chunk,
-    # and chunks of 2 of the 5 pages with padded slot blocks
-    for ppb, spb, cpp in ((1, 1, None), (4, 1, None), (2, 2, 2), (3, 3, 2)):
-        got = pa._paged_kernel_partials(
-            codes, qs, kp, vp, ks, vs, bt, lengths, page_size=page,
-            interpret=True, ppb=ppb, spb=spb, cpp=cpp, **geom)
-        for w, g, dead in zip(want, got, (pa.NEG_INF, 0.0, 0.0)):
-            np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
-            assert np.all(np.asarray(g)[~live] == dead)
+
+    @functools.cache
+    def attend(impl, fused, ppb=1, spb=1):
+        """The (fused: written caches and) output of ``impl``; ref and
+        batch take no block shape."""
+        blocks = dict(ppb=ppb, spb=spb, n_kv_heads=KV, impl=impl,
+                      interpret=True, **geom)
+        if fused:
+            return pa._fused_decode_write_attend(
+                q, *new, kp, vp, ks, vs, bt, lengths - 1, None, None, None,
+                kv_mode="rne", **blocks)[:5]
+        return (pa._paged_decode_attention(q, kp, vp, ks, vs, bt, edge_lens,
+                                           **blocks),)
+
+    # a slot of length 0, slots whose live pages end on a page edge, and
+    # one a row past an edge
+    edge_lens = jnp.asarray(np.resize([0, 2 * page, 4 * page, page + 1], B),
+                            jnp.int32)
+    codes, qs = pa.quantize_q(q.reshape(B, 4, -1), "e5m2")
+    G = 4 // KV
+    # one slot a program, and padded blocks of 2 and 3 slots
+    for ppb, spb in ((1, 1), (4, 1), (2, 2), (3, 3)):
+        for lens in (lengths, edge_lens):
+            want = _batch_partials_jit(
+                (codes, qs), kp, vp, ks, vs, bt, lens, KV=KV, G=G, **geom)
+            got = _kernel_partials_jit(
+                codes, qs, kp, vp, ks, vs, bt, lens, page_size=page, KV=KV,
+                G=G, interpret=True, ppb=ppb, spb=spb, **geom)
+            assert got[0].shape == (B, KV, G)
+            for w, g in zip(want, got):
+                np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+        for fused in (False, True):
+            want = attend("ref", fused)
+            for got in (attend("batch", fused),
+                        attend("kernel", fused, ppb, spb)):
+                for w, g in zip(want, got):
+                    np.testing.assert_array_equal(np.asarray(w),
+                                                  np.asarray(g))
+    out = np.asarray(attend("ref", False)[0])
+    assert np.all(np.isfinite(out))
+    assert np.all(out[np.asarray(edge_lens) == 0] == 0)
+
+
+@pytest.mark.parametrize("shape", ["gqa", "mla"])
+def test_kernel_emits_one_partial_per_slot_at_decode_long(shape):
+    """At decode-long's geometry (64 slots x 96 pages) the kernel emits
+    one folded partial per slot, [B, KV, G(, dv)], not one per page of
+    ``max_seq`` (shapes only: the launch is traced, not run)."""
+
+    B, maxp, page = 64, 96, 16
+    KV, G, hd, dv = (2, 7, 64, 64) if shape == "gqa" else (1, 16, 576, 512)
+    dk = hd if shape == "gqa" else hd - dv
+    P = B * maxp + 1
+    S = jax.ShapeDtypeStruct
+    out = jax.eval_shape(
+        functools.partial(
+            pa._paged_kernel_partials, fmt="e5m2", mode="rne",
+            page_size=page, KV=KV, G=G, window=0, cap=0.0, interpret=True,
+            ppb=4),
+        S((B, KV * G, hd), jnp.uint8), S((B,), jnp.float32),
+        S((P, page, KV, dk) if shape == "gqa" else (P, page, dk), jnp.uint8),
+        S((P, page, KV, dv) if shape == "gqa" else (P, page, dv), jnp.uint8),
+        S((P,), jnp.float32), S((P,), jnp.float32),
+        S((B, maxp), jnp.int32), S((B,), jnp.int32))
+    assert [o.shape for o in out] == [(B, KV, G), (B, KV, G), (B, KV, G, dv)]
 
 
 @pytest.mark.parametrize("KV,G,dv", [(2, 7, 64), (1, 7, 64), (8, 5, 128)],
                          ids=["qwen2", "qwen2-tp2-shard", "qwen3-14b"])
 def test_kernel_partials_vmem_does_not_grow_with_max_seq(KV, G, dv):
-    """A kernel program's output blocks (double-buffered m, l [KV, G] and
-    o [KV, G, dv] f32 per page, in 8 x 128 tiles) stay within
-    ``PARTIALS_VMEM`` up to 32k-token contexts: a longer ``max_seq`` is cut
-    into more, equal chunks; decode-long's 96 pages take one."""
-    from repro.kernels import paged_attention as pa
+    """A kernel program's output block is one folded partial per slot,
+    ``[spb, KV, G(, dv)]``, whatever ``maxp``: a longer ``max_seq``
+    takes more pages walked, not more VMEM."""
 
-    page_bytes = 4 * (2 * 8 * 128 + KV * -(-G // 8) * 8 * -(-dv // 128) * 128)
+    P, page, hd = 8, 16, dv
+    S = jax.ShapeDtypeStruct
     for spb in (1, 4):
         for maxp in (1, 96, 97, 2048):
-            cpp = pa.pages_per_program(spb, maxp, KV, G, dv)
-            chunks = -(-maxp // cpp)
-            assert 2 * spb * cpp * page_bytes <= pa.PARTIALS_VMEM
-            assert cpp * (chunks - 1) < maxp <= cpp * chunks
-            fit = pa.PARTIALS_VMEM // (2 * spb * page_bytes)
-            assert chunks == -(-maxp // fit)
-    assert pa.pages_per_program(1, 96, 2, 7, 64) == 96
+            shapes = jax.eval_shape(
+                functools.partial(
+                    pa._paged_pallas, n_prefetch=5, fmt="e5m2", mode="rne",
+                    page_size=page, window=0, cap=0.0, interpret=True,
+                    ppb=1, spb=spb, scale=None),
+                S((spb, maxp), jnp.int32), S((spb,), jnp.int32),
+                S((spb,), jnp.float32), S((spb, maxp), jnp.float32),
+                S((spb, maxp), jnp.float32), S((spb, KV, G, hd), jnp.uint8),
+                S((P, page, KV, hd), jnp.uint8),
+                S((P, page, KV, dv), jnp.uint8))
+            assert [s.shape for s in shapes] == [
+                (spb, KV, G), (spb, KV, G), (spb, KV, G, dv)]
 
 
 @pytest.mark.parametrize("seed", range(2))
